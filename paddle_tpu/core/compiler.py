@@ -441,11 +441,4 @@ class CompiledProgram:
 
 
 def places_to_devices(places):
-    import jax
-
-    devs = jax.devices()
-    out = []
-    for p in places:
-        did = getattr(p, "device_id", 0)
-        out.append(devs[did % len(devs)])
-    return out
+    return [p.jax_device() for p in places]

@@ -508,6 +508,14 @@ class Executor:
 
     def __init__(self, place: Optional[Place] = None):
         self.place = place or TPUPlace()
+        if self.place.device_id != 0:
+            # state and step live on the process's default device; a
+            # place that names another one must not be ignored
+            raise NotImplementedError(
+                f"Executor({self.place!r}): a single-device Executor "
+                "runs on device 0 of its backend. Span devices with "
+                "CompiledProgram(...).with_data_parallel/"
+                "with_partitioning(places=[...]) instead.")
         self._cache: Dict[Tuple, _CompiledBlock] = {}
         self._run_counter = 0
         self._base_keys: Dict[int, Any] = {}
@@ -942,7 +950,12 @@ class Executor:
                 in_shardings, mesh, strategy,
             )
 
-        bound = _dispatch.BoundStep(self, compiled, scope, block, raw_dtypes)
+        bound = _dispatch.BoundStep(
+            self, compiled, scope, block, raw_dtypes,
+            feed_avals=[
+                jax.ShapeDtypeStruct(np.shape(feed_vals[n]),
+                                     feed_vals[n].dtype)
+                for n in compiled.feed_names])
         if bkey is not None:
             self._bound[bkey] = bound
             while len(self._bound) > self._bound_cap:

@@ -29,19 +29,18 @@ class Place:
         return f"{type(self).__name__}({self.device_id})"
 
     def jax_device(self):
-        """Resolve to a concrete jax.Device."""
+        """Resolve to a concrete jax.Device of this place's backend (the
+        default backend when the class names none). An ordinal the
+        backend does not have is an error, never wrapped onto another
+        device."""
         import jax
 
-        if self._backend is None:
-            return jax.devices()[self.device_id]
-        try:
-            devs = jax.devices(self._backend)
-        except RuntimeError:
-            # Requested backend not present (e.g. TPUPlace on a CPU-only
-            # test host): fall back to the default backend so programs
-            # remain runnable everywhere.
-            devs = jax.devices()
-        return devs[self.device_id % len(devs)]
+        devs = jax.devices(self._backend)
+        if not 0 <= self.device_id < len(devs):
+            raise ValueError(
+                f"{self!r}: the {devs[0].platform} backend has "
+                f"{len(devs)} device(s)")
+        return devs[self.device_id]
 
 
 class CPUPlace(Place):
@@ -52,10 +51,12 @@ class CPUPlace(Place):
 
 
 class TPUPlace(Place):
-    """The native target. On hosts without TPU it degrades to the default
-    jax backend so the same user program runs in CI."""
+    """The native target: a device of jax's default backend — the TPU
+    where one is attached, the CPU under JAX_PLATFORMS=cpu (tests).
+    Code that must not run without a chip asserts the platform itself
+    (chip_smoke.py, bench.py)."""
 
-    _backend = None  # default backend: tpu when present, else cpu
+    _backend = None  # jax's default backend
 
     def __init__(self, device_id: int = 0):
         super().__init__(device_id)

@@ -26,12 +26,6 @@ _FLAG_DEFS: Dict[str, Any] = {
     # (all passes incl. shape re-inference; errors raise
     # ProgramVerificationError BEFORE any JAX lowering)
     "validate_program": "warn",
-    # persistent cross-process XLA compilation cache (runtime/dispatch):
-    # directory for jax_compilation_cache_dir; "" disables. A new
-    # process re-running an already-seen program loads the serialized
-    # executable from disk instead of re-compiling (the scarce-TPU-
-    # window amortization the whole-program compile model depends on).
-    "compile_cache_dir": os.path.join("~", ".cache", "paddle_tpu", "xla"),
     # async host/device pipeline (runtime/dispatch BoundStep
     # .run_pipelined / Executor.run_pipelined): number of prepared
     # feeds the feeder thread may run ahead of the device step. 2 is
@@ -203,13 +197,15 @@ _FLAG_DEFS: Dict[str, Any] = {
     # op-for-op the unfused chain (bitwise-identical trajectories)
     "optimizer_fuse": "auto",
     # tools/autotune.py cost-model autotuner: profiles keyed by
-    # executable fingerprint live under autotune_dir; when
+    # executable fingerprint live under autotune_dir. It names no
+    # directory by default, so nothing outside the checkout steers a
+    # run: the seam is inert until autotune_dir is set. Then, when
     # autotune_apply is on, Executor._compile (and the serving/
     # generation engine constructors) look up the program's profile
     # and apply its tuned flags — EXCEPT flags the user set explicitly
     # (set_flags / FLAGS_ env always win). apply_autotune_profile()
     # is the same seam invoked by hand.
-    "autotune_dir": os.path.join("~", ".cache", "paddle_tpu", "autotune"),
+    "autotune_dir": "",
     "autotune_apply": True,
     # disagg/ (disaggregated prefill/decode serving): the page-store
     # rendezvous between prefill and decode workers.
@@ -432,6 +428,10 @@ def autotune_dir() -> str:
 
 def autotune_profile_path(fingerprint: str, dir: str = None) -> str:
     base = os.path.expanduser(dir) if dir else autotune_dir()
+    if not base:
+        raise ValueError(
+            "no autotune profile directory: set the autotune_dir flag "
+            "(or pass dir=)")
     # fingerprints are hex digests / identifier-safe keys; sanitize
     # anything else so a weird key cannot escape the profile dir
     safe = "".join(c if (c.isalnum() or c in "._-") else "_"
@@ -557,7 +557,8 @@ def autotune_apply_for(fingerprint: str) -> Dict[str, Any]:
     best-effort apply of a matching profile under the
     ``autotune_apply`` flag — once per fingerprint per process, and
     never an exception on the construction path."""
-    if not flag("autotune_apply") or not fingerprint:
+    if not (flag("autotune_apply") and flag("autotune_dir")
+            and fingerprint):
         return {}
     if fingerprint in _autotune_probed:
         return {}

@@ -1603,6 +1603,12 @@ class GenerationEngine:
                     self._retire(slot, "length")
                 elif self.cache.is_active(slot):
                     self.cache.release(slot)
+            if req.stream.error is not None:
+                # the step loop turns a failed step into a per-request
+                # error so one bad batch cannot stop serving; at warmup
+                # there is nothing to keep serving — an executable that
+                # does not compile or run must fail construction
+                raise req.stream.error
             if self.prefix_cache:
                 # warmup's dummy [0, 0] prompt must not seed the trie
                 self.cache.drop_trie()

@@ -98,8 +98,8 @@ def _pallas_mode() -> Optional[str]:
     # PADDLE_TPU_KERNEL_INTERPRET is the shared interpret switch the
     # other fused kernels (layer_norm, softmax_xent) use — honoring it
     # here keeps CI smoke coverage real: with only the flash-specific
-    # var, tests/test_bench_smoke.py's flash stages silently took the
-    # XLA fallback on CPU (round-5 review finding)
+    # var, tests/test_bench_smoke.py's flash stages would take the
+    # reference path on CPU
     if (os.environ.get("PADDLE_TPU_FLASH_INTERPRET", "")
             or os.environ.get("PADDLE_TPU_KERNEL_INTERPRET", "")):
         return "interpret"
@@ -219,8 +219,7 @@ def _flash_fwd_pallas(q, k, v, mask, bias, sm_scale, causal, interpret,
 # live in VMEM, so sequence length is bounded by HBM, not VMEM. A dense
 # [S, S] bias at this length is O(S^2) HBM by definition (same problem
 # the ring-attention route warns about), so bias inputs stay on the
-# panel kernel — whose VMEM try/except falls back to XLA if S is too
-# big for the panel.
+# panel kernel — which raises if S is too big for its VMEM panel.
 
 
 def _make_fwd_stream_kernel(blk_q: int, blk_k: int, nk: int, causal: bool,
@@ -849,25 +848,20 @@ _core.defvjp(_core_fwd, _core_bwd)
 
 
 def _run_fwd(q, k, v, mask, bias, causal, sm_scale, with_lse=True):
+    # the reference is the path where no Pallas mode applies (CPU
+    # without the interpret switch), never a retry: a kernel that fails
+    # to trace, lower or compile raises
     mode = _pallas_mode()
     if mode is not None:
-        try:
-            if q.shape[2] > _panel_max() and bias is None:
-                return _flash_fwd_stream(
-                    q, k, v, mask, sm_scale, causal,
-                    interpret=(mode == "interpret"), with_lse=with_lse,
-                )
-            return _flash_fwd_pallas(
-                q, k, v, mask, bias, sm_scale, causal,
+        if q.shape[2] > _panel_max() and bias is None:
+            return _flash_fwd_stream(
+                q, k, v, mask, sm_scale, causal,
                 interpret=(mode == "interpret"), with_lse=with_lse,
             )
-        except Exception:
-            # a Pallas regression must not silently change what the
-            # bench measures (round-1 verdict weak #6)
-            _logger.warning(
-                "flash_attention Pallas forward failed; falling back to "
-                "naive XLA attention", exc_info=True,
-            )
+        return _flash_fwd_pallas(
+            q, k, v, mask, bias, sm_scale, causal,
+            interpret=(mode == "interpret"), with_lse=with_lse,
+        )
     o = _reference_attention(q, k, v, sm_scale, causal, mask, bias)
     return o, None
 
@@ -877,21 +871,15 @@ def _run_bwd(q, k, v, mask, bias, o, lse, g, causal, sm_scale):
     # re-derived, not stashed: residuals must be jax types)
     mode = _pallas_mode() if lse is not None else None
     if mode is not None:
-        try:
-            if q.shape[2] > _panel_max() and bias is None:
-                return _flash_bwd_stream(
-                    q, k, v, mask, o, lse, g, sm_scale, causal,
-                    interpret=(mode == "interpret"),
-                )
-            return _flash_bwd_pallas(
-                q, k, v, mask, bias, o, lse, g, sm_scale, causal,
+        if q.shape[2] > _panel_max() and bias is None:
+            return _flash_bwd_stream(
+                q, k, v, mask, o, lse, g, sm_scale, causal,
                 interpret=(mode == "interpret"),
             )
-        except Exception:
-            _logger.warning(
-                "flash_attention Pallas backward failed; falling back to "
-                "naive XLA attention backward", exc_info=True,
-            )
+        return _flash_bwd_pallas(
+            q, k, v, mask, bias, o, lse, g, sm_scale, causal,
+            interpret=(mode == "interpret"),
+        )
 
     def ref(q, k, v, bias):
         return _reference_attention(q, k, v, sm_scale, causal, mask, bias)
@@ -996,7 +984,7 @@ def _flash_attention_op(ctx, op, ins):
         if bias is not None:
             _logger.warning(
                 "flash_attention: BiasQK is dense [S, S] and cannot ride "
-                "the ring; falling back to the flash kernel (GSPMD will "
+                "the ring; using the unsharded flash kernel (GSPMD will "
                 "all-gather K/V across the sp axis)")
         else:
             # mode comes from with_sequence_parallel(mode=...): "ring"

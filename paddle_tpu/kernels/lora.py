@@ -53,7 +53,7 @@ win lost — the same PTL092 story as small int8_block blocks).
 
 from __future__ import annotations
 
-import logging
+import os
 from typing import Optional, Sequence, Tuple
 
 import numpy as np
@@ -62,8 +62,6 @@ import jax
 import jax.numpy as jnp
 
 from .quant_matmul import DEFAULT_BLOCK, quantized_matmul
-
-_logger = logging.getLogger("paddle_tpu.lora")
 
 LANES = 128
 SUBLANES = 8
@@ -216,20 +214,17 @@ def batched_lora_delta(x2, a, b, scale, slots):
     (base-only rows, rows owned by another bucket, padding) contribute
     exactly 0.0."""
     m = _pallas_mode()
+    if (m == "tpu" and lora_rank_geometry_issue(a.shape[2])
+            and os.environ.get("PADDLE_TPU_FORCE_PALLAS") != "1"):
+        # the declared geometry rule (PTL092) SELECTS the reference for
+        # a rank Mosaic cannot tile; under FORCE_PALLAS the kernel's
+        # own guard raises instead (PTL091)
+        m = None
     if m is not None:
-        try:
-            return _lora_delta_pallas(x2, a, b, scale, slots,
-                                      interpret=(m == "interpret"))
-        except Exception:  # noqa: BLE001 — a kernel regression must be loud
-            import os
-
-            if os.environ.get("PADDLE_TPU_FORCE_PALLAS") == "1":
-                # AOT-validation contract: never record ok=true for a
-                # kernel that silently fell back
-                raise
-            _logger.warning(
-                "batched_lora_delta Pallas kernel failed; falling back "
-                "to the reference gather path", exc_info=True)
+        # no retry on the reference: a kernel that fails to trace,
+        # lower or compile raises
+        return _lora_delta_pallas(x2, a, b, scale, slots,
+                                  interpret=(m == "interpret"))
     return _reference_lora_delta(x2, a, b, scale, slots)
 
 

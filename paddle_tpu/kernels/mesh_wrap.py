@@ -36,13 +36,6 @@ from __future__ import annotations
 import jax
 
 
-def _smap():
-    f = getattr(jax, "shard_map", None)
-    if f is None:
-        from jax.experimental.shard_map import shard_map as f
-    return f
-
-
 def mode(ctx):
     """('direct'|'wrap'|'xla', mesh, wrap_axes) for a lowering ctx."""
     mesh = getattr(ctx, "mesh", None)
@@ -79,8 +72,5 @@ def wrap_call(mesh, axes, fn, in_specs, out_specs):
     axis — a partial wrap would leave an auto axis free to
     re-partition the Mosaic call."""
     assert set(axes) == set(mesh.axis_names), (axes, mesh.axis_names)
-    kwargs = {"mesh": mesh, "in_specs": in_specs, "out_specs": out_specs}
-    try:
-        return _smap()(fn, check_vma=False, **kwargs)
-    except TypeError:
-        return _smap()(fn, check_rep=False, **kwargs)
+    return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)

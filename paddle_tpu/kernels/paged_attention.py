@@ -40,14 +40,11 @@ a dense cache); pages + block tables are the TPU-native replacement.
 from __future__ import annotations
 
 import functools
-import logging
 import math
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
-
-_logger = logging.getLogger("paddle_tpu.paged_attention")
 
 NEG_INF = -1e30
 
@@ -123,29 +120,19 @@ def paged_attention(q, k_pages, v_pages, lengths, page_indices, *,
     page_indices = page_indices.astype(jnp.int32)
     mode = _pallas_mode()
     if mode == "tpu":
-        try:
-            from jax.experimental.pallas.ops.tpu.paged_attention import (
-                paged_attention as _jax_paged_attention,
-            )
+        # no retry on the reference: a kernel that fails to trace,
+        # lower or compile raises
+        from jax.experimental.pallas.ops.tpu.paged_attention import (
+            paged_attention as _jax_paged_attention,
+        )
 
-            blk = (pages_per_compute_block
-                   or _compute_block_pages(page_indices.shape[1]))
-            return _jax_paged_attention(
-                (q * scale).astype(q.dtype), k_pages, v_pages,
-                lengths, page_indices,
-                pages_per_compute_block=blk,
-            )
-        except Exception:  # noqa: BLE001 — a kernel regression must be loud
-            import os
-
-            if os.environ.get("PADDLE_TPU_FORCE_PALLAS") == "1":
-                # the AOT-validation path (tools/aot_check.py) exists
-                # to catch exactly this — a silent fallback here would
-                # record ok=true for a kernel that never compiled
-                raise
-            _logger.warning(
-                "paged_attention Mosaic kernel failed; falling back to the "
-                "reference gather implementation", exc_info=True)
+        blk = (pages_per_compute_block
+               or _compute_block_pages(page_indices.shape[1]))
+        return _jax_paged_attention(
+            (q * scale).astype(q.dtype), k_pages, v_pages,
+            lengths, page_indices,
+            pages_per_compute_block=blk,
+        )
     return _reference_paged_attention(q, k_pages, v_pages, lengths,
                                       page_indices, scale)
 

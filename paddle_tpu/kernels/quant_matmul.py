@@ -49,16 +49,14 @@ scalars; a vocab-sized scale row would not fit it anyway).
 from __future__ import annotations
 
 import functools
-import logging
 import math
+import os
 from typing import Optional, Tuple
 
 import numpy as np
 
 import jax
 import jax.numpy as jnp
-
-_logger = logging.getLogger("paddle_tpu.quant_matmul")
 
 QUANT_MODES = ("int8", "int8_block", "fp8")
 _I8MAX = 127.0
@@ -241,11 +239,9 @@ def _quant_matmul_pallas(x2, qw, scales, mode: str, block: int,
         # Mosaic's lane constraint: the x tile's trailing dim (KB) must
         # be 128-divisible or the FULL padded K. The diagnosis lives in
         # kernels/constraints.py so the static kernel-geometry pass
-        # (PTL092) and this runtime backstop can never disagree; the
-        # public wrapper turns the raise into a warned reference
-        # fallback (and the FORCE_PALLAS/AOT path into a loud failure).
-        # Interpret mode executes any geometry, so CPU CI still covers
-        # small blocks.
+        # (PTL092), the public wrapper's selection of the reference
+        # and this runtime backstop can never disagree. Interpret mode
+        # executes any geometry, so CPU CI still covers small blocks.
         from .constraints import int8_block_geometry_issue
 
         issue = int8_block_geometry_issue(K, KB)
@@ -309,21 +305,21 @@ def quantized_matmul(x, qw, scales, *, mode: str = "int8",
     N = qw.shape[1]
     x2 = x.reshape(-1, K)
     m = _pallas_mode()
-    if m is not None:
-        try:
-            out = _quant_matmul_pallas(x2, qw, scales, mode, int(block),
-                                       interpret=(m == "interpret"))
-            return out.reshape(tuple(lead) + (N,))
-        except Exception:  # noqa: BLE001 — a kernel regression must be loud
-            import os
+    if (m == "tpu" and mode == "int8_block"
+            and os.environ.get("PADDLE_TPU_FORCE_PALLAS") != "1"):
+        from .constraints import int8_block_geometry_issue
 
-            if os.environ.get("PADDLE_TPU_FORCE_PALLAS") == "1":
-                # AOT-validation contract: never record ok=true for a
-                # kernel that silently fell back
-                raise
-            _logger.warning(
-                "quantized_matmul Pallas kernel failed; falling back to "
-                "the reference dequantize+matmul", exc_info=True)
+        # the declared geometry rule (PTL092) SELECTS the reference for
+        # a block Mosaic cannot tile; under FORCE_PALLAS the kernel's
+        # own guard raises instead (PTL091)
+        if int8_block_geometry_issue(K, int(block)):
+            m = None
+    if m is not None:
+        # no retry on the reference: a kernel that fails to trace,
+        # lower or compile raises
+        out = _quant_matmul_pallas(x2, qw, scales, mode, int(block),
+                                   interpret=(m == "interpret"))
+        return out.reshape(tuple(lead) + (N,))
     out = _reference_quant_matmul(x2, qw, scales, mode, int(block))
     return out.reshape(tuple(lead) + (N,))
 

@@ -155,7 +155,6 @@ def _switch_moe(ctx, op, ins):
         out, aux = _moe_math(x2, wg, w1, b1, w2, b2, cap, act, 0, E)
         return {"Out": [out.reshape(x.shape)], "AuxLoss": [aux.reshape(1)]}
 
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     axes = dict(mesh.shape)
@@ -202,7 +201,7 @@ def _switch_moe(ctx, op, ins):
                                  ep_axis="ep")
             return out.reshape(xl.shape), aux.reshape(1)
 
-    out, aux = shard_map(
+    out, aux = jax.shard_map(
         local_fn, mesh=mesh,
         in_specs=(xspec, P(None, None), espec, bspec, espec, bspec),
         out_specs=(xspec, P()),

@@ -44,7 +44,6 @@ from __future__ import annotations
 
 import contextlib
 import logging
-import os
 import weakref
 from typing import Any, Dict, List, Optional, Tuple
 
@@ -490,7 +489,6 @@ def ensure_planned(program=None, params_grads=None, bucket_mb=None,
     plan = CollectivePlan(program, buckets, quant, qblock, mb)
     program._collective_plan = plan
     program._bump()
-    _maybe_enable_latency_hiding()
     _log.info(
         "collectives: planned %d bucket(s) over %d gradient(s) "
         "(cap %.1f MB, quantization=%s block=%d)",
@@ -498,54 +496,7 @@ def ensure_planned(program=None, params_grads=None, bucket_mb=None,
     return plan
 
 
-def _maybe_enable_latency_hiding() -> None:
-    """Best-effort: turn on XLA's latency-hiding scheduler so the
-    bucket collectives actually overlap the remaining backward. The
-    flag must be in XLA_FLAGS before the TPU backend initializes and
-    is TPU-only (the CPU/GPU flag parsers abort on unknown flags), so
-    it is appended only when the process is clearly headed for a TPU
-    backend and jax has not initialized one yet. Launchers that set
-    XLA_FLAGS themselves are left alone."""
-    want = "--xla_tpu_enable_latency_hiding_scheduler=true"
-    cur = os.environ.get("XLA_FLAGS", "")
-    if "xla_tpu_enable_latency_hiding_scheduler" in cur:
-        return
-    plat = os.environ.get("JAX_PLATFORMS", os.environ.get(
-        "JAX_PLATFORM_NAME", ""))
-    tpu_bound = "tpu" in plat.lower()
-    if not tpu_bound and not plat:
-        # standard Cloud TPU VMs leave the platform env unset and let
-        # jax autodetect the TPU via libtpu — detect it the same way
-        import importlib.util
-
-        tpu_bound = any(importlib.util.find_spec(m) is not None
-                        for m in ("libtpu", "libtpu_release"))
-    if not tpu_bound:
-        return
-    try:
-        from jax._src import xla_bridge as _xb
-
-        if getattr(_xb, "_backends", None):
-            _log.warning(
-                "collectives: jax backend already initialized — cannot "
-                "inject %s; set it in XLA_FLAGS at launch for "
-                "backward-overlapped collectives", want)
-            return
-    except Exception:  # noqa: BLE001 — private API drift: skip the check
-        pass
-    os.environ["XLA_FLAGS"] = (cur + " " + want).strip()
-
-
 # -- compile-time: the split + shard_map step builder -------------------------
-
-
-def _shard_map():
-    import jax
-
-    f = getattr(jax, "shard_map", None)
-    if f is None:
-        from jax.experimental.shard_map import shard_map as f
-    return f
 
 
 def _reads_of(ops) -> set:
@@ -847,21 +798,13 @@ def build_collective_fn(block, feed_names, state_names, fetch_names,
                     outs[i] = jax.lax.pmean(outs[i], axis)
             return tuple(outs)
 
-        smap = _shard_map()
         kwargs = dict(mesh=mesh, in_specs=(P(),) + tuple(in_specs),
-                      out_specs=tuple(out_specs), check_rep=False)
+                      out_specs=tuple(out_specs), check_vma=False)
         if auto:
-            kwargs["auto"] = auto
-        try:
-            sharded = smap(body, **kwargs)
-        except TypeError:
-            # newer jax: check_vma / axis_names spelling
-            kwargs.pop("check_rep", None)
-            kwargs.pop("auto", None)
-            kwargs["check_vma"] = False
-            if auto:
-                kwargs["axis_names"] = {axis}
-            sharded = smap(body, **kwargs)
+            # partial-manual: only the reduce axis is manual, the rest
+            # of the mesh stays GSPMD-auto inside the region
+            kwargs["axis_names"] = {axis}
+        sharded = jax.shard_map(body, **kwargs)
         outs = sharded(step_key, *(env[n] for n in seg1_in))
         env.update(zip(exports, outs))
 

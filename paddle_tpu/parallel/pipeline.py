@@ -25,13 +25,6 @@ import jax.numpy as jnp
 from jax import lax
 
 
-def _shard_map():
-    smap = getattr(jax, "shard_map", None)
-    if smap is None:
-        from jax.experimental.shard_map import shard_map as smap
-    return smap
-
-
 def _auto_axes_of(mesh, axis_name):
     return tuple(a for a in mesh.axis_names if a != axis_name)
 
@@ -46,9 +39,7 @@ def _pin_auto_replicated(tree, auto_axes):
     stuck on a dp2 x mp2 x pp2 CPU mesh). Pin every branch output to
     auto-replicated. A bare PartitionSpec resolves against the CONTEXT
     mesh (auto+manual axis types); a NamedSharding(mesh, ...) would
-    carry all-Auto types and fail the consistency check. (Only
-    reachable on the new shard_map API: _checked_shard_map rejects
-    legacy partial-manual up front.)"""
+    carry all-Auto types and fail the consistency check."""
     if not auto_axes:
         return tree
     from jax.sharding import PartitionSpec as _P
@@ -65,64 +56,6 @@ def _manual_axis_kwargs(mesh, axis_name, kwargs):
     if set(mesh.axis_names) != {axis_name}:
         kwargs["axis_names"] = {axis_name}
     return kwargs
-
-
-def _legacy_shard_map_kwargs(kwargs, mesh):
-    """Translate the current partial-manual spelling (axis_names={...},
-    the MANUAL axes) into the legacy jax.experimental.shard_map one
-    (auto=frozenset(...), the NON-manual axes). Pure so it is unit-
-    testable; no-op when axis_names is absent (full-manual mesh)."""
-    legacy = dict(kwargs)
-    axis_names = legacy.pop("axis_names", None)
-    if axis_names is not None:
-        legacy["auto"] = frozenset(mesh.axis_names) - set(axis_names)
-    return legacy
-
-
-def _checked_shard_map(per_device, mesh, kwargs, op="pipeline schedule",
-                       alternative=None):
-    """shard_map with replication/varying checks off, across jax
-    versions. New API first (check_vma + axis_names); the
-    jax.experimental fallback spells partial-manual as auto= and has
-    no axis_names/check_vma params, so kwargs are translated — on
-    older JAX the dp>1 pipeline used to TypeError on both retries
-    instead of working (round-5 advisor finding). Where the legacy
-    partial-manual path is still broken (its autodiff transpose
-    mis-specs scalar outputs), the opaque _SpecError is converted to a
-    diagnostic naming the exact op (``op``, from the call site) and
-    the supported alternative (``alternative``)."""
-    smap = _shard_map()
-    try:
-        return smap(per_device, check_vma=False, **kwargs)
-    except TypeError:
-        pass
-    legacy_kwargs = _legacy_shard_map_kwargs(kwargs, mesh)
-    if "axis_names" in kwargs:
-        # The auto= translation traces, but the legacy transpose
-        # mis-specs scalar outputs under autodiff (observed: _SpecError
-        # from value_and_grad over the dp>1 schedule) and that error
-        # surfaces OUTSIDE this wrapper where it cannot be labeled.
-        # Fail here, clearly, naming the op the caller was building.
-        raise NotImplementedError(
-            f"jax {jax.__version__}: {op} needs partial-manual "
-            f"shard_map (manual axes {sorted(kwargs['axis_names'])}, "
-            f"GSPMD-auto axes {sorted(legacy_kwargs['auto'])}), and "
-            "this jax only has the legacy jax.experimental.shard_map, "
-            "whose auto= spelling mis-specs scalar outputs under "
-            "autodiff. "
-            + (alternative or "Run the pipeline with dp=1 (full-manual "
-               "mesh, which the legacy API runs)")
-            + ", or upgrade jax to a version with the jax.shard_map "
-            "axis_names API.")
-    try:
-        return smap(per_device, check_rep=False, **legacy_kwargs)
-    except TypeError as e:
-        raise RuntimeError(
-            f"jax {jax.__version__}: {op}: shard_map accepts neither "
-            "the axis_names/check_vma API nor the legacy "
-            "auto=/check_rep one — this jax version is unsupported for "
-            "pipeline parallelism; upgrade jax"
-        ) from e
 
 
 def pipeline_apply(
@@ -201,9 +134,8 @@ def pipeline_apply(
         masked = jnp.where(idx == n_stages - 1, outs, jnp.zeros_like(outs))
         return lax.psum(masked, axis_name)
 
-    smap = _shard_map()
     pspec = jax.tree_util.tree_map(lambda _: P(axis_name), stage_params)
-    return smap(
+    return jax.shard_map(
         per_device,
         mesh=mesh,
         in_specs=(pspec, P()),
@@ -315,10 +247,7 @@ def pipeline_schedule(
     # dropped.
     kwargs = _manual_axis_kwargs(mesh, axis_name, {
         "mesh": mesh, "in_specs": (P(), P()), "out_specs": P()})
-    wrapped = _checked_shard_map(
-        per_device, mesh, kwargs,
-        op="pipeline_apply (GPipe forward schedule)",
-        alternative="Run pipeline_apply with a pp-only mesh (dp=1)")
+    wrapped = jax.shard_map(per_device, check_vma=False, **kwargs)
     return wrapped(params, feeds_mb)
 
 
@@ -473,11 +402,7 @@ def pipeline_schedule_1f1b(
     kwargs = _manual_axis_kwargs(mesh, axis_name, {
         "mesh": mesh, "in_specs": (P(), P(), P(), P()),
         "out_specs": (P(), P())})
-    wrapped = _checked_shard_map(
-        per_device, mesh, kwargs,
-        op="pipeline_schedule_1f1b (1F1B forward/backward schedule)",
-        alternative="Run the 1F1B schedule with a pp-only mesh (dp=1), "
-        "or use the GPipe path (CompiledProgram.with_pipeline)")
+    wrapped = jax.shard_map(per_device, check_vma=False, **kwargs)
     return wrapped(diff_params, tuple(rest_params), feeds_mb,
                    jnp.asarray(grad_scale, jnp.float32))
 
@@ -583,7 +508,7 @@ def pipeline_train_step_3d(
         global_n = outs.size * dp
         return lax.psum(local_sum, dp_axis) / global_n
 
-    smap = _shard_map()
+    smap = jax.shard_map
     mb_spec = P(None, dp_axis)
 
     def step(stage_params, microbatches, targets):
@@ -723,9 +648,7 @@ def pipeline_train_step_1f1b(
             "in_specs": (pspec, P(), P()),
             "out_specs": (P(), pspec),
         }
-        wrapped = _checked_shard_map(
-            per_device, mesh, kwargs,
-            op="pipeline_train_step (stacked-stage train step)")
+        wrapped = jax.shard_map(per_device, check_vma=False, **kwargs)
         return wrapped(stage_params, microbatches, targets)
 
     return step

@@ -95,10 +95,7 @@ def make_ring_attention_fn(mesh, axis_name: str = "sp", causal: bool = False,
     additive [B, S] key mask sharded on S)."""
     from jax.sharding import PartitionSpec as P
 
-    smap = getattr(jax, "shard_map", None)
-    if smap is None:
-        from jax.experimental.shard_map import shard_map as smap
-
+    smap = jax.shard_map
     spec = P(None, None, axis_name, None)
     mspec = P(None, axis_name)
     core = functools.partial(ring_attention, axis_name=axis_name,

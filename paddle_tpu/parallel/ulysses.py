@@ -82,10 +82,7 @@ def make_ulysses_attention_fn(mesh, axis_name: str = "sp",
 
     from jax.sharding import PartitionSpec as P
 
-    smap = getattr(jax, "shard_map", None)
-    if smap is None:
-        from jax.experimental.shard_map import shard_map as smap
-
+    smap = jax.shard_map
     spec = P(None, None, axis_name, None)
     core = functools.partial(ulysses_attention, axis_name=axis_name,
                              causal=causal, sm_scale=sm_scale)

@@ -24,12 +24,11 @@ Level 2 — compilation caching:
     program fingerprint (content hash, not object identity), so
     multiple `Executor` instances — the PS/hogwild/predictor
     clone-per-thread patterns — stop re-jitting the same program;
-  * the persistent on-disk XLA compilation cache
-    (`jax_compilation_cache_dir`) wired behind the live flag
-    `compile_cache_dir`, so a NEW PROCESS re-running an already-seen
-    program deserializes the executable instead of re-compiling —
-    compile cost amortizes across exactly the scarce TPU windows the
-    project keeps losing.
+  * jax's persistent on-disk compilation cache, kept where
+    `JAX_COMPILATION_CACHE_DIR` says or else at one fixed path inside
+    the checkout (`ensure_persistent_cache`), so a NEW PROCESS
+    re-running an already-seen program deserializes the executable
+    instead of re-compiling.
 
 Counters for all of it are surfaced via `Executor.cache_stats()` and
 the profiler host-event log (compiles show up as named ranges in
@@ -70,8 +69,14 @@ _GLOBAL_STATS: Dict[str, Any] = {
     "compile_time_s": 0.0,      # first-call time: trace + XLA compile (+1 step)
 }
 
+# the persistent cache's directory when JAX_COMPILATION_CACHE_DIR is not
+# set: resolved from the package's location, because the path is part
+# of the cache key — a home directory, a temporary name or a pid would
+# never hit again on the next machine
+DEFAULT_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)))), ".jax_cache")
 _PERSISTENT_DIR: Optional[str] = None
-_PERSISTENT_FAILED_PATH: Optional[str] = None
 
 # every live BoundStep in the process — the donation/host-sync audit
 # (tools/donation_audit.py) walks this to prove each subsystem's
@@ -87,62 +92,30 @@ def live_bound_steps() -> List["BoundStep"]:
     return list(_LIVE_BOUND)
 
 
-def ensure_persistent_cache() -> Optional[str]:
-    """Apply the `compile_cache_dir` flag to jax's persistent
-    compilation cache (idempotent; re-applies when the flag changes).
-    Returns the active directory or None when disabled/unavailable."""
-    global _PERSISTENT_DIR, _PERSISTENT_FAILED_PATH
-    from ..flags import flag
-
-    raw = flag("compile_cache_dir")
-    if not raw:
-        return _PERSISTENT_DIR
-    path = os.path.expanduser(raw)
-    # skip only paths already applied or already KNOWN bad — a flag
-    # pointed at a new directory always gets a fresh attempt
-    if path == _PERSISTENT_DIR or path == _PERSISTENT_FAILED_PATH:
+def ensure_persistent_cache() -> str:
+    """Turn on jax's persistent compilation cache (idempotent) and
+    return its directory. ``JAX_COMPILATION_CACHE_DIR``, when set, is
+    the directory — jax reads it at import and nothing here touches
+    it. Otherwise the cache is ``DEFAULT_CACHE_DIR``, one fixed path
+    inside the checkout. An unusable directory raises."""
+    global _PERSISTENT_DIR
+    if _PERSISTENT_DIR is not None:
         return _PERSISTENT_DIR
     import jax
 
-    try:
-        os.makedirs(path, exist_ok=True)
+    path = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if not path:
+        path = DEFAULT_CACHE_DIR
         jax.config.update("jax_compilation_cache_dir", path)
-        # jax latches its cache singleton at the FIRST compile in the
-        # process: if anything jitted before this flag was applied
-        # (e.g. the weight-dtype convert during model load), the
-        # singleton initialized with no directory and silently ignores
-        # the config forever. Reset unconditionally so the next
-        # compile re-initializes against the directory just applied
-        # (private API — best-effort on future jax).
-        try:
-            from jax._src import compilation_cache as _cc
-
-            _cc.reset_cache()
-        except Exception:  # noqa: BLE001
-            pass
-        # default thresholds skip small/fast compiles — a framework
-        # whose unit of compilation is the WHOLE train step wants
-        # every executable persisted, including the tiny eval/infer
-        # programs that dominate cold-start counts
-        for knob, val in (
-            ("jax_persistent_cache_min_entry_size_bytes", -1),
-            ("jax_persistent_cache_min_compile_time_secs", 0.0),
-        ):
-            try:
-                jax.config.update(knob, val)
-            except Exception:  # noqa: BLE001 — knob absent on old jax
-                pass
-        _PERSISTENT_DIR = path
-    except OSError as e:
-        # read-only HOME / container without the dir: dispatch caching
-        # still works, only cross-process persistence is lost
-        _PERSISTENT_FAILED_PATH = path
-        import sys
-
-        sys.stderr.write(
-            f"[paddle_tpu] compile_cache_dir {path!r} unusable ({e}); "
-            "persistent compilation cache disabled\n")
-    return _PERSISTENT_DIR
+    os.makedirs(path, exist_ok=True)
+    # the default thresholds skip small/fast compiles — a framework
+    # whose unit of compilation is the WHOLE train step wants every
+    # executable persisted, including the tiny eval/infer programs
+    # that dominate cold-start counts
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    _PERSISTENT_DIR = path
+    return path
 
 
 def persistent_cache_dir() -> Optional[str]:
@@ -400,14 +373,18 @@ class BoundStep:
         "executor", "compiled", "scope", "block", "base_key",
         "feed_plan", "state_vals", "written_into_state", "scope_gen",
         "n_fetch", "benchmark", "obs_tel", "trace", "rows_hint",
-        "host_sync_calls", "state_globalize", "__weakref__",
+        "host_sync_calls", "feed_avals", "__weakref__",
     )
 
-    def __init__(self, executor, compiled, scope, block, raw_dtypes):
+    def __init__(self, executor, compiled, scope, block, raw_dtypes,
+                 feed_avals=None):
         from ..flags import flag
 
         self.executor = executor
         self.compiled = compiled
+        # the normalized feed signature this step was bound for, in
+        # compiled.feed_names order (aot_compiled lowers against it)
+        self.feed_avals = feed_avals
         self.scope = scope
         self.block = block
         self.benchmark = bool(flag("benchmark"))
@@ -442,18 +419,12 @@ class BoundStep:
         # the zero-overhead plan above.
         from ..distributed.coordinator import spans_processes
 
-        self.state_globalize = None
-        if spans_processes(compiled.mesh):
-            if compiled.feed_shardings:
-                self.feed_plan = [
-                    (n, _globalizing_normalizer(
-                        norm, compiled.feed_shardings.get(n)))
-                    for n, norm in self.feed_plan
-                ]
-            # host-value state (startup init, a restored checkpoint)
-            # is identical on every process; assemble it onto the
-            # global mesh per each var's sharding at resolve time
-            self.state_globalize = compiled.state_sharding_by_name
+        if spans_processes(compiled.mesh) and compiled.feed_shardings:
+            self.feed_plan = [
+                (n, _globalizing_normalizer(
+                    norm, compiled.feed_shardings.get(n)))
+                for n, norm in self.feed_plan
+            ]
         self.n_fetch = len(compiled.fetch_names)
         # positions of written state inside the state arg list (for the
         # in-place cached-ref update after each step); written names
@@ -486,6 +457,14 @@ class BoundStep:
         # snapshot BEFORE the walk: a concurrent set_var mid-walk must
         # leave the counters unequal so the next step re-resolves
         gen = scope_chain_generation(scope)
+        # mesh-bound executable: state (startup init on the default
+        # device, a restored checkpoint's host values) is placed per
+        # each var's compiled sharding here, BEFORE the first call.
+        # Left where startup put it, the first call would trace against
+        # single-device types and the second — fed the first's
+        # mesh-sharded outputs — would trace and compile the whole
+        # step again (jax types carry the mesh).
+        shardings = self.compiled.state_sharding_by_name
         vals = []
         for n in self.compiled.state_names:
             v = scope.find_var(n)
@@ -498,25 +477,28 @@ class BoundStep:
                     f"persistable var {n!r} not found in scope — run the "
                     "startup program first"
                 )
-            if self.state_globalize is not None:
-                v = self._globalize_state(n, v)
+            if shardings:
+                v = self._place_state(shardings.get(n), v)
             vals.append(v)
         self.state_vals = vals
         self.scope_gen = gen
 
-    def _globalize_state(self, name, v):
-        """Multi-host mesh only: a host-value state var (startup init
-        or a restored checkpoint — identical on every process by the
-        deterministic-replay contract) becomes one global jax.Array
-        per its compiled sharding. Already-global arrays (the previous
-        step's outputs) pass through."""
+    @staticmethod
+    def _place_state(sharding, v):
+        """One state var as a global jax.Array laid out per its
+        compiled sharding. Arrays already laid out that way (the
+        previous step's outputs) pass through; on a mesh spanning
+        processes a host value (identical on every process by the
+        deterministic-replay contract) is assembled from each
+        process's copy."""
         import jax
 
-        if isinstance(v, jax.Array):
-            return v
-        sharding = self.state_globalize.get(name)
         if sharding is None:
             return v
+        if isinstance(v, jax.Array):
+            if v.sharding == sharding or not v.is_fully_addressable:
+                return v
+            return jax.device_put(v, sharding)
         arr = np.asarray(v)
         if getattr(sharding, "is_fully_addressable", True):
             return jax.device_put(arr, sharding)
@@ -779,6 +761,20 @@ class BoundStep:
             "xla_analysis": dict(getattr(c, "analysis", None) or {}),
         }
 
+    def aot_compiled(self):
+        """The jax compiled object of this step for the feed signature
+        it was bound with: ``.as_text()`` is the optimized HLO the
+        device runs, ``.memory_analysis()``/``.cost_analysis()`` the
+        target's own accounting. jax exposes these only on an
+        AOT-compiled object, not on the jit path, so this costs one
+        lower + compile — with the persistent compilation cache on, a
+        deserialization of what the first step compiled."""
+        if self.scope_gen != scope_chain_generation(self.scope):
+            self._resolve_state()
+        return self.compiled.fn.lower(
+            self.base_key, np.int32(0), *self.feed_avals,
+            *self.state_vals).compile()
+
     def _first_call(self, fn, counter, ordered):
         """First invocation of a fresh compiled block: this is where
         jax traces + XLA compiles. Timed, counted, and surfaced as a
@@ -800,25 +796,22 @@ class BoundStep:
         _GLOBAL_STATS["compile_time_s"] += dt
         ex = self.executor
         ex._stats["compile_time_s"] = ex._stats.get("compile_time_s", 0.0) + dt
-        self._xla_analysis(fn, counter, ordered)
+        self._xla_analysis()
         return outs
 
-    def _xla_analysis(self, fn, counter, ordered):
+    def _xla_analysis(self):
         """Per-executable XLA ``memory_analysis()``/``cost_analysis()``
         surfaced as registry gauges (labeled by executable tag) and a
         flight-recorder entry. Behind ``observability_xla_analysis``:
-        it costs one extra lower+compile per executable (jax exposes
-        the analyses only on an AOT-compiled object, not on the jit
-        path that just ran — the persistent compilation cache makes
-        the recompile a deserialization in practice). Every sub-step
-        is best-effort: backends expose different analysis subsets."""
+        it costs one extra lower+compile per executable
+        (``aot_compiled``). Every sub-step is best-effort: backends
+        expose different analysis subsets."""
         from ..flags import flag
 
         if not flag("observability_xla_analysis"):
             return
         try:
-            comp = fn.lower(self.base_key, counter, *ordered,
-                            *self.state_vals).compile()
+            comp = self.aot_compiled()
         except Exception:  # noqa: BLE001 — analysis must never fail a step
             return
         vals: Dict[str, float] = {}

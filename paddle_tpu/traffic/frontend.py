@@ -13,9 +13,10 @@ The production shape is N worker PROCESSES behind one port:
   ports, for platforms without SO_REUSEPORT and for tests that need
   deterministic routing. Backends can be swapped live
   (``set_backends``) — that is the drain hook.
-* **Warm start**: every worker applies the PR-2 persistent compile
-  cache (``compile_cache_dir``) BEFORE building its predictor, so the
-  first worker populates the cache and every later worker (including
+* **Warm start**: every worker uses the process-wide persistent
+  compile cache (``JAX_COMPILATION_CACHE_DIR``, inherited through
+  spawn, or the fixed in-checkout directory), so the first worker
+  populates the cache and every later worker (including
   rolling-restart replacements) loads serialized executables instead
   of recompiling. Workers report their measured warmup time and the
   process-wide jit-compile count so the harness can PROVE the warm
@@ -61,9 +62,10 @@ def _free_port(host: str = "127.0.0.1") -> int:
 def _worker_main(spec: Dict[str, Any], conn) -> None:
     """Entry point of one worker process (spawned, so this re-imports
     the stack from scratch — exactly what a fleet rollout does)."""
-    # the child must resolve the same backend as the parent; JAX env
-    # (JAX_PLATFORMS etc.) rides os.environ through spawn
-    os.environ.setdefault("JAX_PLATFORMS", "cpu")
+    # the child resolves the same backend as the parent: JAX env
+    # (JAX_PLATFORMS etc.) rides os.environ through spawn, and nothing
+    # is set here. CPU only today — on a chip the parent must stay off
+    # JAX and each worker needs its own device (README "Running").
     # fleet identity + the parent's trace context, stamped at spawn:
     # PADDLE_WORKER_ID labels every span this process records (the
     # process-lane key in assembled traces) and PADDLE_TRACE_CONTEXT
@@ -82,8 +84,6 @@ def _worker_main(spec: Dict[str, Any], conn) -> None:
     from paddle_tpu.traffic import TrafficConfig, TrafficController
 
     try:
-        if spec.get("compile_cache_dir"):
-            fluid.set_flags({"compile_cache_dir": spec["compile_cache_dir"]})
         if spec.get("flags"):
             fluid.set_flags(dict(spec["flags"]))
         with tracing.attach(propagate.from_env()), \
@@ -312,7 +312,6 @@ class WorkerPool:
     def __init__(self, model_dir: str, num_workers: int = 2,
                  host: str = "127.0.0.1", port: int = 0, *,
                  use_reuseport: Optional[bool] = None,
-                 compile_cache_dir: Optional[str] = None,
                  batch_buckets: Optional[List[int]] = None,
                  warmup_shapes: Optional[Dict[str, List[int]]] = None,
                  engine_kwargs: Optional[Dict[str, Any]] = None,
@@ -334,7 +333,6 @@ class WorkerPool:
         self.ready_timeout_s = float(ready_timeout_s)
         self._spec_base: Dict[str, Any] = {
             "model_dir": model_dir, "host": host,
-            "compile_cache_dir": compile_cache_dir,
             "batch_buckets": list(batch_buckets or []),
             "warmup_shapes": dict(warmup_shapes or {}),
             "engine_kwargs": dict(engine_kwargs or {}),

@@ -2,9 +2,8 @@
 like the scale proofs: a full run recompiles every Pallas kernel plus
 the headline BERT step with libtpu's Mosaic/XLA pipeline (~10 min), so
 it only runs with PT_AOT_CHECK=1; AOT_TPU_CHECK.json archives the
-committed result (round-5: this is how the flash mask and layer_norm
-backward block-spec rejections were found and fixed without a live
-relay window)."""
+committed result (this is how the flash mask and layer_norm backward
+block-spec rejections were found and fixed without a chip)."""
 
 import json
 import os

@@ -407,7 +407,6 @@ def test_quantized_mean_psum_form_matches_exchange_form():
     transport."""
     import jax
     import jax.numpy as jnp
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import Mesh, PartitionSpec as P
 
     devs = np.array(jax.devices()[:8])
@@ -420,8 +419,8 @@ def test_quantized_mean_psum_form_matches_exchange_form():
             return quant.quantized_mean(v[0], "dp", 8, 64,
                                         exchange=exchange)[None]
 
-        f = shard_map(body, mesh=mesh, in_specs=(P("dp"),),
-                      out_specs=P("dp"), check_rep=False)
+        f = jax.shard_map(body, mesh=mesh, in_specs=(P("dp"),),
+                          out_specs=P("dp"), check_vma=False)
         return np.asarray(jax.jit(f)(jnp.asarray(x)))
 
     a, b = run(True), run(False)

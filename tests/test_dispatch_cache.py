@@ -1,7 +1,7 @@
 """Hot-path dispatch + compilation caching (runtime/dispatch):
 counters, cross-executor compile sharing, device-array fetches,
-stale-scope invalidation, persistent-cache flag wiring, sharded-feed
-validation, legacy shard_map kwarg translation."""
+stale-scope invalidation, persistent-cache location, sharded-feed
+validation, partial-manual shard_map kwargs."""
 
 import os
 
@@ -158,35 +158,30 @@ def test_scope_updates_seen_across_programs_sharing_scope():
         assert evals[0] > evals[1] > evals[2], evals
 
 
-def test_persistent_cache_flag_round_trip(tmp_path):
+def test_persistent_cache_is_where_the_environment_says():
+    """JAX_COMPILATION_CACHE_DIR (conftest sets it) is the persistent
+    cache: a bind neither moves jax's setting nor reports another
+    directory, and executables land there. (The unset case — the fixed
+    in-checkout path — needs a fresh process: tests/test_chip_smoke.py.)
+    """
     import jax
 
-    cache_dir = str(tmp_path / "xla_cache")
-    old = fluid.get_flags("compile_cache_dir")["compile_cache_dir"]
-    fluid.set_flags({"compile_cache_dir": cache_dir})
-    try:
-        assert (fluid.get_flags("FLAGS_compile_cache_dir")
-                ["FLAGS_compile_cache_dir"] == cache_dir)
-        # a UNIQUE model: anything already in the in-memory shared
-        # cache would skip XLA entirely and write nothing to disk
-        main, startup = fluid.Program(), fluid.Program()
-        with fluid.program_guard(main, startup), fluid.unique_name.guard():
-            x = fluid.layers.data("x", [13])
-            loss = fluid.layers.mean(fluid.layers.fc(x, 13))
-        scope = fluid.Scope()
-        with fluid.scope_guard(scope):
-            exe = fluid.Executor(fluid.CPUPlace())
-            exe.run(startup)
-            exe.run(main,
-                    feed={"x": np.ones((2, 13), "float32")},
-                    fetch_list=[loss])
-        assert jax.config.jax_compilation_cache_dir == cache_dir
-        assert os.path.isdir(cache_dir)
-        assert os.listdir(cache_dir), "no executables persisted"
-        assert (exe.cache_stats()["process"]["persistent_cache_dir"]
-                == cache_dir)
-    finally:
-        fluid.set_flags({"compile_cache_dir": old})
+    cache_dir = os.environ["JAX_COMPILATION_CACHE_DIR"]
+    main, startup = fluid.Program(), fluid.Program()
+    with fluid.program_guard(main, startup), fluid.unique_name.guard():
+        x = fluid.layers.data("x", [13])
+        loss = fluid.layers.mean(fluid.layers.fc(x, 13))
+    scope = fluid.Scope()
+    with fluid.scope_guard(scope):
+        exe = fluid.Executor(fluid.CPUPlace())
+        exe.run(startup)
+        exe.run(main,
+                feed={"x": np.ones((2, 13), "float32")},
+                fetch_list=[loss])
+    assert jax.config.jax_compilation_cache_dir == cache_dir
+    assert os.listdir(cache_dir), "no executables persisted"
+    assert (exe.cache_stats()["process"]["persistent_cache_dir"]
+            == cache_dir)
 
 
 def test_program_mutation_invalidates_bound_step():
@@ -276,15 +271,14 @@ def test_with_pipeline_static_batch_validation():
         cp.with_pipeline(dp=2)
 
 
-def test_legacy_shard_map_kwarg_translation():
-    """axis_names (new partial-manual spelling) translates to the
-    legacy auto=frozenset(non-manual axes) kwarg (ADVICE.md)."""
+def test_manual_axis_kwargs_partial_manual_only():
+    """axis_names (the partial-manual spelling) is passed only when the
+    mesh has axes beside the pipeline axis."""
     import jax
     import numpy as _np
     from jax.sharding import Mesh
 
-    from paddle_tpu.parallel.pipeline import (
-        _legacy_shard_map_kwargs, _manual_axis_kwargs)
+    from paddle_tpu.parallel.pipeline import _manual_axis_kwargs
 
     devs = jax.devices()
     if len(devs) < 4:
@@ -292,14 +286,9 @@ def test_legacy_shard_map_kwarg_translation():
     mesh = Mesh(_np.array(devs[:4]).reshape(2, 2), ("dp", "pp"))
     kwargs = _manual_axis_kwargs(mesh, "pp", {"mesh": mesh})
     assert kwargs["axis_names"] == {"pp"}
-    legacy = _legacy_shard_map_kwargs(kwargs, mesh)
-    assert "axis_names" not in legacy
-    assert legacy["auto"] == frozenset({"dp"})
-    # full-manual mesh: no axis_names, translation is a no-op
     mesh1 = Mesh(_np.array(devs[:2]), ("pp",))
-    kwargs1 = _manual_axis_kwargs(mesh1, "pp", {"mesh": mesh1})
-    assert "axis_names" not in kwargs1
-    assert "auto" not in _legacy_shard_map_kwargs(kwargs1, mesh1)
+    assert "axis_names" not in _manual_axis_kwargs(mesh1, "pp",
+                                                   {"mesh": mesh1})
 
 
 def test_predictor_pad_feed_skips_static_dim1(tmp_path):

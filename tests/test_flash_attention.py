@@ -72,21 +72,18 @@ def test_flash_residuals_are_linear_in_seq(interpret_mode):
     assert max_leaf <= B * H * S * max(D, fa.LANES), max_leaf
 
 
-def test_flash_fallback_is_logged(monkeypatch, caplog):
-    """A Pallas regression must WARN, not silently swap in the naive
-    kernel (round-1 verdict weak #6)."""
-    import logging
-
+def test_flash_pallas_failure_raises(monkeypatch):
+    """A Pallas regression must RAISE, not swap in the naive kernel —
+    the reference is the path where no Pallas mode applies, never a
+    retry (the tpu-mode twin lives in tests/test_chip_smoke.py)."""
     monkeypatch.setenv("PADDLE_TPU_FLASH_INTERPRET", "1")
     monkeypatch.setattr(
         fa, "_flash_fwd_pallas",
         lambda *a, **k: (_ for _ in ()).throw(RuntimeError("boom")),
     )
     q = k = v = _rand((1, 1, 128, 64), 0)
-    with caplog.at_level(logging.WARNING, logger="paddle_tpu.flash_attention"):
-        out = fa.flash_attention(q, k, v, False, None)
-    assert np.isfinite(np.asarray(out)).all()
-    assert any("falling back" in r.message for r in caplog.records)
+    with pytest.raises(RuntimeError, match="boom"):
+        fa.flash_attention(q, k, v, False, None)
 
 
 def _numpy_masked_attention(q, k, v, mask_add, bias, causal, scale):

@@ -32,8 +32,10 @@ not guessed):
      ``reader_prefetch_depth`` — no GeneratorLoader in the loop) are
      deliberately NOT written to the profile.
 
-The profile lands under ``~/.cache/paddle_tpu/autotune/`` (the
-``autotune_dir`` flag) keyed by ``runtime.dispatch
+The profile lands under the ``autotune_dir`` flag — ``.autotune/`` in
+the checkout when the flag names no directory; a later run consumes it
+only when ITS ``autotune_dir`` flag names that directory — keyed by
+``runtime.dispatch
 .program_fingerprint`` of the TRAIN program — content-derived, so a
 fresh process building the same workload computes the same key and
 finds its profile. Scope note: the serving/generation knobs in a
@@ -70,7 +72,6 @@ REPO = os.path.dirname(HERE)
 sys.path.insert(0, REPO)
 
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
-os.environ.setdefault("JAX_PLATFORM_NAME", "cpu")
 
 # gradient all-reduce bucketing target: enough buckets that the first
 # reduce becomes data-ready mid-backward, few enough that each bucket
@@ -470,6 +471,10 @@ def main():
     if args.measure_one:
         return measure_one(args.measure_one, args.steps or 24)
 
+    from paddle_tpu import flags as pflags
+
+    if not pflags.autotune_dir():
+        pflags.set_flags({"autotune_dir": os.path.join(REPO, ".autotune")})
     steps = args.steps or (24 if args.smoke else 48)
     t0 = time.time()
     report, fingerprint = tune(steps=steps, smoke=args.smoke)
@@ -477,8 +482,6 @@ def main():
     ok = True
 
     if args.smoke:
-        from paddle_tpu import flags as pflags
-
         adir = pflags.autotune_dir()
         default_run = _spawn_measure("default", steps, adir)
         tuned_run = _spawn_measure("tuned", steps, adir)

@@ -42,7 +42,6 @@ HERE = os.path.dirname(os.path.abspath(__file__))
 sys.path.insert(0, os.path.dirname(HERE))
 
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
-os.environ.setdefault("JAX_PLATFORM_NAME", "cpu")
 _xla_flags = os.environ.get("XLA_FLAGS", "")
 if "xla_force_host_platform_device_count" not in _xla_flags:
     os.environ["XLA_FLAGS"] = (

@@ -1,8 +1,7 @@
 #!/usr/bin/env python
 """Donation / host-sync audit over every bound executable.
 
-The MFU headline (BENCH_r04/r05.json) says the device is ~idle; the
-two silent ways a framework re-creates that state are (a) state
+The two silent ways a framework leaves the device idle are (a) state
 buffers that stop being donated — every step then materializes a second
 copy of the parameters and pays an HBM round trip the reference's
 in-place ParamOut update never did — and (b) host-sync points creeping
@@ -49,7 +48,6 @@ HERE = os.path.dirname(os.path.abspath(__file__))
 sys.path.insert(0, os.path.dirname(HERE))
 
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
-os.environ.setdefault("JAX_PLATFORM_NAME", "cpu")
 # the partition phase audits MESH-bound executables (sharded train
 # state must donate exactly like unsharded) — force 8 host devices so
 # a dp4 x tp2 mesh exists on the CPU CI runner

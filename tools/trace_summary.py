@@ -1,9 +1,8 @@
-"""Summarize a jax-profiler trace into the dispatch-vs-compute
-breakdown the round-4 verdict asked for (weak #1: "nobody has profiled
-a single step on chip").
+"""Summarize a jax-profiler trace (bench.py's PT_BENCH_TRACE_DIR) into
+a dispatch-vs-compute breakdown.
 
 Usage:
-    python tools/trace_summary.py .bench_evidence/profile [out.json]
+    python tools/trace_summary.py <trace dir> [out.json]
 
 Walks every `*.trace.json.gz` (perfetto/chrome-trace export) under the
 directory and reports, per trace: wall span, busy time and top ops per
@@ -86,8 +85,6 @@ def main(root, out_path=None):
 
 
 if __name__ == "__main__":
-    sys.exit(main(sys.argv[1] if len(sys.argv) > 1 else
-                  os.path.join(os.path.dirname(os.path.dirname(
-                      os.path.abspath(__file__))),
-                      ".bench_evidence", "profile"),
-                  sys.argv[2] if len(sys.argv) > 2 else None))
+    if len(sys.argv) < 2:
+        sys.exit("usage: trace_summary.py <trace dir> [out.json]")
+    sys.exit(main(sys.argv[1], sys.argv[2] if len(sys.argv) > 2 else None))

@@ -88,7 +88,6 @@ sys.path.insert(0, os.path.dirname(HERE))
 sys.path.insert(0, HERE)
 
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
-os.environ.setdefault("JAX_PLATFORM_NAME", "cpu")
 
 
 # -- arrival processes -------------------------------------------------------
@@ -1199,14 +1198,13 @@ def run_rolling_restart(tmp_dir, model_dir, spec):
 
     from paddle_tpu.traffic import WorkerPool
 
-    cache_dir = os.path.join(tmp_dir, "compile_cache")
     pool = WorkerPool(
-        model_dir, num_workers=spec["workers"],
-        compile_cache_dir=cache_dir, batch_buckets=[1, 4],
+        model_dir, num_workers=spec["workers"], batch_buckets=[1, 4],
         warmup_shapes={"x": [1, 16]},
         engine_kwargs={"max_batch_size": 4, "batch_timeout_ms": 2,
                        "num_workers": 1},
         use_reuseport=spec.get("use_reuseport"))
+    cache_dir = pool.workers[0].info["persistent_cache_dir"]
     x = np.zeros((1, 16), np.float32).tolist()
     body = json.dumps({"inputs": {"x": x}}).encode()
     stop = threading.Event()
