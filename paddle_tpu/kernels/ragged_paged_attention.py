@@ -42,13 +42,15 @@ including PADDLE_TPU_KERNEL_INTERPRET=1, which runs the real kernel
 body in interpreter mode. The reference is the numerics oracle AND the
 CPU-CI execution path.
 
-Precision on the chip: K/V pages and the online-softmax accumulators
-are float32, but the two matmuls (q.k^T, p.v) are float32 dots at the
-TPU's DEFAULT matmul precision — Mosaic rounds their operands to bf16
-for one MXU pass, exactly what XLA does to every other float32 matmul
-of the step program. Against a "highest"-precision reference that is
-~2.5e-3 relative (measured on a v5e, chip_smoke.py), not float32's
-1e-6: CPU numerics are NOT the chip's numerics to the last bit.
+Precision on the chip: the kernel computes in float32 whatever the
+pages hold — operands are up-cast, the online-softmax accumulators are
+float32, and the two matmuls (q.k^T, p.v) ask Mosaic for
+Precision.HIGHEST (float32 contraction, several MXU passes) instead of
+the TPU default, which rounds float32 operands to bf16 for one pass.
+The first chip run measured that default at 2.5e-3 relative against a
+"highest"-precision reference; chip_smoke.py's kernel phase holds this
+kernel to the float32 bound (2e-5), so a bf16 rounding anywhere in the
+attend path fails there.
 """
 
 from __future__ import annotations
@@ -64,6 +66,8 @@ from .quant import blockwise_dequantize, blockwise_quantize
 
 NEG_INF = -1e30
 LANES = 128  # TPU minor tile; m/l scratch is lane-replicated
+# the TPU default rounds float32 dot operands to bf16 (module docstring)
+_F32_DOT = jax.lax.Precision.HIGHEST
 
 
 def _pallas_mode() -> Optional[str]:
@@ -158,7 +162,7 @@ def _make_ragged_kernel(C: int, ps: int, maxp: int, sm_scale: float,
                 k = k * ks_ref[0, 0].astype(jnp.float32)
                 v = v * vs_ref[0, 0].astype(jnp.float32)
             s = jax.lax.dot_general(
-                q, k, (((1,), (1,)), ((), ())),
+                q, k, (((1,), (1,)), ((), ())), precision=_F32_DOT,
                 preferred_element_type=jnp.float32)            # [C, ps]
             kpos = p * ps + jax.lax.broadcasted_iota(
                 jnp.int32, (C, ps), 1)
@@ -175,7 +179,8 @@ def _make_ragged_kernel(C: int, ps: int, maxp: int, sm_scale: float,
                 l_ref.shape)
             m_ref[...] = jnp.broadcast_to(m_next[:, None], m_ref.shape)
             acc_ref[...] = acc_ref[...] * alpha[:, None] + jnp.dot(
-                pexp, v, preferred_element_type=jnp.float32)
+                pexp, v, precision=_F32_DOT,
+                preferred_element_type=jnp.float32)
 
         @pl.when(p == maxp - 1)
         def finish():  # noqa: ANN202
@@ -266,8 +271,8 @@ def ragged_paged_attention(q, k_pages, v_pages, start_pos, num_valid,
     attends keys 0 .. start_pos[b] + j (the chunk's own K/V has been
     written by kv_cache_write before this op in every program). The
     softmax scale (default 1/sqrt(D)) applies to q identically on both
-    paths; on the chip the matmuls run at the default (one bf16 pass)
-    precision — see the module docstring.
+    paths, and the kernel's matmuls are float32 on the chip too
+    (module docstring).
     """
     B, C, H, D = q.shape
     scale = sm_scale if sm_scale is not None else 1.0 / math.sqrt(D)

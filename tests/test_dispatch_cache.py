@@ -158,32 +158,6 @@ def test_scope_updates_seen_across_programs_sharing_scope():
         assert evals[0] > evals[1] > evals[2], evals
 
 
-def test_persistent_cache_is_where_the_environment_says():
-    """JAX_COMPILATION_CACHE_DIR (conftest sets it) is the persistent
-    cache: a bind neither moves jax's setting nor reports another
-    directory, and executables land there. (The unset case — the fixed
-    in-checkout path — needs a fresh process: tests/test_chip_smoke.py.)
-    """
-    import jax
-
-    cache_dir = os.environ["JAX_COMPILATION_CACHE_DIR"]
-    main, startup = fluid.Program(), fluid.Program()
-    with fluid.program_guard(main, startup), fluid.unique_name.guard():
-        x = fluid.layers.data("x", [13])
-        loss = fluid.layers.mean(fluid.layers.fc(x, 13))
-    scope = fluid.Scope()
-    with fluid.scope_guard(scope):
-        exe = fluid.Executor(fluid.CPUPlace())
-        exe.run(startup)
-        exe.run(main,
-                feed={"x": np.ones((2, 13), "float32")},
-                fetch_list=[loss])
-    assert jax.config.jax_compilation_cache_dir == cache_dir
-    assert os.listdir(cache_dir), "no executables persisted"
-    assert (exe.cache_stats()["process"]["persistent_cache_dir"]
-            == cache_dir)
-
-
 def test_program_mutation_invalidates_bound_step():
     """Appending an op bumps program.version: the bound path must not
     serve the stale executable."""
