@@ -17,7 +17,9 @@ in this order:
            save_inference_model, loaded with create_predictor, served
            by the ragged GenerationEngine behind ServingServer's
            POST /v1/generate to four concurrent clients (what
-           examples/generate_stream.py builds at toy size);
+           examples/generate_stream.py builds at toy size); all 24
+           layers unless the export directory's disk is too small,
+           when depth alone is cut and the cut is printed;
   leg C    when four chips are visible: leg A's program data-parallel
            over all four, and GPT-small under a dp2 x tp2 partitioning.
 
@@ -459,17 +461,52 @@ def leg_a(report, *, cfg, seq, batch, steps=8):
 # -- leg B: a server answers requests -----------------------------------------
 
 
-def _export_lm(cfg, seq, model_dir):
+def _export_bytes(program, layers):
+    """(fixed, per decoder layer) float32 bytes save_inference_model
+    writes for an LM program of ``layers`` equal decoder layers; layer
+    i's weights are named dec<i>_*."""
+    sizes = {v.name: 4 * int(np.prod(v.shape))
+             for v in program.global_block().vars.values()
+             if v.persistable and not v.is_data}
+    per_layer = sum(n for name, n in sizes.items()
+                    if name.startswith("dec0_"))
+    return sum(sizes.values()) - layers * per_layer, per_layer
+
+
+def _export_lm(cfg, seq, model_dir, out):
+    """Export ``cfg`` with random weights; returns the config exported.
+    Depth is cut — never a width — when the export directory's disk
+    cannot hold all of it, and the cut is printed."""
+    import dataclasses
+    import resource
+
     import paddle_tpu as fluid
     from paddle_tpu.generation.model import build_lm_program
 
     main, startup, _feeds, fetches = build_lm_program(cfg, seq)
+    fixed, per_layer = _export_bytes(main, cfg.num_layers)
+    free = shutil.disk_usage(model_dir).free
+    # the machine's limits, on record (RLIMIT_FSIZE: -1 is none): the
+    # driver's chip machine refused one 5.4 GB file with EFBIG
+    out.update(export_bytes=fixed + cfg.num_layers * per_layer,
+               export_dir_free_bytes=free,
+               file_size_limit=resource.getrlimit(resource.RLIMIT_FSIZE)[0])
+    fit = int((0.9 * free - fixed) // per_layer)
+    if fit < cfg.num_layers:
+        print(f"leg B: DEPTH CUT {cfg.num_layers} -> {fit} layers: "
+              f"{model_dir} has {free} bytes free, the export needs "
+              f"{fixed} + {per_layer} per layer", flush=True)
+        if fit < 1:
+            raise OSError(f"no room in {model_dir} for one decoder layer")
+        cfg = dataclasses.replace(cfg, num_layers=fit)
+        main, startup, _feeds, fetches = build_lm_program(cfg, seq)
     scope = fluid.Scope()
     with fluid.scope_guard(scope):
         exe = fluid.Executor(fluid.TPUPlace())
         exe.run(startup)
         fluid.io.save_inference_model(model_dir, ["tokens"],
                                       [fetches["logits"]], exe, main)
+    return cfg
 
 
 def _http_get(server, path):
@@ -489,12 +526,16 @@ def leg_b(report, *, cfg, prompt_lens, new_tokens=32, export_seq=128,
     from paddle_tpu.runtime import dispatch
     from paddle_tpu.serving import ServingEngine, ServingServer
 
-    out = {"layers": cfg.num_layers}
+    out = {}
     model_dir = tempfile.mkdtemp(prefix="chip_smoke_lm_")
     eng = srv = serve = None
     try:
         t0 = time.perf_counter()
-        _export_lm(cfg, export_seq, model_dir)
+        cfg = _export_lm(cfg, export_seq, model_dir, out)
+        out["layers"] = cfg.num_layers
+        out["largest_file_bytes"] = max(
+            os.path.getsize(os.path.join(model_dir, f))
+            for f in os.listdir(model_dir))
         gc.collect()            # the export scope's weights leave the chip
         pred = create_predictor(Config(model_dir))
         out["export_load_s"] = round(time.perf_counter() - t0, 1)

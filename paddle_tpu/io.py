@@ -8,7 +8,11 @@ feed/fetch targets; single-file save/load (:1507, :1565).
 TPU-native format: one .npz per save directory (or single file) holding
 each persistable var by name + a JSON program description. Same
 "persistables by name" semantics; no bit-compat with the reference's
-binary LoD tensor format (documented divergence).
+binary LoD tensor format (documented divergence). An archive that would
+pass ``_PART_BYTES`` continues in numbered parts beside it
+(``__params__.1.npz``, ...), a var larger than a part as runs of its
+rows: a machine may cap the size of one file (RLIMIT_FSIZE, a 2 or
+4 GiB filesystem limit) well below a model's weights.
 """
 
 from __future__ import annotations
@@ -41,6 +45,11 @@ __all__ = [
 
 _PARAMS_FILE = "__params__.npz"
 _MODEL_FILE = "__model__"
+# most bytes one archive part holds: under every common per-file cap
+_PART_BYTES = 256 << 20
+# an entry's zip and npy headers, counted against the part (var names
+# are far shorter than this)
+_ENTRY_BYTES = 1024
 
 
 def _persistable_vars(program: Program) -> List[Variable]:
@@ -51,19 +60,71 @@ def _persistable_vars(program: Program) -> List[Variable]:
     ]
 
 
+def _part_path(path, i):
+    """Part 0 is the archive under the name np.savez gives it; part i
+    sits beside it as ``<stem>.<i>.npz``."""
+    if not path.endswith(".npz"):
+        path += ".npz"
+    return path if i == 0 else f"{path[:-len('.npz')]}.{i}.npz"
+
+
+def _part_bytes():
+    """Bytes one archive part may hold: ``_PART_BYTES``, or half the
+    process's own file-size limit (RLIMIT_FSIZE) where that is less."""
+    import resource
+
+    soft = resource.getrlimit(resource.RLIMIT_FSIZE)[0]
+    return (_PART_BYTES if soft == resource.RLIM_INFINITY
+            else min(_PART_BYTES, soft // 2))
+
+
+def _pieces(name, val, bound):
+    """(key, array) archive entries for one var: itself or, when it is
+    larger than a part, runs of its rows under offset keys."""
+    if val.nbytes <= bound or val.ndim == 0:
+        yield name, val
+        return
+    rows = max(1, (bound - _ENTRY_BYTES) // (val.nbytes // len(val)))
+    rest = (slice(None),) * (val.ndim - 1)
+    for a in range(0, len(val), rows):
+        sl = slice(a, min(a + rows, len(val)))
+        yield _index_key(name, (sl,) + rest, val.shape), val[sl]
+
+
 def save_vars(executor, dirname, main_program=None, vars=None, predicate=None, filename=None):
     main_program = main_program or framework.default_main_program()
     if vars is None:
         vars = [v for v in main_program.global_block().vars.values() if predicate is None or predicate(v)]
     scope = global_scope()
     os.makedirs(dirname, exist_ok=True)
-    arrays = {}
+    path = os.path.join(dirname, filename or _PARAMS_FILE)
+    bound = _part_bytes()
+    parts = 0
+    arrays, held = {}, 0
+
+    def flush():
+        nonlocal parts, arrays, held
+        np.savez(_part_path(path, parts), **arrays)
+        parts += 1
+        arrays, held = {}, 0
+
+    # one part's worth of host copies at a time, never the whole model
     for v in vars:
         val = scope.find_var(v.name)
         if val is None:
             continue
-        arrays[v.name] = np.asarray(val)
-    np.savez(os.path.join(dirname, filename or _PARAMS_FILE), **arrays)
+        for key, piece in _pieces(v.name, np.asarray(val), bound):
+            size = piece.nbytes + _ENTRY_BYTES
+            if arrays and held + size > bound:
+                flush()
+            arrays[key] = piece
+            held += size
+    if arrays or parts == 0:
+        flush()
+    # an earlier, larger save into this directory must not be read back
+    while os.path.exists(_part_path(path, parts)):
+        os.remove(_part_path(path, parts))
+        parts += 1
 
 
 def save_params(executor, dirname, main_program=None, filename=None):
@@ -92,11 +153,27 @@ def load_vars(executor, dirname, main_program=None, vars=None, predicate=None, f
     if vars is None:
         vars = [v for v in main_program.global_block().vars.values() if predicate is None or predicate(v)]
     path = os.path.join(dirname, filename or _PARAMS_FILE)
-    data = np.load(path)
+    wanted = {v.name for v in vars}
     scope = global_scope()
-    for v in vars:
-        if v.name in data:
-            scope.set_var(v.name, jnp.asarray(data[v.name]))
+    rows = {}                   # split var -> [(first row, piece)]
+    part = path                 # part 0: the name as the caller gave it
+    i = 0
+    while i == 0 or os.path.exists(part):
+        with np.load(part) as data:
+            for key in data.files:
+                name, idx = ((key, None) if key in wanted
+                             else _parse_index_key(key))
+                if name not in wanted:
+                    continue
+                if idx is None:
+                    scope.set_var(name, jnp.asarray(data[key]))
+                else:
+                    rows.setdefault(name, []).append((idx[0][0], data[key]))
+        i += 1
+        part = _part_path(path, i)
+    for name, got in rows.items():
+        got.sort(key=lambda p: p[0])
+        scope.set_var(name, jnp.asarray(np.concatenate([p for _, p in got])))
 
 
 def load_params(executor, dirname, main_program=None, filename=None):

@@ -88,6 +88,47 @@ def test_leg_b_toy(interpret_kernels):
         engine_kwargs=dict(page_size=8, num_pages=64, max_decode_batch=4,
                            chunk_tokens=8)))
     assert out["mode"] == "ragged" and out["mean_active_lanes"] > 1
+    assert out["layers"] == 2
+
+
+def test_leg_b_export_is_cut_in_depth_when_the_disk_is_short(
+        tmp_path, monkeypatch):
+    """The driver's chip machine refused a 5.4 GB file: the export is in
+    bounded parts, and depth (never a width) gives way to the disk."""
+    import collections
+
+    import paddle_tpu as fluid
+    from paddle_tpu.generation.model import build_lm_program
+    from paddle_tpu.models.gpt import GPTConfig
+
+    cfg = GPTConfig(vocab_size=151, hidden_size=48, num_layers=4,
+                    num_heads=4, ffn_size=96, max_position=64,
+                    hidden_dropout=0.0, attention_dropout=0.0)
+    fixed, per_layer = chip_smoke._export_bytes(
+        build_lm_program(cfg, 32)[0], cfg.num_layers)
+    usage = collections.namedtuple("usage", "total used free")
+    free = int((fixed + 2.5 * per_layer) / 0.9)
+    monkeypatch.setattr(chip_smoke.shutil, "disk_usage",
+                        lambda _d: usage(free, 0, free))
+    monkeypatch.setattr(fluid.io, "_PART_BYTES", per_layer)
+    out = {}
+    got = chip_smoke._export_lm(cfg, 32, str(tmp_path), out)
+    assert got.num_layers == 2 and got.hidden_size == cfg.hidden_size
+    assert out["export_bytes"] == fixed + 4 * per_layer
+    files = sorted(os.listdir(tmp_path))
+    assert "__params__.1.npz" in files, files
+    assert max(os.path.getsize(tmp_path / f) for f in files) < 2 * per_layer
+    stored = {}
+    for f in files:
+        if f.endswith(".npz"):
+            with np.load(tmp_path / f) as z:
+                stored.update({k: z[k].nbytes for k in z.files})
+    assert sum(stored.values()) == fixed + 2 * per_layer
+    from paddle_tpu.inference import Config, create_predictor
+
+    pred = create_predictor(Config(str(tmp_path)))
+    (logits,) = pred.run([np.zeros((1, 32), np.int64)])
+    assert logits.shape == (1, 32, 151) and np.isfinite(logits).all()
 
 
 def test_leg_c_toy(interpret_kernels):
