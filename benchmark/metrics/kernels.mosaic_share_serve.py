@@ -1,0 +1,7 @@
+"""Reader of the per-layer metric `kernels.mosaic_share_serve`: device time in Mosaic (Pallas) custom calls over device busy time (%)."""
+
+import layer_math
+
+
+def read(x):
+    return layer_math.mosaic_share(x)

@@ -1,0 +1,7 @@
+"""Reader of the per-layer metric `train_step.device_ms_p50`: device time of one run of the train step's program, median (ms)."""
+
+import layer_math
+
+
+def read(x):
+    return layer_math.step_device_ms_p50(x)
