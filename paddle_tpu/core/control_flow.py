@@ -105,7 +105,10 @@ def _lower_recompute_segment_grad(block, op, env, ctx):
     def seg_fn(diff_vals):
         local = dict(aux)
         local.update(diff_vals)
-        _lower_block(sub, local, ctx)
+        # as in registry._make_auto_grad: the scope takes the
+        # transform's wrapper, kernel names inside stay whole
+        with jax.named_scope("recompute_segment"):
+            _lower_block(sub, local, ctx)
         return tuple(local[n] for n in out_names)
 
     primals, vjp_fn = jax.vjp(jax.checkpoint(seg_fn), diff)

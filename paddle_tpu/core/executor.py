@@ -658,15 +658,19 @@ class Executor:
         fetch_list = fetch_list if fetch_list is not None else []
 
         # -- hot path: one dict hit resolves the whole dispatch --------
-        bkey = None
+        bkey = bound = None
         if use_program_cache and self.fast_dispatch:
-            bkey = self._bound_key(program, feed, fetch_list, scope)
-            if bkey is not None:
-                bound = self._bound.get(bkey)
-                if bound is not None:
-                    self._stats["bound_hits"] += 1
-                    self._bound.move_to_end(bkey)
-                    return bound.run(feed, return_numpy)
+            from ..observability import tracing
+
+            with tracing.annotation("executor/bind"):
+                bkey = self._bound_key(program, feed, fetch_list, scope)
+                if bkey is not None:
+                    bound = self._bound.get(bkey)
+                    if bound is not None:
+                        self._stats["bound_hits"] += 1
+                        self._bound.move_to_end(bkey)
+            if bound is not None:
+                return bound.run(feed, return_numpy)
         self._stats["bound_misses"] += 1
         return self._run_slow(
             program, dict(feed), list(fetch_list), scope, return_numpy,

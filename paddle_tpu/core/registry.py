@@ -234,7 +234,12 @@ def _make_auto_grad(fwd: OpDef) -> OpDef:
 
         def fwd_fn(d_ins):
             all_ins = {**aux_ins, **d_ins}
-            outs = fwd.lower(ctx, pseudo, all_ins)
+            # the scope takes the jvp(...) / transpose(jvp(...)) wrapper
+            # that jax puts around the innermost scope under a transform,
+            # so a Pallas kernel's own name (pallas_call(name=...), one
+            # scope further in) reaches the device trace unwrapped
+            with jax.named_scope(fwd.type):
+                outs = fwd.lower(ctx, pseudo, all_ins)
             # keep only real (listed) outputs, as a dict of lists
             return {s: list(outs.get(s, [])) for s in fwd.output_slots}
 

@@ -48,6 +48,22 @@ does what modern LLM serving does instead:
   per-step hot path is a feed-dict assembly and one jitted call,
   nothing else. Page pools ride feeds/fetches as jax arrays
   (zero-copy through the dispatch normalizers).
+* **Loop phases** (ragged mode). One iteration of the step loop is
+  partitioned, with nothing left between them, into ``wait`` (starved:
+  no queue, no live lane), ``admit`` (page-store consult, prefix
+  lookup, lane + page reservation), ``grow`` (retire dead rows, page
+  growth / eviction, the speculative budget, the adapter check),
+  ``draft`` (only with speculative rows), ``assemble`` (the numpy
+  batch, block tables, the feed dict with the page pools), ``bind``,
+  ``step`` (the dispatch, the wait for the tokens —
+  ``generation/fetch`` — and the pools' hand-back) and ``emit``
+  (advance, stop conditions, the clients' ``on_token`` callbacks,
+  trie publish and, last, the release of the pools the step read).
+  Each is the range ``generation/<phase>`` on the
+  profiler's clock — always on, so any attached profiler session reads
+  the device's idle gaps in these terms — and, at the same boundary,
+  the exact counter ``loop_<phase>_us_total`` (``stats()``,
+  ``paddle_generation_loop_*`` on ``/metrics``).
 * **Streaming.** ``submit()`` returns a ``GenerationStream`` —
   iterate it for tokens as they are sampled (time-to-first-token is a
   prefill, not a whole generation), or ``result()`` for the full list.
@@ -75,6 +91,7 @@ from typing import Any, Dict, List, Optional, Sequence
 
 import numpy as np
 
+from ..observability import tracing
 from ..serving.engine import (DeadlineExceeded, EngineClosed, Overloaded,
                               RequestCancelled, ServingError)
 from ..serving.metrics import StreamingHistogram
@@ -85,6 +102,11 @@ from .model import (CacheGeometry, build_decode_program,
 __all__ = ["GenerationEngine", "GenerationStream", "GenerationMetrics"]
 
 _DONE = object()  # stream sentinel
+
+# one iteration of the ragged step loop, in order, nothing between
+# them (module docstring, "Loop phases")
+LOOP_PHASES = ("wait", "admit", "grow", "draft", "assemble", "bind",
+               "step", "emit")
 
 
 class GenerationStream:
@@ -232,6 +254,32 @@ class _GenRequest:
         self.adapter = adapter          # resident LoRA adapter id (or None)
 
 
+class _Phase:
+    """One loop phase: the range ``generation/<phase>`` on the profiler's
+    clock and, at the same boundary, its wall time into
+    ``loop_<phase>_us_total``. With ``args`` (the step phase) it is a
+    span, which ``observability_tracing`` gives ids and ``flow_from``."""
+
+    __slots__ = ("metrics", "counter", "span", "t0")
+
+    def __init__(self, metrics, phase: str, args=None):
+        self.metrics = metrics
+        self.counter = "loop_" + phase + "_us_total"
+        name = "generation/" + phase
+        self.span = (tracing.annotation(name) if args is None
+                     else tracing.span(name, args))
+
+    def __enter__(self):
+        self.t0 = time.perf_counter_ns()
+        return self.span.__enter__()
+
+    def __exit__(self, *exc):
+        self.span.__exit__(*exc)
+        self.metrics.inc(self.counter,
+                         (time.perf_counter_ns() - self.t0) // 1000)
+        return False
+
+
 class GenerationMetrics:
     """Lock-protected counters + streaming histograms for the engine.
     The ENGINE (which also owns the page-pool stats) self-registers
@@ -251,7 +299,11 @@ class GenerationMetrics:
                  # speculative decoding (exported as the
                  # paddle_generation_spec_* gauge family)
                  "spec_rounds_total", "spec_proposed_total",
-                 "spec_accepted_total")
+                 "spec_accepted_total"
+                 # ragged mode: wall microseconds of the loop thread by
+                 # phase, counted where the generation/<phase> span
+                 # closes (GenerationEngine._phase)
+                 ) + tuple(f"loop_{p}_us_total" for p in LOOP_PHASES)
 
     def __init__(self):
         self._lock = threading.Lock()
@@ -653,8 +705,6 @@ class GenerationEngine:
         ``AdapterMissing`` before any queueing when it is not); the
         adapter is refcount-pinned until the request's terminal state,
         so evict cannot pull the factors out from under it."""
-        from ..observability import tracing
-
         prompt = np.asarray(prompt, dtype=np.int64).reshape(-1)
         if prompt.size < 1:
             raise ValueError("prompt must hold at least one token")
@@ -692,6 +742,9 @@ class GenerationEngine:
         if adapter is not None:
             stream.add_done_callback(
                 lambda _s, _a=adapter: self.adapter_store.release(_a))
+        # request tracing, on a client thread: behind the flag, unlike
+        # the loop's phases (a short span here would claim device gaps
+        # that belong to the loop thread's phase)
         with (tracing.span("generation/submit", {"prompt": int(prompt.size),
                                                  "max_new": max_new})
               if tracing.enabled() else contextlib.nullcontext()) as ctx:
@@ -866,14 +919,22 @@ class GenerationEngine:
         done.set()
 
     # -- the step loop -------------------------------------------------------
+    def _phase(self, phase: str, args=None) -> _Phase:
+        """``with self._phase("assemble"):`` — one of LOOP_PHASES, on the
+        loop thread (ragged mode)."""
+        return _Phase(self.metrics, phase, args)
+
     def _loop(self):
         try:
             while True:
                 with self._cond:
-                    while (not self._queue and not self._by_slot
-                           and not self._stop and not self._closed
-                           and self._pending_swap is None):
-                        self._cond.wait(0.05)
+                    if not self._queue and not self._by_slot:
+                        # starved, not slow: nothing queued, no lane live
+                        with self._phase("wait"):
+                            while (not self._queue and not self._by_slot
+                                   and not self._stop and not self._closed
+                                   and self._pending_swap is None):
+                                self._cond.wait(0.05)
                     if self._stop or (self._closed and not self._queue
                                       and not self._by_slot):
                         break
@@ -884,7 +945,8 @@ class GenerationEngine:
                     # half-swapped scope
                     self._apply_swap(*swap)
                 if self.mode == "ragged":
-                    self._admit_ragged()
+                    with self._phase("admit"):
+                        self._admit_ragged()
                     if self._by_slot:
                         self._ragged_step()
                 else:
@@ -1007,8 +1069,6 @@ class GenerationEngine:
         return entry
 
     def _prefill(self, bucket: int, reqs: List[_GenRequest]):
-        from ..observability import tracing
-
         t0 = time.monotonic()
         prog, fetches = self._prefill_prog(bucket)
         # FIXED prefill batch (the lane count): exactly ONE executable
@@ -1038,14 +1098,13 @@ class GenerationEngine:
         for li in range(L):
             feed[f"gen_k_pages_{li}"] = self.cache.k_pages[li]
             feed[f"gen_v_pages_{li}"] = self.cache.v_pages[li]
-        span_cm = contextlib.nullcontext()
-        if tracing.enabled():
+
+        def span_args():
             flow = [r.ctx.span_id for r in reqs[1:] if r.ctx is not None]
-            span_cm = tracing.span(
-                f"generation/prefill[n={len(reqs)}]",
-                {"bucket": bucket, "rows": int(num_valid.sum()),
-                 **({"flow_from": flow} if flow else {})},
-                parent=reqs[0].ctx)
+            return {"n": len(reqs), "bucket": bucket,
+                    "rows": int(num_valid.sum()),
+                    **({"flow_from": flow} if flow else {})}
+
         # the prefill lane drives the SAME resolved dispatch object as
         # every other subsystem (Executor.bind, one BoundStep per seq
         # bucket) — tagged for spans and the donation audit, with
@@ -1054,7 +1113,8 @@ class GenerationEngine:
                                tag=f"generation/prefill[{bucket}]")
         bound.rows_hint = len(reqs)
         try:
-            with span_cm:
+            with tracing.span("generation/prefill", span_args,
+                              parent=reqs[0].ctx):
                 outs = bound.run(feed, False)
         except Exception as e:  # noqa: BLE001 — a bad prompt batch must not kill the loop
             for req in reqs:
@@ -1225,199 +1285,215 @@ class GenerationEngine:
         whatever its sequence needs this step — a prefill chunk, a
         decode token, or a decode token plus speculative drafts — and
         the whole batch attends raggedly over the shared page pool."""
-        from ..observability import tracing
-
         R, C, L = self.lanes, self.chunk_tokens, self.config.num_layers
-        now = time.monotonic()
-        self._retire_dead_rows(now)
-        # page growth for decode rows (+ the speculative window);
-        # prefill rows were fully reserved at admission. A dry pool
-        # first degrades speculation to plain decode, then evicts
-        # (youngest first), then finishes the stuck row early.
-        spec_rows: List = []
-        for slot, req in list(self._by_slot.items()):
-            if slot not in self._by_slot:
-                continue
-            if req.prefill_off < int(req.prompt.size):
-                continue
-            req.drafts = None
-            k = self._spec_budget(slot, req)
-            if k > 0:
-                try:
-                    self.cache.ensure_capacity(
-                        slot, int(self.cache.lengths[slot]) + 1 + k)
-                    spec_rows.append((slot, req, k))
-                    continue
-                except PagePoolExhausted:
-                    pass
-            self._grow_or_evict(slot)
-        if not self._by_slot:
-            return
-        if self.adapter_store is not None:
-            # a force-evicted adapter fails ITS rows here, before they
-            # cost a step — never the whole batch
-            from ..adapters import AdapterMissing
-
+        with self._phase("grow"):
+            self._retire_dead_rows(time.monotonic())
+            # page growth for decode rows (+ the speculative window);
+            # prefill rows were fully reserved at admission. A dry pool
+            # first degrades speculation to plain decode, then evicts
+            # (youngest first), then finishes the stuck row early.
+            spec_rows: List = []
             for slot, req in list(self._by_slot.items()):
-                if req.adapter is None:
+                if slot not in self._by_slot:
                     continue
-                try:
-                    self.adapter_store.slots_row(req.adapter)
-                except AdapterMissing as e:
-                    self._retire(slot, "error", ServingError(str(e)))
+                if req.prefill_off < int(req.prompt.size):
+                    continue
+                req.drafts = None
+                k = self._spec_budget(slot, req)
+                if k > 0:
+                    try:
+                        self.cache.ensure_capacity(
+                            slot, int(self.cache.lengths[slot]) + 1 + k)
+                        spec_rows.append((slot, req, k))
+                        continue
+                    except PagePoolExhausted:
+                        pass
+                self._grow_or_evict(slot)
             if not self._by_slot:
                 return
-        # batched drafting: ONE propose() call covers every
-        # speculative row, so draft cost amortizes over the batch
-        spec_rows = [(s, r, k) for s, r, k in spec_rows
-                     if s in self._by_slot]
+            if self.adapter_store is not None:
+                # a force-evicted adapter fails ITS rows here, before
+                # they cost a step — never the whole batch
+                from ..adapters import AdapterMissing
+
+                for slot, req in list(self._by_slot.items()):
+                    if req.adapter is None:
+                        continue
+                    try:
+                        self.adapter_store.slots_row(req.adapter)
+                    except AdapterMissing as e:
+                        self._retire(slot, "error", ServingError(str(e)))
+                if not self._by_slot:
+                    return
+            spec_rows = [(s, r, k) for s, r, k in spec_rows
+                         if s in self._by_slot]
         if spec_rows:
-            ctxs = [np.concatenate([r.orig_prompt,
-                                    np.asarray(r.stream._tokens, np.int64)])
+            # batched drafting: ONE propose() call covers every
+            # speculative row, so draft cost amortizes over the batch
+            with self._phase("draft"):
+                ctxs = [np.concatenate(
+                    [r.orig_prompt, np.asarray(r.stream._tokens, np.int64)])
                     for _, r, _ in spec_rows]
-            # always propose the FULL spec window and trim per row:
-            # a shrinking k near a request's token budget would mint a
-            # fresh draft executable per distinct k (warmup compiled
-            # exactly the spec_tokens buckets)
-            try:
-                props = self._draft.propose(ctxs, self.spec_tokens)
-            except Exception:  # noqa: BLE001 — a broken draft must never kill decode
-                props = [np.zeros(0, np.int64)] * len(spec_rows)
-            self.metrics.inc("spec_rounds_total")
-            for (slot, req, k), dr in zip(spec_rows, props):
-                dr = np.asarray(dr, np.int64).reshape(-1)[:k]
-                req.drafts = dr
-                self.metrics.inc("spec_proposed_total", int(dr.size))
-        # assemble the mixed batch
-        tokens = np.zeros((R, C), np.int64)
-        pos_ids = np.zeros((R, C), np.int64)
-        positions = np.zeros(R, np.int64)
-        num_valid = np.zeros(R, np.int32)
-        for slot, req in self._by_slot.items():
-            if req.prefill_off < int(req.prompt.size):
-                off = req.prefill_off
-                c = min(C, int(req.prompt.size) - off)
-                tokens[slot, :c] = req.prompt[off:off + c]
-                pos_ids[slot, :c] = np.arange(off, off + c)
-                positions[slot] = off
-                num_valid[slot] = c
-            else:
-                dr = (req.drafts if req.drafts is not None
-                      else np.zeros(0, np.int64))
-                row = np.concatenate(
-                    [np.asarray([req.pending], np.int64), dr])
-                L0 = int(self.cache.lengths[slot])
-                tokens[slot, :row.size] = row
-                pos_ids[slot, :row.size] = np.arange(L0, L0 + row.size)
-                positions[slot] = L0
-                num_valid[slot] = row.size
-        feed = {
-            "gen_tokens": tokens,
-            "gen_pos_ids": pos_ids,
-            "gen_positions": positions,
-            "gen_num_valid": num_valid,
-            "gen_block_tables": np.ascontiguousarray(
-                self.cache.block_tables),
-        }
-        if self.adapter_store is not None:
-            # per-row adapter slots, fed exactly like a block table:
-            # zeros = the reserved zero adapter (base-only rows / idle
-            # lanes), so the base path is identity by construction
-            aslots = np.zeros((R, self.adapter_store.n_buckets), np.int32)
+                # always propose the FULL spec window and trim per row:
+                # a shrinking k near a request's token budget would mint
+                # a fresh draft executable per distinct k (warmup
+                # compiled exactly the spec_tokens buckets)
+                try:
+                    props = self._draft.propose(ctxs, self.spec_tokens)
+                except Exception:  # noqa: BLE001 — a broken draft must never kill decode
+                    props = [np.zeros(0, np.int64)] * len(spec_rows)
+                self.metrics.inc("spec_rounds_total")
+                for (slot, req, k), dr in zip(spec_rows, props):
+                    dr = np.asarray(dr, np.int64).reshape(-1)[:k]
+                    req.drafts = dr
+                    self.metrics.inc("spec_proposed_total", int(dr.size))
+        with self._phase("assemble"):
+            tokens = np.zeros((R, C), np.int64)
+            pos_ids = np.zeros((R, C), np.int64)
+            positions = np.zeros(R, np.int64)
+            num_valid = np.zeros(R, np.int32)
             for slot, req in self._by_slot.items():
-                if req.adapter is not None:
-                    aslots[slot] = self.adapter_store.slots_row(req.adapter)
-            feed["gen_adapter_slots"] = aslots
-        for li in range(L):
-            feed[f"gen_k_pages_{li}"] = self.cache.k_pages[li]
-            feed[f"gen_v_pages_{li}"] = self.cache.v_pages[li]
-        if self.cache.quantized:
+                if req.prefill_off < int(req.prompt.size):
+                    off = req.prefill_off
+                    c = min(C, int(req.prompt.size) - off)
+                    tokens[slot, :c] = req.prompt[off:off + c]
+                    pos_ids[slot, :c] = np.arange(off, off + c)
+                    positions[slot] = off
+                    num_valid[slot] = c
+                else:
+                    dr = (req.drafts if req.drafts is not None
+                          else np.zeros(0, np.int64))
+                    row = np.concatenate(
+                        [np.asarray([req.pending], np.int64), dr])
+                    L0 = int(self.cache.lengths[slot])
+                    tokens[slot, :row.size] = row
+                    pos_ids[slot, :row.size] = np.arange(L0, L0 + row.size)
+                    positions[slot] = L0
+                    num_valid[slot] = row.size
+            feed = {
+                "gen_tokens": tokens,
+                "gen_pos_ids": pos_ids,
+                "gen_positions": positions,
+                "gen_num_valid": num_valid,
+                "gen_block_tables": np.ascontiguousarray(
+                    self.cache.block_tables),
+            }
+            if self.adapter_store is not None:
+                # per-row adapter slots, fed exactly like a block
+                # table: zeros = the reserved zero adapter (base-only
+                # rows / idle lanes), so the base path is identity by
+                # construction
+                aslots = np.zeros((R, self.adapter_store.n_buckets),
+                                  np.int32)
+                for slot, req in self._by_slot.items():
+                    if req.adapter is not None:
+                        aslots[slot] = self.adapter_store.slots_row(
+                            req.adapter)
+                feed["gen_adapter_slots"] = aslots
             for li in range(L):
-                feed[f"gen_k_scales_{li}"] = self.cache.k_scales[li]
-                feed[f"gen_v_scales_{li}"] = self.cache.v_scales[li]
-        bound = self._bind_ragged(feed)
-        active = list(self._by_slot.items())
-        bound.rows_hint = len(active)
-        span_cm = contextlib.nullcontext()
-        if tracing.enabled():
+                feed[f"gen_k_pages_{li}"] = self.cache.k_pages[li]
+                feed[f"gen_v_pages_{li}"] = self.cache.v_pages[li]
+            if self.cache.quantized:
+                for li in range(L):
+                    feed[f"gen_k_scales_{li}"] = self.cache.k_scales[li]
+                    feed[f"gen_v_scales_{li}"] = self.cache.v_scales[li]
+        with self._phase("bind"):
+            bound = self._bind_ragged(feed)
+            active = list(self._by_slot.items())
+            bound.rows_hint = len(active)
+
+        def step_args():
             flow = [r.ctx.span_id for _, r in active if r.ctx is not None]
-            span_cm = tracing.span(
-                f"generation/ragged_step[n={len(active)}]",
-                {"lanes": R, "chunk": C,
-                 "new_tokens": int(num_valid.sum()),
-                 **({"flow_from": flow} if flow else {})})
-        t0 = time.monotonic()
-        try:
-            with span_cm:
+            return {"n": len(active), "lanes": R, "chunk": C,
+                    "new_tokens": int(num_valid.sum()),
+                    **({"flow_from": flow} if flow else {})}
+
+        with self._phase("step", step_args):
+            t0 = time.monotonic()
+            try:
                 outs = bound.run(feed, False)
-        except Exception as e:  # noqa: BLE001 — a bad batch must not kill the loop
-            for slot, req in active:
-                self._retire(slot, "error", ServingError(
-                    f"ragged step execution failed: {e!r}"))
-            return
-        next_all = np.asarray(outs[0]).reshape(R, C)
-        if self.cache.quantized:
-            self.cache.set_buffers(
-                list(outs[1:1 + L]), list(outs[1 + L:1 + 2 * L]),
-                list(outs[1 + 2 * L:1 + 3 * L]), list(outs[1 + 3 * L:]))
-        else:
-            self.cache.set_buffers(list(outs[1:1 + L]),
-                                   list(outs[1 + L:]))
-        now = time.monotonic()
-        self.metrics.inc("ragged_steps_total")
-        emitted_total = 0
-        for slot, req in active:
-            if slot not in self._by_slot:
-                continue
-            nv = int(num_valid[slot])
-            if nv <= 0:
-                continue
-            if req.prefill_off < int(req.prompt.size):
-                # a prefill chunk: its K/V is cached now; the FINAL
-                # chunk additionally samples the first token (TTFT).
-                # Publish BEFORE _emit: a request retiring on its very
-                # first token must still leave its prompt pages in the
-                # trie for the siblings behind it.
-                self.cache.advance(slot, nv)
-                req.prefill_off += nv
-                self.metrics.inc("prefill_chunks_total")
-                self.metrics.inc("prefill_tokens_total", nv)
-                if self.prefix_cache:
-                    self.cache.publish(slot, req.prompt,
-                                       tenant=req.tenant)
-                if req.prefill_off >= int(req.prompt.size):
-                    self.metrics.inc("prefill_batches_total")
-                    self._emit(req, int(next_all[slot, nv - 1]), now)
-                    emitted_total += 1
+            except Exception as e:  # noqa: BLE001 — a bad batch must not kill the loop
+                for slot, req in active:
+                    self._retire(slot, "error", ServingError(
+                        f"ragged step execution failed: {e!r}"))
+                return
+            # where the loop waits for the device
+            with tracing.annotation("generation/fetch"):
+                next_all = np.asarray(outs[0]).reshape(R, C)
+            if self.cache.quantized:
+                self.cache.set_buffers(
+                    list(outs[1:1 + L]), list(outs[1 + L:1 + 2 * L]),
+                    list(outs[1 + 2 * L:1 + 3 * L]),
+                    list(outs[1 + 3 * L:]))
             else:
-                # decode / speculative verify: next_all[slot, j] IS
-                # the greedy token after position start+j, so draft j
-                # is accepted iff it equals the target's token at its
-                # own offset — the emitted stream is greedy-identical
-                # by construction, whatever the draft proposed
-                dr = req.drafts if req.drafts is not None else ()
-                for j in range(nv):
-                    if j > 0:
-                        if int(dr[j - 1]) != int(next_all[slot, j - 1]):
-                            break       # rejected: the tail is dead
-                        self.metrics.inc("spec_accepted_total")
-                        req.stream.accepted_draft_tokens += 1
-                    self.cache.advance(slot)
-                    emitted_total += 1
-                    self._emit(req, int(next_all[slot, j]), now)
-                    if slot not in self._by_slot:
-                        break           # retired (eos/length/deadline)
-                if self.prefix_cache and slot in self._by_slot:
-                    # decode-produced full pages join the trie too:
-                    # only positions < length publish, and rejected
-                    # drafts live strictly at positions >= length
-                    self.cache.publish(slot, np.concatenate(
-                        [req.orig_prompt,
-                         np.asarray(req.stream._tokens, np.int64)]),
-                        tenant=req.tenant)
-        n_active = sum(1 for s, _ in active if num_valid[s] > 0)
-        self.metrics.observe_decode_step(
-            (now - t0) * 1e3, n_active, R, tokens=emitted_total)
+                self.cache.set_buffers(list(outs[1:1 + L]),
+                                       list(outs[1 + L:]))
+        with self._phase("emit"):
+            now = time.monotonic()
+            self.metrics.inc("ragged_steps_total")
+            emitted_total = 0
+            for slot, req in active:
+                if slot not in self._by_slot:
+                    continue
+                nv = int(num_valid[slot])
+                if nv <= 0:
+                    continue
+                if req.prefill_off < int(req.prompt.size):
+                    # a prefill chunk: its K/V is cached now; the FINAL
+                    # chunk additionally samples the first token
+                    # (TTFT). Publish BEFORE _emit: a request retiring
+                    # on its very first token must still leave its
+                    # prompt pages in the trie for the siblings behind
+                    # it.
+                    self.cache.advance(slot, nv)
+                    req.prefill_off += nv
+                    self.metrics.inc("prefill_chunks_total")
+                    self.metrics.inc("prefill_tokens_total", nv)
+                    if self.prefix_cache:
+                        self.cache.publish(slot, req.prompt,
+                                           tenant=req.tenant)
+                    if req.prefill_off >= int(req.prompt.size):
+                        self.metrics.inc("prefill_batches_total")
+                        self._emit(req, int(next_all[slot, nv - 1]), now)
+                        emitted_total += 1
+                else:
+                    # decode / speculative verify: next_all[slot, j] IS
+                    # the greedy token after position start+j, so draft
+                    # j is accepted iff it equals the target's token at
+                    # its own offset — the emitted stream is
+                    # greedy-identical by construction, whatever the
+                    # draft proposed
+                    dr = req.drafts if req.drafts is not None else ()
+                    for j in range(nv):
+                        if j > 0:
+                            if int(dr[j - 1]) != int(next_all[slot, j - 1]):
+                                break       # rejected: the tail is dead
+                            self.metrics.inc("spec_accepted_total")
+                            req.stream.accepted_draft_tokens += 1
+                        self.cache.advance(slot)
+                        emitted_total += 1
+                        self._emit(req, int(next_all[slot, j]), now)
+                        if slot not in self._by_slot:
+                            break       # retired (eos/length/deadline)
+                    if self.prefix_cache and slot in self._by_slot:
+                        # decode-produced full pages join the trie too:
+                        # only positions < length publish, and rejected
+                        # drafts live strictly at positions >= length
+                        self.cache.publish(slot, np.concatenate(
+                            [req.orig_prompt,
+                             np.asarray(req.stream._tokens, np.int64)]),
+                            tenant=req.tenant)
+            n_active = sum(1 for s, _ in active if num_valid[s] > 0)
+            self.metrics.observe_decode_step(
+                (now - t0) * 1e3, n_active, R, tokens=emitted_total)
+            # the last references to the pools this step read go here,
+            # inside the phase and AFTER the clients' callbacks, where
+            # they went when the frame died (a quarter of a millisecond
+            # for the 48 arrays of GPT-3 XL): released before emit, a
+            # client that answers a finished request with its next
+            # submit reaches the queue after the next admit more often,
+            # and waits a whole step for its lane (PERF.md PR 26)
+            del feed, outs
 
     # -- decode lane ---------------------------------------------------------
     def _bind_decode(self, feed):
@@ -1467,8 +1543,6 @@ class GenerationEngine:
         return True
 
     def _decode_step(self):
-        from ..observability import tracing
-
         Bd, L = self.lanes, self.config.num_layers
         now = time.monotonic()
         self._retire_dead_rows(now)
@@ -1505,15 +1579,15 @@ class GenerationEngine:
         bound = self._bind_decode(feed)
         active = list(self._by_slot.items())
         bound.rows_hint = len(active)
-        span_cm = contextlib.nullcontext()
-        if tracing.enabled():
+
+        def span_args():
             flow = [r.ctx.span_id for _, r in active if r.ctx is not None]
-            span_cm = tracing.span(
-                f"generation/decode_step[n={len(active)}]",
-                {"lanes": Bd, **({"flow_from": flow} if flow else {})})
+            return {"n": len(active), "lanes": Bd,
+                    **({"flow_from": flow} if flow else {})}
+
         t0 = time.monotonic()
         try:
-            with span_cm:
+            with tracing.span("generation/decode_step", span_args):
                 outs = bound.run(feed, False)
         except Exception as e:  # noqa: BLE001
             for slot, req in active:

@@ -208,6 +208,7 @@ def _flash_fwd_pallas(q, k, v, mask, bias, sm_scale, causal, interpret,
         in_specs=in_specs,
         out_specs=tuple(out_specs),
         interpret=interpret,
+        name="flash_attention_fwd_panel",
     )(*args)
     return res if with_lse else (res[0], None)
 
@@ -325,6 +326,7 @@ def _flash_fwd_stream(q, k, v, mask, sm_scale, causal, interpret,
         compiler_params=pltpu.CompilerParams(dimension_semantics=(
             "parallel", "parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        name="flash_attention_fwd",
     )(*args)
     return res if with_lse else (res[0], None)
 
@@ -485,6 +487,7 @@ def _flash_bwd_stream(q, k, v, mask, o, lse, g, sm_scale, causal, interpret,
         compiler_params=pltpu.CompilerParams(dimension_semantics=(
             "parallel", "parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        name="flash_attention_bwd_dq",
     )(*dq_args)
 
     dkv_in_specs = [
@@ -516,6 +519,7 @@ def _flash_bwd_stream(q, k, v, mask, o, lse, g, sm_scale, causal, interpret,
         compiler_params=pltpu.CompilerParams(dimension_semantics=(
             "parallel", "parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        name="flash_attention_bwd_dkv",
     )(*dkv_args)
     return dq, dk, dv, None
 
@@ -674,6 +678,7 @@ def _flash_bwd_pallas(q, k, v, mask, bias, o, lse, g, sm_scale, causal,
             out_specs=pl.BlockSpec((1, 1, blk_q, D),
                                    lambda b, h, i: (b, h, i, 0)),
             interpret=interpret,
+            name="flash_attention_bwd_dq_panel",
         )(*dq_args)
         dbias = None
     else:
@@ -741,6 +746,7 @@ def _flash_bwd_pallas(q, k, v, mask, bias, o, lse, g, sm_scale, causal,
                 spec((1, 1, blk_q, S), "bias"),
             ),
             interpret=interpret,
+            name="flash_attention_bwd_dq_panel_bias",
         )(*dq_args)
         dq, dbias = res
         dbias = dbias.astype(bias.dtype)
@@ -777,6 +783,7 @@ def _flash_bwd_pallas(q, k, v, mask, bias, o, lse, g, sm_scale, causal,
             pl.BlockSpec((1, 1, blk_k, D), lambda b, h, j: (b, h, j, 0)),
         ),
         interpret=interpret,
+        name="flash_attention_bwd_dkv_panel",
     )(*dkv_args)
     return dq, dk, dv, dbias
 

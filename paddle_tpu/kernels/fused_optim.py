@@ -131,10 +131,11 @@ def _momentum_kernel(scal_ref, p_ref, g_ref, vel_ref,
     velo_ref[...] = vel.astype(velo_ref.dtype)
 
 
-def _run_fused(kernel, scal, arrays, n_out: int):
+def _run_fused(kernel, scal, arrays, n_out: int, name: str):
     """Shared pallas_call driver: panels every array, grids over row
     blocks, aliases state inputs onto their outputs (in-place over the
-    executor's donated buffers), un-panels the results."""
+    executor's donated buffers), un-panels the results. ``name`` is the
+    caller's kernel name, as a device trace shows it."""
     shape = arrays[0].shape
     panels = []
     n = None
@@ -161,6 +162,7 @@ def _run_fused(kernel, scal, arrays, n_out: int):
                    for a in ([arrays[0]] + list(arrays[2:2 + n_out - 1]))],
         input_output_aliases=aliases,
         interpret=_interpret(),
+        name=name,
     )(scal, *panels)
     return tuple(_unpanel(o, n, shape) for o in outs)
 
@@ -231,7 +233,7 @@ def fused_adam_update(p, g, m1, m2, lr, beta1_pow, beta2_pow, *,
         _adam_kernel, beta1=float(beta1), beta2=float(beta2),
         eps=float(epsilon), coeff=float(weight_decay))
     return _run_fused(kernel, _scal(lr_t, lr, clip_scale),
-                      (p, g, m1, m2), 3)
+                      (p, g, m1, m2), 3, "fused_adam")
 
 
 def fused_momentum_update(p, g, vel, lr, *, mu: float = 0.9,
@@ -247,7 +249,8 @@ def fused_momentum_update(p, g, vel, lr, *, mu: float = 0.9,
                                    float(mu), bool(use_nesterov))
     kernel = functools.partial(_momentum_kernel, mu=float(mu),
                                nesterov=bool(use_nesterov))
-    return _run_fused(kernel, _scal(lr, lr, clip_scale), (p, g, vel), 2)
+    return _run_fused(kernel, _scal(lr, lr, clip_scale), (p, g, vel), 2,
+                      "fused_momentum")
 
 
 def optimizer_fuse_enabled() -> bool:

@@ -139,6 +139,7 @@ def _fwd_impl(x2, gamma, beta, eps):
             jax.ShapeDtypeStruct((xp.shape[0], LANES), jnp.float32),
         ],
         interpret=_interpret(),
+        name="layer_norm_fwd",
     )(xp, g2, b2)
     return y[:true_r], mean[:true_r], rstd[:true_r]
 
@@ -181,6 +182,7 @@ def _vjp_bwd(eps, res, dy):
             jax.ShapeDtypeStruct((n_blocks, 1, C), jnp.float32),
         ],
         interpret=_interpret(),
+        name="layer_norm_bwd",
     )(xp, gamma.reshape(1, C), dyp, meanp, rstdp)
     dgamma = jnp.sum(dg_part, axis=(0, 1)).astype(gamma.dtype)
     dbeta = jnp.sum(db_part, axis=(0, 1)).astype(gamma.dtype)

@@ -198,6 +198,7 @@ def _lora_delta_pallas(x2, a, b, scale, slots, interpret: bool):
         out_shape=jax.ShapeDtypeStruct((Mp, Np), x2.dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
         interpret=interpret,
+        name="lora_delta",
     )(xp, a, bp, sc, sl)
     return out[:M, :N]
 

@@ -281,6 +281,7 @@ def _quant_matmul_pallas(x2, qw, scales, mode: str, block: int,
         out_shape=jax.ShapeDtypeStruct((Mp, Np), x2.dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
         interpret=interpret,
+        name="quant_matmul",
     )(xp, wp, sp)
     return out[:M, :N]
 

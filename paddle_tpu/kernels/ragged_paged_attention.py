@@ -244,6 +244,7 @@ def _ragged_pallas(q, k_pages, v_pages, start_pos, num_valid, page_indices,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, H, Cp, D), q.dtype),
         interpret=interpret,
+        name="ragged_paged_attention",
     )(page_indices, start_pos, num_valid, *args)
     out = jnp.transpose(out[:, :, :C], (0, 2, 1, 3))      # [B, C, H, D]
     row_ok = (jnp.arange(C, dtype=jnp.int32)[None, :]

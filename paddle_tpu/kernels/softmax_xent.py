@@ -113,6 +113,7 @@ def _fwd_impl(logits2, labels):
             jax.ShapeDtypeStruct((sp.shape[0], LANES), jnp.float32),
         ],
         interpret=_interpret(),
+        name="softmax_xent_fwd",
     )(sp, lp)
     return loss[:true_r, 0], lse[:true_r]
 
@@ -144,6 +145,7 @@ def _vjp_bwd(res, dloss):
         out_specs=pl.BlockSpec((BLOCK_R, C), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct(sp.shape, logits2.dtype),
         interpret=_interpret(),
+        name="softmax_xent_bwd",
     )(sp, lp, lsep, dlp)
     return ds[:true_r], None
 

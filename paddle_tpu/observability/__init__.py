@@ -9,11 +9,17 @@ needs from one place:
   dispatch/compile caches, executors, supervisors and data loaders all
   register into it, so a single ``/metrics`` scrape (or
   ``observability.snapshot()``) shows the whole stack.
-* ``tracing`` — spans with trace/span/parent ids layered on
-  ``profiler.record_event``, propagated across threads (serving
-  request -> micro-batch -> worker -> jit step; supervisor step ->
-  retry/rollback), rendered as Perfetto flow arrows by
-  ``tools_timeline``.
+* ``tracing`` — ``span(name)``. Always on: a named range on the
+  profiler's clock (``jax.profiler.TraceAnnotation``), so any attached
+  profiler session reads the Executor's ``executor/bind|feed|step|
+  fetch`` and the GenerationEngine's ``generation/<phase>`` loop
+  phases beside the device trace, whatever the flags say
+  (``annotation(name)`` is that and nothing more, flag or no flag). What
+  ``observability_tracing`` adds: trace/span/parent ids, propagated
+  across threads (serving request -> micro-batch -> worker -> jit
+  step; supervisor step -> retry/rollback), ``flow_from`` arrows
+  rendered by ``tools_timeline``, and the flight-ring copy of every
+  span.
 * ``flight`` — an always-on constant-memory flight recorder dumped to
   JSON on NaN rollback, watchdog hang, uncaught loop exception,
   SIGTERM and SIGUSR2.

@@ -1,9 +1,10 @@
-"""Trace spans with parentage, layered on ``profiler.record_event``.
+"""Trace spans: named host ranges on the profiler's clock, with
+parentage when ``observability_tracing`` is on.
 
-``profiler.record_event`` gives named host ranges; what it cannot say
-is which serving request a micro-batch served, or which supervisor
-step a rollback undid — ranges on different threads have no shared
-identity. A span adds exactly that: a ``trace_id`` (one per root
+A ``jax.profiler.TraceAnnotation`` gives named host ranges; what it
+cannot say is which serving request a micro-batch served, or which
+supervisor step a rollback undid — ranges on different threads have no
+shared identity. A span adds exactly that: a ``trace_id`` (one per root
 request/step), a ``span_id``, and a ``parent_id``, carried in the
 event's ``args`` so ``tools_timeline`` can draw Perfetto flow arrows
 across threads (serving request -> admission queue -> micro-batch ->
@@ -15,14 +16,26 @@ the submitting side stores ``ctx = span(...)``'s yielded context on
 the work item, and the consuming thread opens its span with
 ``parent=ctx`` (or wraps its whole handling in ``attach(ctx)``).
 
-Cost model: with ``observability_tracing`` off (the default), ``span``
-is exactly ``profiler.record_event`` — the pre-existing behavior of
-every call site this API replaced. With it on, a span is a slotted
-class-based context manager (no generator frames on the hot path):
-two lock-free id draws, one TraceAnnotation, one conditional
-host-event append, one flight-ring append. ``tools/obs_bench.py``
-gates the combined metrics+tracing per-step cost at <3% of a bare
-step.
+Cost model. ALWAYS ON, whatever the flag: every ``span`` is a
+``jax.profiler.TraceAnnotation`` — a named range in any profiler
+session attached to the process (``jax.profiler.start_trace``, the
+benchmark's ``--trace 1``), on the clock the device trace shares, and
+a no-op in the runtime when no session records — plus, inside a
+``paddle_tpu.profiler`` session (``profiler._recording``, one read of
+a plain bool), one host-event append. That off path is a slotted
+context manager of about a microsecond, so the step loops (``Executor``
+/ ``BoundStep``: ``executor/bind|feed|step|fetch``; ``GenerationEngine``:
+``generation/<phase>``) open their spans unconditionally, under
+CONSTANT names: what varies per step goes into ``args``, which may be
+a callable and is then built only when something will keep it. With
+``observability_tracing`` ON a span additionally gets its identity:
+two lock-free id draws, parentage from the thread-local stack,
+``flow_from`` arrows, one flight-ring append (so a trace can be
+assembled across threads and processes) — ``executor/step`` and
+``generation/step`` do, as before; the other phases of the two loops are
+``annotation``s, which stay on the off path, so the flag still costs one
+ring entry a step. ``tools/obs_bench.py`` gates the combined
+metrics+tracing per-step cost at <3% of a bare step.
 """
 
 from __future__ import annotations
@@ -31,13 +44,16 @@ import functools
 import os
 import threading
 import time
-from typing import Any, Dict, NamedTuple, Optional
+from typing import Any, Callable, Dict, NamedTuple, Optional, Union
+
+from jax.profiler import TraceAnnotation as _TraceAnnotation
 
 from .. import profiler
 from ..flags import _flags  # hot path: direct flag-store reads
 from . import flight
 
-__all__ = ["SpanContext", "span", "traced", "attach", "current", "enabled"]
+__all__ = ["SpanContext", "span", "annotation", "traced", "attach", "current",
+           "enabled"]
 
 
 class SpanContext(NamedTuple):
@@ -116,17 +132,8 @@ class _Span:
 
     def __enter__(self) -> SpanContext:
         self._stack.append(self.ctx)
-        # the device-trace annotation only matters inside a profiling
-        # session (sessions started via paddle_tpu.profiler flip
-        # _recording); outside one, skipping it keeps the per-step
-        # span within the obs_bench overhead budget
-        if profiler._recording:
-            import jax
-
-            self._ta = jax.profiler.TraceAnnotation(self.name)
-            self._ta.__enter__()
-        else:
-            self._ta = None
+        self._ta = _TraceAnnotation(self.name)
+        self._ta.__enter__()
         self.t0 = time.time()
         return self.ctx
 
@@ -137,8 +144,7 @@ class _Span:
 
     def __exit__(self, *exc):
         dur = time.time() - self.t0
-        if self._ta is not None:
-            self._ta.__exit__(*exc)
+        self._ta.__exit__(*exc)
         self._stack.pop()
         profiler.emit_event(self.name, self.t0, dur, self.meta)
         entry = {"kind": "span", "t": self.t0, "name": self.name,
@@ -150,18 +156,59 @@ class _Span:
         return False
 
 
-def span(name: str, args: Optional[Dict[str, Any]] = None, parent=_AMBIENT):
-    """Context manager for one traced range. Yields the SpanContext
-    (or None when tracing is off — it then degrades to a plain
-    ``profiler.record_event``, which is what these call sites did
-    before tracing existed).
+class _Annotation:
+    """The off path of ``span``: the range on the profiler's clock and,
+    inside a ``paddle_tpu.profiler`` session, the host-event log entry.
+    No ids, no parentage, no flight ring."""
+
+    __slots__ = ("name", "args", "t0", "_ta")
+
+    def __init__(self, name: str, args):
+        self.name = name
+        self.args = args
+
+    def __enter__(self) -> None:
+        self._ta = _TraceAnnotation(self.name)
+        self._ta.__enter__()
+        self.t0 = time.time() if profiler._recording else 0.0
+        return None
+
+    def __exit__(self, *exc):
+        self._ta.__exit__(*exc)
+        if self.t0:
+            args = self.args
+            profiler.emit_event(self.name, self.t0, time.time() - self.t0,
+                                args() if callable(args) else args)
+        return False
+
+
+def span(name: str,
+         args: Union[Dict[str, Any], Callable[[], Dict[str, Any]], None] = None,
+         parent=_AMBIENT):
+    """Context manager for one traced range, under a CONSTANT ``name``.
+    Yields the SpanContext (or None when ``observability_tracing`` is
+    off: the range is then a profiler annotation and nothing more).
+
+    ``args``: a dict of what varies (step number, row count,
+    ``flow_from`` span ids), or a callable returning one — called only
+    when the flag is on or a ``paddle_tpu.profiler`` session records,
+    so a hot loop pays nothing to describe a span nobody keeps.
 
     ``parent``: default is the ambient thread-local span; pass an
     explicit SpanContext to stitch across threads, or None to force a
     new root trace."""
     if not _flags["observability_tracing"]:
-        return profiler.record_event(name, args)
-    return _Span(name, args, parent)
+        return _Annotation(name, args)
+    return _Span(name, args() if callable(args) else args, parent)
+
+
+def annotation(name: str) -> _Annotation:
+    """The off path of ``span`` whatever the flag says: for the phases
+    of a hot loop, whose place is the timeline beside the device trace,
+    not a request's trace. Were they full spans, ``observability_tracing``
+    would put a dozen flight-ring entries a step where one was, and a
+    request's submit span would leave the ring within seconds."""
+    return _Annotation(name, None)
 
 
 class _Attach:

@@ -50,6 +50,8 @@ from typing import Any, Callable, Dict, Iterable, List, Optional, Tuple
 
 import numpy as np
 
+from ..observability import tracing
+
 _log = logging.getLogger("paddle_tpu.dispatch")
 
 # -- global (process-wide) state -------------------------------------------
@@ -372,7 +374,7 @@ class BoundStep:
     __slots__ = (
         "executor", "compiled", "scope", "block", "base_key",
         "feed_plan", "state_vals", "written_into_state", "scope_gen",
-        "n_fetch", "benchmark", "obs_tel", "trace", "rows_hint",
+        "n_fetch", "benchmark", "obs_tel", "rows_hint",
         "host_sync_calls", "feed_avals", "__weakref__",
     )
 
@@ -397,13 +399,6 @@ class BoundStep:
             from ..observability.registry import step_telemetry
 
             self.obs_tel = step_telemetry()
-        # the tracing module itself when spans are on, else None —
-        # saves a per-step sys.modules lookup on the traced path
-        self.trace = None
-        if flag("observability_tracing"):
-            from ..observability import tracing
-
-            self.trace = tracing
         # raw_dtypes: the CALLER's per-feed dtypes (pre-normalization)
         # — the plan must normalize what actually arrives each step
         raw_dtypes = raw_dtypes or {}
@@ -512,7 +507,8 @@ class BoundStep:
 
     # -- the hot path -------------------------------------------------------
     def run(self, feed: Dict[str, Any], return_numpy: bool):
-        ordered = [norm(feed[n]) for n, norm in self.feed_plan]
+        with tracing.annotation("executor/feed"):
+            ordered = [norm(feed[n]) for n, norm in self.feed_plan]
         return self._run_ordered(ordered, return_numpy)
 
     def _run_ordered(self, ordered: List[Any], return_numpy: bool):
@@ -541,13 +537,11 @@ class BoundStep:
         t_obs = time.perf_counter() if tel is not None else 0.0
         if compiled.compile_time is None:
             outs = self._first_call(fn, counter, ordered)
-        elif self.trace is not None:
-            with self.trace.span("executor/step",
-                                 {"step": int(counter),
-                                  "tag": compiled.tag or "program"}):
-                outs = fn(self.base_key, counter, *ordered, *self.state_vals)
         else:
-            outs = fn(self.base_key, counter, *ordered, *self.state_vals)
+            with tracing.span("executor/step",
+                              lambda: {"step": int(counter),
+                                       "tag": compiled.tag or "program"}):
+                outs = fn(self.base_key, counter, *ordered, *self.state_vals)
         n_fetch = self.n_fetch
         new_state = outs[n_fetch:]
         if new_state:
@@ -595,7 +589,9 @@ class BoundStep:
 
             if fetched:
                 self.host_sync_calls += 1
-            fetched = [_fetch_to_host(v) for v in fetched]
+            # where the host waits for the device
+            with tracing.annotation("executor/fetch"):
+                fetched = [_fetch_to_host(v) for v in fetched]
         return fetched
 
     # -- async host/device pipeline -----------------------------------------

@@ -438,9 +438,9 @@ def test_decode_steps_join_request_trace(predictor):
         evs = [e for e in flight.entries()
                if "generation" in str(e.get("name", ""))]
         names = {e["name"] for e in evs}
-        assert any(n.startswith("generation/ragged_step") for n in names)
+        assert "generation/step" in names
         subs = [e for e in evs if e["name"] == "generation/submit"]
-        steps = [e for e in evs if "ragged_step" in e["name"]]
+        steps = [e for e in evs if e["name"] == "generation/step"]
         assert subs and steps
         sub_ids = {s["span_id"] for s in subs}
         assert any(set(e.get("flow_from") or []) & sub_ids for e in steps)

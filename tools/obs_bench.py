@@ -123,8 +123,16 @@ def measure_loops(reps=5, timed=150):
 
         t0 = time.perf_counter()
         for i in range(n):
+            # what one Executor.run opens: three annotations (always
+            # on, flag or no flag) and the one span the flag gives ids
+            with tracing.annotation("executor/bind"):
+                pass
+            with tracing.annotation("executor/feed"):
+                pass
             t_obs = time.perf_counter()  # the pair BoundStep pays
             with tracing.span("executor/step", {"step": i, "tag": "bench"}):
+                pass
+            with tracing.annotation("executor/fetch"):
                 pass
             tel.record((time.perf_counter() - t_obs) * 1e3, 8, step=i)
         machinery_s = (time.perf_counter() - t0) / n
