@@ -299,7 +299,11 @@ class GenerationMetrics:
                  # speculative decoding (exported as the
                  # paddle_generation_spec_* gauge family)
                  "spec_rounds_total", "spec_proposed_total",
-                 "spec_accepted_total"
+                 "spec_accepted_total",
+                 # ragged mode: pages the step's lanes hold (what the
+                 # attention kernel walks) against the width of their
+                 # block tables (lanes x max_pages_per_seq)
+                 "attn_live_pages_total", "attn_table_pages_total"
                  # ragged mode: wall microseconds of the loop thread by
                  # phase, counted where the generation/<phase> span
                  # closes (GenerationEngine._phase)
@@ -1370,6 +1374,11 @@ class GenerationEngine:
                     pos_ids[slot, :row.size] = np.arange(L0, L0 + row.size)
                     positions[slot] = L0
                     num_valid[slot] = row.size
+            live = positions + num_valid
+            self.metrics.inc("attn_live_pages_total", int(
+                (-(-live[num_valid > 0] // self.geom.page_size)).sum()))
+            self.metrics.inc("attn_table_pages_total",
+                             R * self.geom.max_pages_per_seq)
             feed = {
                 "gen_tokens": tokens,
                 "gen_pos_ids": pos_ids,

@@ -42,6 +42,27 @@ including PADDLE_TPU_KERNEL_INTERPRET=1, which runs the real kernel
 body in interpreter mode. The reference is the numerics oracle AND the
 CPU-CI execution path.
 
+The kernel's loop: the grid is one step a lane, every head at once,
+and nothing in it is as wide as the block tables. Inside a grid step
+the kernel walks the lane's LIVE keys only, in blocks of several pages:
+the trip count is cdiv(start_pos + num_valid, block keys), read from
+the scalar-prefetched arrays (0 for an idle lane, which writes zeros),
+so a lane holding 13 pages of a 128-entry table does 13 pages of work.
+The pools stay in HBM; for each page of a block one strided async copy
+takes that page of every KV head ([KVH, ps, D] out of [KVH, P, ps, D])
+into one of two VMEM buffers, so block i + 1 is in flight while block i
+is multiplied: a KV head's query rows (group * chunk of them) against
+[block keys, D], batched over the KV heads. The block's size comes from
+the static shapes and a VMEM budget (_block_pages: 8 float32 pages of
+16 x 128 for 16 heads); only live pages are copied, so table entries
+past them (junk page 0) are never read, and the dead tail of a lane's
+last block is masked (stale K) or zeroed (stale V) in VMEM. int8
+pages: the per-key scales are gathered along each lane's table into
+block order outside the kernel (keys on the lane axis) and multiply
+the scores and the probabilities, q.(k * s) = (q.k) * s. Head dims
+that are not whole lane tiles are zero-padded (a copy of the pool a
+call): 128 is the fast case.
+
 Precision on the chip: the kernel computes in float32 whatever the
 pages hold — operands are up-cast, the online-softmax accumulators are
 float32, and the two matmuls (q.k^T, p.v) ask Mosaic for
@@ -126,130 +147,223 @@ def _reference_ragged(q, k_pages, v_pages, start_pos, num_valid,
 
 # -- Pallas lowering ---------------------------------------------------------
 
+# what the K and V blocks of a grid step may hold in VMEM: two buffers
+# each (float32, 16 heads x 128 keys x 128 = 1 MiB a buffer)
+_BLOCK_VMEM_BYTES = 4 << 20
 
-def _make_ragged_kernel(C: int, ps: int, maxp: int, sm_scale: float,
-                        quantized: bool):
+
+def _block_pages(kvh: int, ps: int, d: int, itemsize: int, rows: int,
+                 maxp: int) -> int:
+    """Pages of one K/V block, from the static shapes alone: the
+    double-buffered K and V blocks fill _BLOCK_VMEM_BYTES, the
+    [kvh, rows, keys] float32 score tile stays under a quarter of it,
+    and pages narrower than a lane tile come in whole tiles of keys."""
+    bp = _BLOCK_VMEM_BYTES // (2 * 2 * kvh * ps * d * itemsize)
+    bp = min(bp, _BLOCK_VMEM_BYTES // 4 // (kvh * rows * 4 * ps), maxp)
+    tile = max(1, LANES // ps)
+    return max(1, bp - bp % tile if bp >= tile else bp)
+
+
+def _block_scales(scales, page_indices, npages, bp: int):
+    """[KVH, P, ps] scale planes -> [B, blocks, KVH, bp * ps]: each
+    lane's scales in the order its blocks walk the keys, keys on the
+    lane axis (where the kernel scales scores and probabilities; a
+    [.., ps, 1] column cannot be sliced out of a tiled pool). Columns
+    past a lane's live pages repeat its last live page: the kernel
+    multiplies them into masked scores and zero probabilities, so
+    they must be finite, which a dead table entry's need not be."""
+    KVH, _P, ps = scales.shape
+    B, maxp = page_indices.shape
+    blocks = -(-maxp // bp)
+    col = jnp.minimum(jnp.arange(blocks * bp, dtype=jnp.int32)[None, :],
+                      jnp.maximum(npages - 1, 0)[:, None])
+    pages = jnp.take_along_axis(page_indices, col, axis=1)
+    return jnp.transpose(
+        scales[:, pages].reshape(KVH, B, blocks, bp * ps), (1, 2, 0, 3))
+
+
+def _make_ragged_kernel(C: int, group: int, ps: int, bp: int,
+                        sm_scale: float, quantized: bool):
+    """One grid step = one lane, every head: walk the lane's live keys
+    in blocks of ``bp`` pages. ``C`` is the padded chunk; a KV head's
+    ``group`` query heads ride as group * C rows of one matmul."""
     from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    bk = bp * ps
 
     def kernel(*refs):
         it = iter(refs)
         tables_ref, starts_ref, nvalid_ref = next(it), next(it), next(it)
-        q_ref, k_ref, v_ref = next(it), next(it), next(it)
+        q_ref = next(it)
+        pools = next(it), next(it)       # K and V, whole, in HBM
         ks_ref = next(it) if quantized else None
         vs_ref = next(it) if quantized else None
         o_ref = next(it)
+        bufs = next(it), next(it)        # their two-slot VMEM blocks
+        sem = next(it)
         acc_ref, m_ref, l_ref = next(it), next(it), next(it)
 
-        b, p = pl.program_id(0), pl.program_id(2)
+        b = pl.program_id(0)
         start = starts_ref[b]
-        total = start + nvalid_ref[b]    # keys written for this row
-        del tables_ref                   # consumed by the index maps
+        # keys this lane attends; an idle lane walks nothing
+        total = jnp.where(nvalid_ref[b] > 0, start + nvalid_ref[b], 0)
+        npages = pl.cdiv(total, ps)
+        nblocks = pl.cdiv(total, bk)
 
-        @pl.when(p == 0)
-        def init():  # noqa: ANN202
-            acc_ref[...] = jnp.zeros_like(acc_ref)
-            m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-            l_ref[...] = jnp.zeros_like(l_ref)
+        def fetch(i, slot):
+            # block i -> buffer `slot`: one strided copy a live page
+            # takes that page of every KV head. Table entries past the
+            # lane's live pages are never read.
+            for j in range(bp):
+                @pl.when(i * bp + j < npages)
+                def live():  # noqa: ANN202
+                    page = tables_ref[b, i * bp + j]
+                    for pool, buf in zip(pools, bufs):
+                        pltpu.make_async_copy(pool.at[:, page],
+                                              buf.at[slot, :, j],
+                                              sem.at[slot]).start()
 
-        @pl.when(p * ps < total)
-        def body():  # noqa: ANN202
-            q = q_ref[0, 0].astype(jnp.float32) * sm_scale     # [C, D]
-            k = k_ref[0, 0].astype(jnp.float32)                # [ps, D]
-            v = v_ref[0, 0].astype(jnp.float32)
-            if quantized:
-                # scale planes ride as [KVH, P, ps, 1] blocks (Mosaic
-                # wants the trailing dims tile-aligned or exact)
-                k = k * ks_ref[0, 0].astype(jnp.float32)
-                v = v * vs_ref[0, 0].astype(jnp.float32)
+        def wait(i, slot):
+            # the last block's pages past the live ones hold whatever
+            # the buffer held: stale K is masked with its scores, stale
+            # V would meet a probability of 0 as 0 * NaN, so it is zeroed
+            vbuf = bufs[1]
+            for j in range(bp):
+                @pl.when(i * bp + j < npages)
+                def live():  # noqa: ANN202
+                    for pool, buf in zip(pools, bufs):
+                        pltpu.make_async_copy(pool.at[:, 0],
+                                              buf.at[slot, :, j],
+                                              sem.at[slot]).wait()
+
+                @pl.when(i * bp + j >= npages)
+                def dead():  # noqa: ANN202
+                    vbuf[slot, :, j] = jnp.zeros(
+                        (vbuf.shape[1],) + vbuf.shape[3:], vbuf.dtype)
+
+        @pl.when(nblocks > 0)
+        def first():  # noqa: ANN202
+            fetch(0, 0)
+
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+        l_ref[...] = jnp.zeros_like(l_ref)
+        q = q_ref[0].astype(jnp.float32) * sm_scale       # [KVH, G*C, D]
+        kvh = q.shape[0]
+        # row g * C + j of a KV head is query j of its g-th query head
+        qpos = start + jax.lax.broadcasted_iota(
+            jnp.int32, (group, C, bk), 1).reshape(group * C, bk)
+        key = jax.lax.broadcasted_iota(jnp.int32, (group * C, bk), 1)
+
+        def block(i, carry):
+            slot = i % 2
+
+            @pl.when(i + 1 < nblocks)
+            def ahead():  # noqa: ANN202
+                fetch(i + 1, 1 - slot)
+
+            wait(i, slot)
+            k, v = (buf[slot].astype(jnp.float32).reshape(kvh, bk, -1)
+                    for buf in bufs)
             s = jax.lax.dot_general(
-                q, k, (((1,), (1,)), ((), ())), precision=_F32_DOT,
-                preferred_element_type=jnp.float32)            # [C, ps]
-            kpos = p * ps + jax.lax.broadcasted_iota(
-                jnp.int32, (C, ps), 1)
-            qpos = start + jax.lax.broadcasted_iota(
-                jnp.int32, (C, ps), 0)
-            s = jnp.where(kpos <= qpos, s, NEG_INF)
-            m_prev = m_ref[:, 0]
-            m_curr = s.max(axis=-1)
-            m_next = jnp.maximum(m_prev, m_curr)
+                q, k, (((2,), (2,)), ((0,), (0,))), precision=_F32_DOT,
+                preferred_element_type=jnp.float32)        # [KVH, G*C, bk]
+            if quantized:
+                # int8 pages: q.(k * scale) = (q.k) * scale, a key's
+                # scale applied where keys lie on the lane axis
+                s = s * ks_ref[0, i][:, None, :]
+            s = jnp.where((i * bk + key <= qpos)[None], s, NEG_INF)
+            m_prev = m_ref[:, :, :1]
+            m_next = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
             alpha = jnp.exp(m_prev - m_next)
-            pexp = jnp.exp(s - m_next[:, None])
+            pexp = jnp.exp(s - m_next)
             l_ref[...] = jnp.broadcast_to(
-                (alpha * l_ref[:, 0] + pexp.sum(axis=-1))[:, None],
+                alpha * l_ref[:, :, :1] + pexp.sum(axis=-1, keepdims=True),
                 l_ref.shape)
-            m_ref[...] = jnp.broadcast_to(m_next[:, None], m_ref.shape)
-            acc_ref[...] = acc_ref[...] * alpha[:, None] + jnp.dot(
-                pexp, v, precision=_F32_DOT,
+            m_ref[...] = jnp.broadcast_to(m_next, m_ref.shape)
+            if quantized:
+                pexp = pexp * vs_ref[0, i][:, None, :]
+            acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
+                pexp, v, (((2,), (1,)), ((0,), (0,))), precision=_F32_DOT,
                 preferred_element_type=jnp.float32)
+            return carry
 
-        @pl.when(p == maxp - 1)
-        def finish():  # noqa: ANN202
-            denom = l_ref[:, 0]
-            denom = jnp.where(denom == 0.0, 1.0, denom)   # len-0 row -> 0
-            o_ref[0, 0] = (acc_ref[...] / denom[:, None]).astype(o_ref.dtype)
+        jax.lax.fori_loop(0, nblocks, block, None)
+        denom = l_ref[:, :, :1]
+        denom = jnp.where(denom == 0.0, 1.0, denom)       # idle lane -> 0
+        o_ref[0] = (acc_ref[...] / denom).astype(o_ref.dtype)
 
     return kernel
 
 
+# jitted so that a step program's 24 layers, and every later trace of
+# it, share one trace and one lowering of the kernel (unrolled over a
+# block's pages, it costs 0.15 s to trace: seconds of set-up otherwise)
+@functools.partial(jax.jit, static_argnames=("sm_scale", "interpret"))
 def _ragged_pallas(q, k_pages, v_pages, start_pos, num_valid, page_indices,
-                   sm_scale: float, k_scales, v_scales, interpret: bool):
+                   sm_scale: float, k_scales, v_scales, interpret):
+    """``interpret``: False on the chip, True for the Pallas interpreter
+    (or a pltpu.InterpretParams for the TPU one, which is slower and
+    starts every buffer as NaN)."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    B, C, H, D = q.shape
+    B, C, H, D0 = q.shape
     KVH, _P, ps, _ = k_pages.shape
-    maxp = page_indices.shape[1]
     quantized = k_scales is not None
-    # sublane-align the chunk so the [C, D] scratch tiles cleanly
+    # sublane-align the chunk so the [C, D] scratch tiles cleanly, and
+    # lane-align the head dim: a copy out of the pool moves whole lane
+    # tiles. Zero columns change no score and are cut off the output;
+    # the pad copies the pool, so head dims of whole tiles (128) are
+    # the fast case.
     Cp = -(-C // 8) * 8
-    qt = jnp.transpose(q, (0, 2, 1, 3))                   # [B, H, C, D]
-    if Cp != C:
-        qt = jnp.pad(qt, ((0, 0), (0, 0), (0, Cp - C), (0, 0)))
+    D = -(-D0 // LANES) * LANES
+    qt = jnp.pad(jnp.transpose(q, (0, 2, 1, 3)),          # [B, H, C, D]
+                 ((0, 0), (0, 0), (0, Cp - C), (0, D - D0)))
+    if D != D0:
+        k_pages, v_pages = (jnp.pad(p, ((0, 0),) * 3 + ((0, D - D0),))
+                            for p in (k_pages, v_pages))
     group = H // KVH
-
-    def kv_idx(b, h, p, tables, starts, nvalid):
-        del starts, nvalid
-        return (h // group, tables[b, p], 0, 0)
-
-    def scale_idx(b, h, p, tables, starts, nvalid):
-        del starts, nvalid
-        return (h // group, tables[b, p], 0, 0)
-
-    in_specs = [
-        pl.BlockSpec((1, 1, Cp, D),
-                     lambda b, h, p, *refs: (b, h, 0, 0)),    # q
-        pl.BlockSpec((1, 1, ps, D), kv_idx),                  # k page
-        pl.BlockSpec((1, 1, ps, D), kv_idx),                  # v page
-    ]
-    args = [qt, k_pages, v_pages]
+    rows = group * Cp          # query head h = kv head h // group
+    bp = _block_pages(KVH, ps, D, k_pages.dtype.itemsize, rows,
+                      page_indices.shape[1])
+    lane_block = pl.BlockSpec((1, KVH, rows, D), lambda b, *refs: (b, 0, 0, 0))
+    in_specs = [lane_block] + [pl.BlockSpec(memory_space=pl.ANY)] * 2
+    args = [qt.reshape(B, KVH, rows, D), k_pages, v_pages]
     if quantized:
-        in_specs += [pl.BlockSpec((1, 1, ps, 1), scale_idx),
-                     pl.BlockSpec((1, 1, ps, 1), scale_idx)]
-        args += [k_scales[..., None], v_scales[..., None]]
+        npages = -(-jnp.where(num_valid > 0, start_pos + num_valid, 0) // ps)
+        args += [_block_scales(sc, page_indices, npages, bp)
+                 for sc in (k_scales, v_scales)]
+        in_specs += [pl.BlockSpec((1,) + args[-1].shape[1:],
+                                  lambda b, *refs: (b, 0, 0, 0))] * 2
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
-        grid=(B, H, maxp),
+        grid=(B,),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, 1, Cp, D),
-                               lambda b, h, p, *refs: (b, h, 0, 0)),
+        out_specs=lane_block,
         scratch_shapes=[
-            pltpu.VMEM((Cp, D), jnp.float32),       # acc
-            pltpu.VMEM((Cp, LANES), jnp.float32),   # m
-            pltpu.VMEM((Cp, LANES), jnp.float32),   # l
+            pltpu.VMEM((2, KVH, bp, ps, D), k_pages.dtype),
+            pltpu.VMEM((2, KVH, bp, ps, D), v_pages.dtype),
+            pltpu.SemaphoreType.DMA((2,)),
+            pltpu.VMEM((KVH, rows, D), jnp.float32),       # acc
+            pltpu.VMEM((KVH, rows, LANES), jnp.float32),   # m
+            pltpu.VMEM((KVH, rows, LANES), jnp.float32),   # l
         ],
     )
-    kernel = _make_ragged_kernel(Cp, ps, maxp, sm_scale, quantized)
+    kernel = _make_ragged_kernel(Cp, group, ps, bp, sm_scale, quantized)
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, H, Cp, D), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((B, KVH, rows, D), q.dtype),
         interpret=interpret,
         name="ragged_paged_attention",
     )(page_indices, start_pos, num_valid, *args)
-    out = jnp.transpose(out[:, :, :C], (0, 2, 1, 3))      # [B, C, H, D]
+    out = jnp.transpose(out.reshape(B, H, Cp, D)[:, :, :C, :D0],
+                        (0, 2, 1, 3))
     row_ok = (jnp.arange(C, dtype=jnp.int32)[None, :]
               < num_valid[:, None])
-    return jnp.where(row_ok[..., None, None], out, 0.0)
+    return jnp.where(row_ok[..., None, None], out, 0.0)   # [B, C, H, D]
 
 
 # -- public entry ------------------------------------------------------------

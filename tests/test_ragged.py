@@ -17,6 +17,7 @@ Correctness anchors:
 """
 
 import http.client
+import importlib
 import json
 import threading
 import time
@@ -230,6 +231,147 @@ def test_quantized_kernel_error_bound_and_junk_isolation():
     assert np.allclose(np.asarray(ks2b)[:, 1:], 1.0)
 
 
+def _pool_batch(case):
+    """A ragged batch drawn straight into the pool (no kv_cache_write):
+    every lane's live pages hold random K/V, the rest of the pool and
+    every table entry past a lane's live pages whatever ``case`` says.
+    Shapes are small but real enough for the interpreter to walk the
+    kernel's blocks: 2 pages a block (the test shrinks the kernel's
+    VMEM budget), so 40 keys span three blocks."""
+    rng = np.random.RandomState(7)
+    ps, D, KVH, P, maxp = 8, 16, 2, 40, 12
+    H, C = KVH * case.get("group", 1), case.get("C", 4)
+    starts = np.asarray(case["starts"], np.int32)
+    nvalid = np.asarray(case["nvalid"], np.int32)
+    B = len(starts)
+    k = rng.randn(KVH, P, ps, D).astype(np.float32)
+    v = rng.randn(KVH, P, ps, D).astype(np.float32)
+    dead = case.get("dead_page", 0)
+    if case.get("nan_dead"):
+        k[:, dead] = np.nan
+        v[:, dead] = np.nan
+    tables = np.full((B, maxp), dead, np.int32)
+    free = [p for p in rng.permutation(np.arange(1, P)) if p != dead]
+    for b in range(B):
+        need = -(-int(starts[b] + nvalid[b]) // ps) if nvalid[b] else 0
+        tables[b, :need] = [free.pop() for _ in range(need)]
+    q = rng.randn(B, C, H, D).astype(np.float32)
+    return q, k, v, starts, nvalid, tables
+
+
+_BLOCK_CASES = {
+    # 40 keys = 5 pages = three blocks of two pages
+    "three_blocks": dict(starts=[36, 0], nvalid=[4, 4]),
+    # 21 keys end inside page 2 and inside block 1; 9 inside page 1
+    "mid_block_mid_page": dict(starts=[18, 8], nvalid=[3, 1]),
+    # decode only: C = 1 pads to the 8-row tile
+    "decode_only": dict(C=1, starts=[33, 5, 16], nvalid=[1, 1, 1]),
+    # two query heads a KV head
+    "grouped_query": dict(group=2, starts=[20, 0], nvalid=[4, 3]),
+    "int8_pages": dict(int8=True, starts=[36, 3], nvalid=[4, 2]),
+    # entries past the live pages name a page full of NaN: never used
+    "nan_dead_entries": dict(nan_dead=True, dead_page=9,
+                             starts=[17, 0, 30], nvalid=[4, 0, 2]),
+    "all_idle": dict(starts=[0, 12, 0], nvalid=[0, 0, 0]),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_BLOCK_CASES))
+def test_ragged_kernel_blocks_interpret(case, monkeypatch):
+    """The kernel body (interpreter mode) walking a lane's live keys in
+    blocks of several pages, against _reference_ragged and the dense
+    oracle: contexts of three blocks, ends inside a block and a page,
+    C = 1, grouped queries, int8 pages, dead table entries, idle
+    lanes."""
+    import jax.numpy as jnp
+
+    from paddle_tpu.kernels import quant
+
+    # by module path: the package rebinds the name to the function
+    rpa = importlib.import_module(
+        "paddle_tpu.kernels.ragged_paged_attention")
+    spec = _BLOCK_CASES[case]
+    q, k, v, starts, nvalid, tables = _pool_batch(spec)
+    KVH, P, ps, D = k.shape
+    # K and V of two pages, two buffers each (head dim padded to a
+    # lane tile): blocks of two pages
+    monkeypatch.setattr(rpa, "_BLOCK_VMEM_BYTES",
+                        2 * 2 * 2 * KVH * ps * 128 * 4)
+    assert rpa._block_pages(KVH, ps, 128, 4, 8, tables.shape[1]) == 2
+    scales = {}
+    kp, vp = jnp.asarray(k), jnp.asarray(v)
+    if spec.get("int8"):
+        (kp, ks), (vp, vs) = (quant.blockwise_quantize(
+            jnp.asarray(a).reshape(-1, D)) for a in (k, v))
+        kp, vp = kp.reshape(k.shape), vp.reshape(v.shape)
+        scales = dict(k_scales=ks.reshape(KVH, P, ps),
+                      v_scales=vs.reshape(KVH, P, ps))
+        # what the pool holds is the oracle's K/V
+        k = np.asarray(quant.blockwise_dequantize(
+            kp.reshape(-1, D).astype(jnp.float32), ks)).reshape(k.shape)
+        v = np.asarray(quant.blockwise_dequantize(
+            vp.reshape(-1, D).astype(jnp.float32), vs)).reshape(v.shape)
+    args = (jnp.asarray(q), kp, vp, jnp.asarray(starts),
+            jnp.asarray(nvalid), jnp.asarray(tables))
+    monkeypatch.setenv("PADDLE_TPU_KERNEL_INTERPRET", "1")
+    out = np.asarray(rpa.ragged_paged_attention(*args, **scales))
+    assert np.all(np.isfinite(out))
+    group = q.shape[2] // KVH
+    for b in range(len(starts)):
+        total = int(starts[b] + nvalid[b])
+        pages = tables[b, :-(-total // ps)]
+        keys = k[:, pages].reshape(KVH, -1, D).repeat(group, 0)
+        vals = v[:, pages].reshape(KVH, -1, D).repeat(group, 0)
+        for j in range(int(nvalid[b])):
+            n = int(starts[b]) + j + 1
+            np.testing.assert_allclose(
+                out[b, j], _dense_row(q[b, j], keys[:, :n].swapaxes(0, 1),
+                                      vals[:, :n].swapaxes(0, 1), D),
+                rtol=2e-5, atol=2e-5)
+        assert np.all(out[b, int(nvalid[b]):] == 0.0)
+    if case in ("mid_block_mid_page", "nan_dead_entries"):
+        # the TPU interpreter starts every buffer as NaN and runs the
+        # copies and semaphores as such: what a block's dead tail
+        # leaves unwritten in VMEM must not reach the output either
+        from jax.experimental.pallas import tpu as pltpu
+
+        tpu = rpa._ragged_pallas(*args, 1.0 / np.sqrt(D), None, None,
+                                 pltpu.InterpretParams())
+        np.testing.assert_allclose(np.asarray(tpu), out, rtol=1e-6,
+                                   atol=1e-6)
+    if not spec.get("nan_dead"):    # the reference gathers dead entries too
+        ref = np.asarray(rpa._reference_ragged(
+            *args, 1.0 / np.sqrt(D), scales.get("k_scales"),
+            scales.get("v_scales")))
+        np.testing.assert_allclose(out, ref, rtol=2e-5, atol=2e-5)
+
+
+def test_ragged_kernel_grid_ignores_table_width():
+    """The work no longer follows the table: the same live pages under
+    block tables 8 and 128 entries wide trace to the same grid, and no
+    grid dimension is the table's width."""
+    import jax
+    import jax.numpy as jnp
+
+    rpa = importlib.import_module(
+        "paddle_tpu.kernels.ragged_paged_attention")
+    B, C, H, D, P, ps = 3, 16, 4, 128, 160, 16
+
+    def grid(maxp):
+        args = (jnp.zeros((B, C, H, D)), jnp.zeros((H, P, ps, D)),
+                jnp.zeros((H, P, ps, D)), jnp.full((B,), 40, jnp.int32),
+                jnp.ones((B,), jnp.int32), jnp.zeros((B, maxp), jnp.int32))
+        jaxpr = jax.make_jaxpr(
+            lambda *a: rpa._ragged_pallas(*a, 0.1, None, None, False))(*args)
+        (inner,) = jaxpr.jaxpr.eqns          # _ragged_pallas is jitted
+        (call,) = [e for e in inner.params["jaxpr"].jaxpr.eqns
+                   if e.primitive.name == "pallas_call"]
+        return tuple(call.params["grid_mapping"].grid)
+
+    assert grid(8) == grid(128)
+    assert 8 not in grid(8) and 128 not in grid(128)
+
+
 # -- proglint + registry -----------------------------------------------------
 
 
@@ -327,6 +469,21 @@ def test_one_bound_step_per_step(predictor):
     assert new[0] is eng._ragged_bound
     assert st["ragged_steps_total"] == st["decode_steps_total"]
     assert not eng._prefill_progs and eng._decode_bound is None
+
+
+def test_attention_page_counters_match_a_hand_count(predictor):
+    """attn_live_pages_total counts the pages the step's lanes hold
+    (what the kernel walks), attn_table_pages_total the block tables'
+    width: one 9-token prompt, 3 new tokens, pages of 4, chunks of 6 on
+    4 lanes is four steps over 6, 9, 10 and 11 keys."""
+    with _engine(predictor) as eng:
+        eng.generate(_prompts(1, lo=9, hi=10, seed=5)[0], max_new_tokens=3,
+                     timeout=600)
+        st = eng.stats()
+        maxp = eng.geom.max_pages_per_seq
+    assert st["ragged_steps_total"] == 4
+    assert st["attn_live_pages_total"] == 2 + 3 + 3 + 3
+    assert st["attn_table_pages_total"] == 4 * 4 * maxp
 
 
 # -- speculative decoding ----------------------------------------------------
