@@ -42,7 +42,8 @@ reference on CPU CI).
 from .draft import DraftModel, HostDraft
 from .engine import GenerationEngine, GenerationMetrics, GenerationStream
 from .kvcache import PagedKVCache, PagePoolExhausted
-from .model import (CacheGeometry, GPTConfig, build_decode_program,
+from .model import (CacheGeometry, GPTConfig, HybridConfig,
+                    build_decode_program, build_hybrid_step_program,
                     build_lm_program, build_prefill_program,
                     build_ragged_step_program)
 
@@ -60,4 +61,6 @@ __all__ = [
     "build_prefill_program",
     "build_decode_program",
     "build_ragged_step_program",
+    "HybridConfig",
+    "build_hybrid_step_program",
 ]

@@ -75,6 +75,22 @@ does what modern LLM serving does instead:
   re-prefill of prompt+generated — greedy decode makes the resumed
   continuation identical), so the oldest work always completes.
 
+* **Recurrent layers beside the pages** (a ``models.hybrid.
+  HybridConfig``: Mamba-2 and attention layers mixed, dropless top-k
+  experts). The page pools cover the attention layers only; what the
+  recurrent layers carry is a per-lane state ``[lanes, ...]`` that the
+  cache manager owns beside the pools and that rides the step as they
+  do, rewritten whole. A lane that takes a new sequence is zeroed
+  inside the step (its first row is at position 0), so neither
+  admission nor release touches it, and pool-dry eviction keeps its
+  rule: the re-prefill from prompt + generated restarts the state from
+  zero. What such a sequence cannot have yet is refused at
+  construction, by what is missing: a prefix cache (state at the fork
+  point), speculative rows (state rollback), a page store (state on
+  the wire), the two_lane programs. The builder and the extra arrays
+  are chosen once, in ``__init__``; the dense decoder's step is what
+  it was.
+
 The engine runs *over a cloned Predictor*: the clone shares the loaded
 weights (scope) and executor, so generation and plain ``/v1/predict``
 serving coexist on one model instance, and the caller's predictor
@@ -96,8 +112,9 @@ from ..serving.engine import (DeadlineExceeded, EngineClosed, Overloaded,
                               RequestCancelled, ServingError)
 from ..serving.metrics import StreamingHistogram
 from .kvcache import PagedKVCache, PagePoolExhausted
-from .model import (CacheGeometry, build_decode_program,
-                    build_prefill_program, build_ragged_step_program)
+from .model import (CacheGeometry, HybridConfig, build_decode_program,
+                    build_hybrid_step_program, build_prefill_program,
+                    build_ragged_step_program)
 
 __all__ = ["GenerationEngine", "GenerationStream", "GenerationMetrics"]
 
@@ -303,7 +320,14 @@ class GenerationMetrics:
                  # ragged mode: pages the step's lanes hold (what the
                  # attention kernel walks) against the width of their
                  # block tables (lanes x max_pages_per_seq)
-                 "attn_live_pages_total", "attn_table_pages_total"
+                 "attn_live_pages_total", "attn_table_pages_total",
+                 # recurrent layers and experts in the step (a hybrid
+                 # config): valid tokens x expert layers, and lanes that
+                 # took a new sequence (its state restarts from zero in
+                 # the graph). The pairs that landed on held experts are
+                 # counted on the device (stats(): moe_held_assignments_
+                 # total, moe_expert_load_max / _mean)
+                 "moe_tokens_routed_total", "state_lane_resets_total"
                  # ragged mode: wall microseconds of the loop thread by
                  # phase, counted where the generation/<phase> span
                  # closes (GenerationEngine._phase)
@@ -502,12 +526,27 @@ class GenerationEngine:
         self.geom = CacheGeometry(num_pages=self.num_pages,
                                   page_size=self.page_size,
                                   max_pages_per_seq=maxp)
+        # what the model is, decided here once: a hybrid config (a list
+        # of attention and recurrent layers, models/hybrid.py) pages its
+        # attention layers only and carries a per-lane recurrent state
+        # beside the pools; the step loop only ever sees how many pooled
+        # layers there are and which extra arrays ride the step
+        hybrid = isinstance(config, HybridConfig)
+        self._kv_layers = (len(config.attention_layers) if hybrid
+                           else config.num_layers)
+        self._state_names: tuple = ()
+        state = None
+        if hybrid:
+            self._refuse_for_recurrent_state(page_store)
+            state = config.state_shapes(self.lanes)
+            self._state_names = tuple(state)
         self.cache = PagedKVCache(
-            config.num_layers, config.num_heads,
+            self._kv_layers,
+            config.num_kv_heads if hybrid else config.num_heads,
             config.hidden_size // config.num_heads,
             num_pages=self.num_pages, page_size=self.page_size,
             max_seqs=self.lanes, max_pages_per_seq=maxp,
-            dtype=self.kv_dtype,
+            dtype=self.kv_dtype, state=state,
             prefix_cache=self.prefix_cache,
             prefix_min_pages=int(flag("generation_prefix_min_pages")),
             trie_max_pages=int(flag("generation_trie_max_pages")),
@@ -540,9 +579,10 @@ class GenerationEngine:
         if self.mode == "ragged":
             # THE executable: one mixed prefill+decode program for the
             # engine's whole life, one BoundStep per step
-            self._ragged_prog, self._ragged_fetches = \
-                build_ragged_step_program(config, self.geom,
-                                          self.chunk_tokens, self.kv_dtype)
+            build = (build_hybrid_step_program if hybrid
+                     else build_ragged_step_program)
+            self._ragged_prog, self._ragged_fetches = build(
+                config, self.geom, self.chunk_tokens, self.kv_dtype)
         else:
             self._decode_prog, self._decode_fetches = build_decode_program(
                 config, self.geom)
@@ -640,6 +680,34 @@ class GenerationEngine:
             self._warmup()
         if start:
             self.start()
+
+    def _refuse_for_recurrent_state(self, page_store):
+        """A sequence with recurrent layers keeps part of its past in a
+        per-lane state that is in no page. What would have to exist
+        before each of these could serve it is named; until then
+        construction fails rather than serving wrong tokens."""
+        if self.mode != "ragged":
+            raise ValueError(
+                "a config with recurrent layers needs the ragged engine: "
+                "the two_lane prefill and decode programs carry no state")
+        if self.prefix_cache:
+            raise ValueError(
+                "prefix_cache with recurrent layers: a shared prefix's "
+                "pages hold its K/V but not the recurrent state at the "
+                "fork point; it needs state snapshots at page boundaries")
+        if self._draft is not None or self.spec_tokens > 0:
+            raise ValueError(
+                "speculative decoding with recurrent layers: a rejected "
+                "draft has already advanced the state; it needs a state "
+                "rollback to the last accepted token")
+        if page_store is not None:
+            raise ValueError(
+                "page_store with recurrent layers: exported pages carry "
+                "K/V only; it needs the recurrent state on the wire")
+        if self.quantize_weights != "off":
+            raise ValueError(
+                "quantize_weights with a hybrid config: the rewrite knows "
+                "mul ops only, not the stored-type products of its layers")
 
     # -- lifecycle -----------------------------------------------------------
     def start(self) -> "GenerationEngine":
@@ -811,6 +879,14 @@ class GenerationEngine:
         # flattened by the registry into paddle_generation_radix_*
         out["radix"] = self.cache.radix_stats()
         out["model_swaps"] = self.model_swaps
+        if self._state_names:
+            # the one read of the on-device load counts: never in a step
+            loads = np.asarray(self.cache.state["gen_state_moe_loads"],
+                               np.int64)
+            out["moe_held_assignments_total"] = int(loads.sum())
+            out["moe_expert_load_max"] = int(loads.max())
+            out["moe_expert_load_mean"] = float(loads.mean())
+            out["recurrent_state_bytes"] = self.cache.state_bytes()
         if self._page_store is not None:
             lk = self.store_lookups_total
             # flattened into paddle_generation_store_* — this WORKER's
@@ -1153,6 +1229,10 @@ class GenerationEngine:
             req.pending = None
             req.drafts = None
             self._by_slot[req.slot] = req
+            if self._state_names:
+                # the lane's first row is at position 0: the step zeroes
+                # its recurrent state in the graph
+                self.metrics.inc("state_lane_resets_total")
 
     # -- the page store seam (disagg) ----------------------------------------
     def _consult_store(self) -> None:
@@ -1289,7 +1369,7 @@ class GenerationEngine:
         whatever its sequence needs this step — a prefill chunk, a
         decode token, or a decode token plus speculative drafts — and
         the whole batch attends raggedly over the shared page pool."""
-        R, C, L = self.lanes, self.chunk_tokens, self.config.num_layers
+        R, C, L = self.lanes, self.chunk_tokens, self._kv_layers
         with self._phase("grow"):
             self._retire_dead_rows(time.monotonic())
             # page growth for decode rows (+ the speculative window);
@@ -1406,6 +1486,12 @@ class GenerationEngine:
                 for li in range(L):
                     feed[f"gen_k_scales_{li}"] = self.cache.k_scales[li]
                     feed[f"gen_v_scales_{li}"] = self.cache.v_scales[li]
+            if self._state_names:
+                # recurrent layers: the per-lane state rides the step as
+                # the pools do, rewritten whole
+                feed.update(self.cache.state)
+                self.metrics.inc("moe_tokens_routed_total",
+                                 int(num_valid.sum()) * self.config.num_layers)
         with self._phase("bind"):
             bound = self._bind_ragged(feed)
             active = list(self._by_slot.items())
@@ -1436,7 +1522,9 @@ class GenerationEngine:
                     list(outs[1 + 3 * L:]))
             else:
                 self.cache.set_buffers(list(outs[1:1 + L]),
-                                       list(outs[1 + L:]))
+                                       list(outs[1 + L:1 + 2 * L]))
+            if self._state_names:
+                self.cache.set_state(outs[-len(self._state_names):])
         with self._phase("emit"):
             now = time.monotonic()
             self.metrics.inc("ragged_steps_total")
@@ -1695,6 +1783,9 @@ class GenerationEngine:
             if self.prefix_cache:
                 # warmup's dummy [0, 0] prompt must not seed the trie
                 self.cache.drop_trie()
+            if self._state_names:
+                # nor count in the experts' loads
+                self.cache.reset_state()
             self.metrics.__init__()
             return
         for bucket in self._seq_buckets:

@@ -21,6 +21,17 @@ are called from the engine's single step loop; the lock protects the
 metric/probe reader paths (``stats()`` / ``match_len`` from scrape and
 traffic threads).
 
+Two kinds of state live here. Pages hold what attention layers keep of
+a sequence. What recurrent layers keep (a state-space layer's state, a
+conv's last inputs) is in no page: ``state={feed name: (shape, dtype)}``
+names a pool of per-lane arrays ``[max_seqs, ...]`` that is created
+once, rides every step beside the page pools (``state`` in,
+``set_state`` out) and is rewritten whole. ``num_layers`` is then the
+count of ATTENTION layers. A lane's state needs no release and no
+reset: the step zeroes it in the graph when the lane's row is at
+position 0. Prefix sharing, page export and ingest see pages only, so
+the engine refuses them for a model with such state.
+
 Page 0 is permanently reserved as the JUNK page: idle decode lanes and
 batch-padding rows point their tables at it, so their (discarded)
 writes can never corrupt a live sequence.
@@ -117,7 +128,8 @@ class PagedKVCache:
                  num_pages: int, page_size: int, max_seqs: int,
                  max_pages_per_seq: int, dtype: str = "float32",
                  prefix_cache: bool = False, prefix_min_pages: int = 1,
-                 trie_max_pages: int = 0, tenant_quota_pages: int = 0):
+                 trie_max_pages: int = 0, tenant_quota_pages: int = 0,
+                 state: Optional[Dict[str, tuple]] = None):
         if num_pages < 2:
             raise ValueError("num_pages must be >= 2 (page 0 is reserved)")
         if page_size < 1 or max_seqs < 1 or max_pages_per_seq < 1:
@@ -143,6 +155,14 @@ class PagedKVCache:
         self._v_pages: Optional[List[Any]] = None
         self._k_scales: Optional[List[Any]] = None
         self._v_scales: Optional[List[Any]] = None
+        # the second kind of state: what a sequence's recurrent layers
+        # carry is in no page. ``state`` names the arrays ({feed name:
+        # (shape, dtype)}, first axis the lane for per-lane ones); they
+        # ride the step as the pools do and are rewritten whole. A lane
+        # that takes a new sequence is zeroed inside the step (its first
+        # row is at position 0), so release() has nothing to do here.
+        self._state_spec = dict(state or {})
+        self._state: Optional[Dict[str, Any]] = None
         # host bookkeeping
         self.block_tables = np.zeros((max_seqs, max_pages_per_seq), np.int32)
         self.lengths = np.zeros(max_seqs, np.int32)
@@ -243,6 +263,32 @@ class PagedKVCache:
                 raise ValueError("set_buffers: int8 pool needs scale planes")
             self._k_scales = list(k_scales)
             self._v_scales = list(v_scales)
+
+    @property
+    def state(self) -> Dict[str, Any]:
+        """The recurrent-state arrays by feed name (lazy, zeros)."""
+        if self._state is None:
+            import jax.numpy as jnp
+
+            self._state = {name: jnp.zeros(shape, dtype)
+                           for name, (shape, dtype)
+                           in self._state_spec.items()}
+        return self._state
+
+    def set_state(self, arrays) -> None:
+        """Swap in the state a step fetched, in the spec's order."""
+        if len(arrays) != len(self._state_spec):
+            raise ValueError("set_state: wrong array count")
+        self._state = dict(zip(self._state_spec, arrays))
+
+    def reset_state(self) -> None:
+        self._state = None
+
+    def state_bytes(self) -> int:
+        import jax.numpy as jnp
+
+        return sum(int(np.prod(shape)) * jnp.dtype(dtype).itemsize
+                   for shape, dtype in self._state_spec.values())
 
     @staticmethod
     def page_bytes(num_kv_heads: int, head_dim: int, page_size: int,

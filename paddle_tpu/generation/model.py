@@ -58,10 +58,12 @@ import numpy as np
 from .. import layers, nets
 from ..core.framework import Program, program_guard, unique_name
 from ..models.gpt import GPTConfig, _attr
+from ..models.hybrid import HybridConfig, hybrid_decoder
 from ..param_attr import ParamAttr
 
 __all__ = ["CacheGeometry", "build_lm_program", "build_prefill_program",
-           "build_decode_program", "build_ragged_step_program", "GPTConfig"]
+           "build_decode_program", "build_ragged_step_program",
+           "build_hybrid_step_program", "GPTConfig", "HybridConfig"]
 
 
 @dataclasses.dataclass(frozen=True)
@@ -302,6 +304,80 @@ def build_ragged_step_program(cfg: GPTConfig, geom: CacheGeometry,
                + [p[1] for p in out_pages])
     if quantized:
         fetches += [p[2] for p in out_pages] + [p[3] for p in out_pages]
+    return main, fetches
+
+
+def build_hybrid_step_program(cfg: HybridConfig, geom: CacheGeometry,
+                              chunk: int, kv_dtype: str = "float32"):
+    """The ragged executable of a hybrid decoder (models/hybrid.py): the
+    same [lanes, chunk] window and feed contract as
+    ``build_ragged_step_program`` (``gen_pos_ids`` is fed and unused:
+    there is no position embedding), with
+
+    * page pools ``gen_k_pages_{j}`` / ``gen_v_pages_{j}`` for the j-th
+      ATTENTION layer only, ``[num_kv_heads, pages, page_size,
+      head_dim]``: grouped queries, the kernel's own 1/sqrt(head_dim)
+      with the attention multiplier folded into q;
+    * the per-lane recurrent state of ``cfg.state_shapes(lanes)`` fed as
+      ``gen_state_*`` and fetched back, rewritten whole: a row advances
+      its Mamba layers by its valid tokens, and a row at position 0
+      starts from zero state inside the graph;
+    * padding rows taking no expert, and the head on each row's last
+      valid position only (``next_tokens`` repeats that token over the
+      row's columns).
+
+    Returns (program, fetches) with fetch order
+    [next_tokens(R*C), k_pages.., v_pages.., state.. (feed order)].
+    """
+    if kv_dtype == "int8":
+        raise ValueError("a hybrid step keeps float pages: int8 scale "
+                         "planes are not wired through it")
+    main, startup = Program(), Program()
+    with program_guard(main, startup), unique_name.guard():
+        tokens = layers.data("gen_tokens", [chunk], dtype="int64")
+        layers.data("gen_pos_ids", [chunk], dtype="int64")
+        positions = layers.data("gen_positions", [], dtype="int64")
+        num_valid = layers.data("gen_num_valid", [], dtype="int32")
+        tables = layers.data("gen_block_tables", [geom.max_pages_per_seq],
+                             dtype="int32")
+        shape = [cfg.num_kv_heads, geom.num_pages, geom.page_size,
+                 cfg.head_dim]
+        pools = {i: (layers.data(f"gen_k_pages_{j}", shape, dtype=kv_dtype,
+                                 append_batch_size=False),
+                     layers.data(f"gen_v_pages_{j}", shape, dtype=kv_dtype,
+                                 append_batch_size=False))
+                 for j, i in enumerate(cfg.attention_layers)}
+        state = {name: layers.data(name, list(shp), dtype=dt,
+                                   append_batch_size=False)
+                 for name, (shp, dt) in cfg.state_shapes(-1).items()}
+        from ..kernels import (kv_cache_write_layer,
+                               ragged_paged_attention_layer)
+
+        out_pages = {}
+
+        def attention(i, q, k, v):
+            kp, vp = pools[i]
+            ko, vo = kv_cache_write_layer(kp, vp, k, v, tables, positions,
+                                          num_valid, cfg.num_kv_heads)
+            out_pages[i] = (ko, vo)
+            return ragged_paged_attention_layer(
+                q, ko, vo, tables, positions, num_valid, cfg.num_heads)
+
+        # no speculative rows here, so the engine reads one token a row,
+        # the one after its last valid position: the head runs on that
+        # position alone (a sixteenth of the window's rows) and its
+        # token fills the row. An idle row selects nothing and reads 0.
+        last = layers.slice(
+            layers.one_hot(layers.unsqueeze(num_valid, [1]), chunk + 1),
+            axes=[1], starts=[1], ends=[chunk + 1])     # hot at nv - 1
+        logits, state_out = hybrid_decoder(cfg, tokens, attention,
+                                           num_valid, positions, state,
+                                           head_at=last)
+        next_tok = layers.reshape(layers.expand(
+            layers.argmax(logits, axis=-1), [1, chunk]), [-1])      # [R*C]
+    fetches = ([next_tok] + [out_pages[i][0] for i in cfg.attention_layers]
+               + [out_pages[i][1] for i in cfg.attention_layers]
+               + [state_out[name] for name in state])
     return main, fetches
 
 

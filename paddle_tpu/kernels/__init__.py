@@ -30,6 +30,10 @@ attention/ffn/layer_norm/adam/softmax-ce):
     small-rank matmuls accumulated in VMEM, composing with the
     dense OR quantized base — the kernel layer under
     paddle_tpu.adapters' multi-adapter serving
+  * Mamba-2 state step — read a chunk out of every lane's carried
+    state [H, P, N] and advance it, one pass over the state
+    (kernels/mamba2_state.py): the kernel under ops/ssm.py's
+    ``mamba2_mixer`` in the hybrid serving step
   * fused optimizer — one-pass Adam/AdamW/Momentum over donated
     buffers (kernels/fused_optim.py): the whole m/v/param update is a
     single Pallas pass per parameter with the global-norm-clip scale
@@ -48,6 +52,7 @@ from .flash_attention import flash_attention, flash_attention_layer
 from .fused_optim import (fused_adam_update, fused_momentum_update,
                           optimizer_fuse_enabled)
 from .layer_norm import fused_layer_norm, layer_norm_pallas
+from .mamba2_state import state_step
 from .lora import (batched_lora_delta, batched_lora_matmul,
                    lora_pool_shapes, lora_rank_geometry_issue,
                    lora_slot_bytes)

@@ -22,6 +22,7 @@ from .detection import iou_similarity, box_coder, prior_box
 from .sequence import *  # noqa: F401,F403
 from .py_func_registry import py_func
 from .extras import *  # noqa: F401,F403
+from .decoder import *  # noqa: F401,F403
 
 # auto-generated wrappers fill remaining reference layer names; hand-
 # written layers above always win on name conflicts

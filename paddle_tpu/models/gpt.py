@@ -36,6 +36,10 @@ class GPTConfig:
     # MoE (beyond-reference, SURVEY §2f EP axis): every `moe_every`-th
     # decoder swaps its dense FFN for a switch-MoE layer (0 = dense).
     # Train with CompiledProgram.with_expert_parallel to shard experts.
+    # This is `switch_moe` (ops/moe.py): top-1, a capacity, dropped
+    # tokens, training only. The serving expert layer is `topk_moe` in
+    # the same file (dropless top-k, a chip's share of the experts),
+    # which models/hybrid.py builds on; the two share no code path.
     moe_every: int = 0
     moe_experts: int = 8
     moe_capacity: float = 1.25

@@ -27,3 +27,5 @@ from . import misc  # noqa: F401
 from . import detection2  # noqa: F401
 from . import persist  # noqa: F401
 from . import moe  # noqa: F401
+from . import decoder  # noqa: F401
+from . import ssm  # noqa: F401

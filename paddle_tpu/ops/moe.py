@@ -1,9 +1,20 @@
-"""Switch-style Mixture-of-Experts with expert parallelism.
+"""Mixture-of-Experts layers. Two, for two jobs:
 
-Beyond the reference (SURVEY §2f last row names EP as a north-star
-axis; the reference snapshot has no MoE). Design follows the Switch
-Transformer recipe: top-1 routing, capacity-bounded dispatch, and the
-load-balancing auxiliary loss aux = E * sum_e(frac_e * mean_prob_e).
+``switch_moe`` (training): Switch-Transformer routing, top-1 with a
+capacity and dropped tokens, the load-balancing auxiliary loss, expert
+parallelism over an ``ep`` mesh axis. The rest of this docstring and
+the first half of the file.
+
+``topk_moe`` (serving): top-k routing over ALL experts with no capacity
+and no dropped token, computing only the experts this chip holds
+(``first_expert .. first_expert + held``: a chip's share of an
+expert-parallel deployment) for the tokens routed to them, and taking no
+padding row of a ragged window. The last part of the file.
+
+switch_moe. Beyond the reference (SURVEY §2f last row names EP as a
+north-star axis; the reference snapshot has no MoE). Design follows the
+Switch Transformer recipe: top-1 routing, capacity-bounded dispatch, and
+the load-balancing auxiliary loss aux = E * sum_e(frac_e * mean_prob_e).
 
 Two lowerings behind ONE op type, selected by the compile mesh (the
 same routing contract as the fused attention op's `sp` axis):
@@ -21,6 +32,8 @@ Tokens over capacity C = ceil(T/E * capacity_factor) are dropped
 """
 
 from __future__ import annotations
+
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -207,3 +220,107 @@ def _switch_moe(ctx, op, ins):
         out_specs=(xspec, P()),
     )(x, wg, w1, b1, w2, b2)
     return {"Out": [out], "AuxLoss": [aux]}
+
+
+# -- topk_moe: dropless top-k over a chip's share of the experts ------------
+
+_HI = jax.lax.Precision.HIGHEST
+
+
+# jitted so that a program's expert layers share one trace and lowering
+@functools.partial(jax.jit, static_argnames=(
+    "top_k", "num_experts", "first_expert", "block_rows"))
+def topk_moe(x, valid, router_w, w_in, w_out, loads=None, *, top_k: int,
+             num_experts: int, first_expert: int, block_rows: int = 128):
+    """x [T, d]; valid [T] bool (padding rows of a ragged window take no
+    expert); router_w [d, num_experts]; w_in [held, d, 2f] and w_out
+    [held, f, d]: experts first_expert .. first_expert + held, each a
+    gated-SiLU feed-forward. Returns (out [T, d] float32: the held
+    experts' part of ``sum_e gate_e o_e``; loads [held] int32: the
+    assignments each held expert took, added to ``loads`` if given).
+
+    Every token routes over all ``num_experts`` (the router runs in
+    float32 at HIGHEST: a rounded logit would flip the tenth expert);
+    gates are the softmax over its top_k logits. The (token, expert)
+    pairs that landed on held experts are sorted by expert and go
+    through two grouped matrix products (``jax.lax.ragged_dot``: on a
+    TPU, XLA's own grouped-matmul kernel, which visits only tiles that
+    hold rows) in blocks of ``block_rows`` sorted rows, as many blocks
+    as there are live pairs: a decode step of 32 tokens costs 2 blocks,
+    not the 40 a full window of 512 would. Rows are picked and results
+    put back by one-hot products (exact: one term each), so the loop
+    holds no gather and no scatter.
+    """
+    T, d = x.shape
+    held = w_in.shape[0]
+    if router_w.shape[1] != num_experts or first_expert + held > num_experts:
+        raise ValueError(
+            f"topk_moe: router {router_w.shape}, experts {first_expert}.."
+            f"{first_expert + held} of {num_experts}")
+    logits = jnp.dot(x.astype(jnp.float32), router_w.astype(jnp.float32),
+                     precision=_HI)
+    vals, idx = jax.lax.top_k(logits, top_k)                # [T, k]
+    gates = jax.nn.softmax(vals, axis=-1)
+    local = idx.astype(jnp.int32) - first_expert
+    mine = valid[:, None] & (local >= 0) & (local < held)
+    key = jnp.where(mine, local, held).reshape(-1)          # dead pairs last
+    pairs = T * top_k
+    rows = -(-pairs // block_rows) * block_rows
+    order = jnp.argsort(key, stable=True)
+    pad = (0, rows - pairs)
+    e_sorted = jnp.pad(key[order], pad, constant_values=held)
+    tok_sorted = jnp.pad((order // top_k).astype(jnp.int32), pad)
+    gate_sorted = jnp.pad(jnp.where(mine, gates, 0.0).reshape(-1)[order], pad)
+    counts = jnp.sum(key[:, None] == jnp.arange(held, dtype=jnp.int32),
+                     axis=0, dtype=jnp.int32)               # [held]
+    live = jnp.sum(counts)
+    xs = x.astype(w_in.dtype)
+    tokens = jnp.arange(T, dtype=jnp.int32)
+    experts = jnp.arange(held, dtype=jnp.int32)
+
+    def block(i, out):
+        at = i * block_rows
+        e = jax.lax.dynamic_slice(e_sorted, (at,), (block_rows,))
+        tok = jax.lax.dynamic_slice(tok_sorted, (at,), (block_rows,))
+        g = jax.lax.dynamic_slice(gate_sorted, (at,), (block_rows,))
+        sizes = jnp.sum(e[:, None] == experts, axis=0, dtype=jnp.int32)
+        pick = tok[:, None] == tokens[None, :]              # [rows, T]
+        xb = jnp.dot(pick.astype(xs.dtype), xs,
+                     preferred_element_type=jnp.float32).astype(xs.dtype)
+        a = jax.lax.ragged_dot(xb, w_in, sizes,
+                               preferred_element_type=jnp.float32)
+        a1, a2 = jnp.split(a, 2, axis=-1)
+        h = (jax.nn.silu(a1) * a2).astype(w_out.dtype)
+        o = jax.lax.ragged_dot(h, w_out, sizes,
+                               preferred_element_type=jnp.float32)
+        # rows past the block's pairs belong to no group: ragged_dot
+        # leaves them undefined
+        o = jnp.where((e < held)[:, None], o, 0.0)
+        put = jnp.where(pick, g[:, None], 0.0)              # [rows, T]
+        return out + jnp.dot(put.T, o, precision=_HI)
+
+    out = jax.lax.fori_loop(0, -(-live // block_rows), block,
+                            jnp.zeros((T, d), jnp.float32))
+    return out, (counts if loads is None else loads + counts)
+
+
+@register_op("topk_moe",
+             inputs=("X", "NumValid", "RouterW", "ExpertWIn", "ExpertWOut",
+                     "Loads"),
+             outputs=("Out", "LoadsOut"),
+             no_grad=("NumValid", "Loads"), stop_gradient=True)
+def _topk_moe_op(ctx, op, ins):
+    x = ins["X"][0]                                         # [R, C, d]
+    R, C, d = x.shape
+    nv = ins.get("NumValid")
+    valid = (jnp.ones((R, C), bool) if not nv else
+             jnp.arange(C, dtype=jnp.int32)[None, :]
+             < nv[0].astype(jnp.int32)[:, None])
+    loads = ins.get("Loads")
+    out, loads = topk_moe(
+        x.reshape(R * C, d), valid.reshape(-1), ins["RouterW"][0],
+        ins["ExpertWIn"][0], ins["ExpertWOut"][0],
+        loads[0] if loads else None, top_k=int(op.attrs["top_k"]),
+        num_experts=int(op.attrs["num_experts"]),
+        first_expert=int(op.attrs["first_expert"]))
+    return {"Out": [out.reshape(R, C, d)], "LoadsOut": [loads]}

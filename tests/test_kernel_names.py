@@ -21,9 +21,11 @@ def _mod(name):
     return importlib.import_module("paddle_tpu.kernels." + name)
 
 
-fa, fused_optim, layer_norm, lora, quant_matmul, rpa, softmax_xent = map(
+(fa, fused_optim, layer_norm, lora, mamba2_state, quant_matmul, rpa,
+ softmax_xent) = map(
     _mod, ("flash_attention", "fused_optim", "layer_norm", "lora",
-           "quant_matmul", "ragged_paged_attention", "softmax_xent"))
+           "mamba2_state", "quant_matmul", "ragged_paged_attention",
+           "softmax_xent"))
 
 KERNELS = os.path.dirname(os.path.abspath(fa.__file__))
 F32 = jnp.float32
@@ -72,6 +74,12 @@ def _lora():
                                    jnp.ones(2), _z(16, dtype=jnp.int32), True)
 
 
+def _state_step():
+    return mamba2_state._state_step_pallas(
+        _z(2, 8, 16, 128), _z(2, 1, 8, 128), _z(2, 1, 8, 128),
+        _z(2, 8, 128), _z(2, 8), True)
+
+
 def _adam():
     p = _z(8, 128)
     return fused_optim.fused_adam_update(p, p, p, p, 1e-3, 0.9, 0.999)
@@ -117,6 +125,7 @@ SITES = [
     ("ragged_paged_attention.py", _ragged, ["ragged_paged_attention"]),
     ("quant_matmul.py", _quant, ["quant_matmul"]),
     ("lora.py", _lora, ["lora_delta"]),
+    ("mamba2_state.py", _state_step, ["mamba2_state_step"]),
 ]
 
 

@@ -532,6 +532,15 @@ NOOP_OPS = ["delete_var",  # scope-level free; nothing to lower (dist_compute.py
 # ops with dedicated tests elsewhere in the suite (regenerate with
 # paddle_tpu.core.registry.exercised_ops() after a full run)
 COVERED_ELSEWHERE = {
+    # PR-29 hybrid decoder ops (tests/test_hybrid.py, all at the logits
+    # against benchmark/models/granite_hybrid_reference.py: the Mamba-2
+    # mixer in ragged chunks through a carried state vs the token-by-
+    # token recurrence, kernel body interpreted; dropless top-k experts
+    # vs the dense-gate reference, padding rows, the two-shares test;
+    # rms_norm / gated_silu_ffn / linear_stored through the step
+    # program == one full forward and the engine == greedy reference)
+    'mamba2_mixer', 'topk_moe', 'rms_norm', 'gated_silu_ffn',
+    'linear_stored',
     # PR-6 generation ops (tests/test_generation.py: paged_attention
     # vs dense-softmax oracle incl. length masking + len-0 rows;
     # kv_cache_write scatter vs oracle + junk-page isolation; both

@@ -358,6 +358,78 @@ def _child():
     record("chip_smoke_ragged_step_program_gpt3xl_2layer",
            ragged_step_program, group="chip_smoke")
 
+    # -- the hybrid serving cell's step program at its own shapes
+    # (PT_AOT_ONLY=hybrid): granite4_h_small_serve as benchmark/ runs it:
+    # 10 layers, 36 of 72 experts, bfloat16 weights (zeros: only shapes
+    # and types reach the compiler), 32 lanes x 16 tokens, float32
+    # recurrent state, bfloat16 pages. A v5e compile failure or a step
+    # that does not fit 16 GB shows here, before a chip call.
+    def hybrid_step_program():
+        import importlib.util
+
+        import ml_dtypes
+
+        import paddle_tpu as fluid
+        from paddle_tpu.generation.model import (
+            CacheGeometry, build_hybrid_step_program)
+
+        bench = os.path.join(HERE, "benchmark")
+
+        def load(path):
+            spec = importlib.util.spec_from_file_location(
+                os.path.basename(path)[:-3], path)
+            mod = importlib.util.module_from_spec(spec)
+            spec.loader.exec_module(mod)
+            return mod
+
+        with open(os.path.join(bench, "configs",
+                               "granite4_h_small_serve.json")) as f:
+            cfg = json.load(f)
+        ref = load(os.path.join(bench, "models",
+                                "granite_hybrid_reference.py"))
+        hcfg = load(os.path.join(
+            bench, "models", "granite_hybrid_program.py")).hybrid_config(cfg)
+        eng = cfg["engine"]
+        lanes, chunk = eng["lanes"], eng["chunk_tokens"]
+        maxp = -(-eng["max_position"] // eng["page_size"])
+        geom = CacheGeometry(num_pages=eng["num_pages"],
+                             page_size=eng["page_size"],
+                             max_pages_per_seq=maxp)
+        prog, fetches = build_hybrid_step_program(hcfg, geom, chunk,
+                                                  eng["kv_dtype"])
+        feed = {"gen_tokens": np.zeros((lanes, chunk), np.int64),
+                "gen_pos_ids": np.zeros((lanes, chunk), np.int64),
+                "gen_positions": np.zeros(lanes, np.int64),
+                "gen_num_valid": np.zeros(lanes, np.int32),
+                "gen_block_tables": np.zeros((lanes, maxp), np.int32)}
+        for j in range(len(hcfg.attention_layers)):
+            for kv in "kv":
+                feed[f"gen_{kv}_pages_{j}"] = np.zeros(
+                    (hcfg.num_kv_heads, geom.num_pages, geom.page_size,
+                     hcfg.head_dim), ml_dtypes.bfloat16)
+        for name, (shape, dt) in hcfg.state_shapes(lanes).items():
+            feed[name] = np.zeros(shape, dt)
+        scope = fluid.Scope()
+        for name, shape, _init in ref.spec(cfg):
+            scope.set_var(name, np.zeros(shape, ml_dtypes.bfloat16))
+        exe = fluid.Executor(fluid.TPUPlace())
+        return exe.aot_compile(prog, feed, fetches, scope=scope,
+                               devices=[dev])
+
+    record("hybrid_step_program_granite4_h_small_10layer",
+           hybrid_step_program, group="hybrid")
+    from paddle_tpu.kernels.mamba2_state import state_step
+
+    f32 = jnp.float32
+    aot("hybrid_mamba2_state_step_32x128x64x128_t16", state_step,
+        (jax.ShapeDtypeStruct((32, 128, 64, 128), f32),
+         jax.ShapeDtypeStruct((32, 1, 16, 128), f32),
+         jax.ShapeDtypeStruct((32, 1, 16, 128), f32),
+         jax.ShapeDtypeStruct((32, 16, 8192), f32),
+         jax.ShapeDtypeStruct((32, 128), f32)),
+        group="hybrid", lanes=32, heads=128, head_dim=64, state=128,
+        chunk=16)
+
     # -- the bench stages: full train steps at their REAL shapes -------
     # the exact (kind, model, batch, seq) of bench.py's stage ladder,
     # params + adam state as abstract args, full fwd+bwd+update. This
