@@ -624,6 +624,20 @@ def leg_b(report, *, cfg, prompt_lens, new_tokens=32, export_seq=128,
                            if b.compiled.tag == "generation/ragged_step")
         out["mosaic_calls"] = _check_mosaic(report, "leg B ragged step",
                                             ragged_step.aot_compiled())
+        st = eng.stats()
+        donated, skipped = (st.get("step_donated_bytes"),
+                            st.get("donation_skip_reason"))
+        out["step_donated_bytes"] = donated
+        # (the executor donates nothing on a CPU, where the test of this
+        # leg runs it, and says so)
+        report.check("leg B: the ragged step is donated every page pool",
+                     donated == (0 if skipped else eng.cache.pool_bytes()),
+                     f"{donated} of {eng.cache.pool_bytes()} B ({skipped})")
+        aliases = ragged_step.donation_aliases()
+        report.check("leg B: each pool is aliased onto its own output",
+                     all(k == v for k, v in aliases.items())
+                     and bool(aliases or skipped),
+                     str({k: v for k, v in aliases.items() if k != v})[:300])
         eng.close(drain=True)
         in_use = eng.cache.stats()["pages_in_use"]
         report.check("leg B: zero pages in use after drain", in_use == 0,

@@ -46,19 +46,30 @@ does what modern LLM serving does instead:
   prefill-bucket ladder in two_lane); the loop holds its
   ``runtime.dispatch.BoundStep`` (``Executor.bind``) directly — the
   per-step hot path is a feed-dict assembly and one jitted call,
-  nothing else. Page pools ride feeds/fetches as jax arrays
-  (zero-copy through the dispatch normalizers).
+  nothing else. The page pools are neither fed nor fetched: the step
+  programs declare them as state they rewrite (generation/model.py),
+  the engine binds every step against a scope of its own (a child of
+  the predictor's: weights resolve through the parent, two engines
+  over one predictor keep separate pools) in which the
+  ``PagedKVCache`` keeps the arrays, and the executor donates them to
+  the step, which writes the new K/V rows into the same buffers. A
+  donated array is deleted at the dispatch, so the dispatch runs
+  under ``cache.pools_locked()`` (the wait for the tokens does not),
+  as every other reader or writer of the pools does: ``spill_run``
+  from another thread reads the arrays of before a step or of after
+  it. ``stats()["step_donated_bytes"]`` says that it engaged.
 * **Loop phases** (ragged mode). One iteration of the step loop is
   partitioned, with nothing left between them, into ``wait`` (starved:
   no queue, no live lane), ``admit`` (page-store consult, prefix
   lookup, lane + page reservation), ``grow`` (retire dead rows, page
   growth / eviction, the speculative budget, the adapter check),
   ``draft`` (only with speculative rows), ``assemble`` (the numpy
-  batch, block tables, the feed dict with the page pools), ``bind``,
-  ``step`` (the dispatch, the wait for the tokens —
-  ``generation/fetch`` — and the pools' hand-back) and ``emit``
-  (advance, stop conditions, the clients' ``on_token`` callbacks,
-  trie publish and, last, the release of the pools the step read).
+  batch, block tables, the feed dict: tokens, positions, tables, and
+  a hybrid model's recurrent state), ``bind``, ``step`` (the dispatch
+  with the pools donated, under the cache's pool lock, then the wait
+  for the tokens — ``generation/fetch``) and ``emit`` (advance, stop
+  conditions, the clients' ``on_token`` callbacks, trie publish and,
+  last, the release of what the step was fed).
   Each is the range ``generation/<phase>`` on the
   profiler's clock — always on, so any attached profiler session reads
   the device's idle gaps in these terms — and, at the same boundary,
@@ -79,8 +90,9 @@ does what modern LLM serving does instead:
   HybridConfig``: Mamba-2 and attention layers mixed, dropless top-k
   experts). The page pools cover the attention layers only; what the
   recurrent layers carry is a per-lane state ``[lanes, ...]`` that the
-  cache manager owns beside the pools and that rides the step as they
-  do, rewritten whole. A lane that takes a new sequence is zeroed
+  cache manager owns beside the pools and that is fed to the step and
+  fetched back, rewritten whole (the pools are donated state; the
+  recurrent state is not yet: PERF.md, open questions). A lane that takes a new sequence is zeroed
   inside the step (its first row is at position 0), so neither
   admission nor release touches it, and pool-dry eviction keeps its
   rule: the re-prefill from prompt + generated restarts the state from
@@ -456,6 +468,9 @@ class GenerationEngine:
         self._pred = predictor.clone()
         self._exe = self._pred._exe
         self._scope = self._pred._scope
+        # what the steps are bound against: this engine's own state (the
+        # page pools, kept there by the cache) over the shared weights
+        self._step_scope = self._scope.new_scope()
         self.page_size = int(page_size or flag("generation_page_size"))
         self.num_pages = int(num_pages or flag("generation_num_pages"))
         self.lanes = int(max_decode_batch
@@ -546,11 +561,12 @@ class GenerationEngine:
             config.hidden_size // config.num_heads,
             num_pages=self.num_pages, page_size=self.page_size,
             max_seqs=self.lanes, max_pages_per_seq=maxp,
-            dtype=self.kv_dtype, state=state,
+            dtype=self.kv_dtype, state=state, scope=self._step_scope,
             prefix_cache=self.prefix_cache,
             prefix_min_pages=int(flag("generation_prefix_min_pages")),
             trie_max_pages=int(flag("generation_trie_max_pages")),
             tenant_quota_pages=int(flag("generation_trie_tenant_quota")))
+        self.cache.reset_buffers()      # the steps find their state
         # disagg seam: a page store (HostPageStore / PageStoreClient
         # duck) makes this engine a split-topology citizen — admission
         # consults it for queued prompts before cold prefill
@@ -879,6 +895,15 @@ class GenerationEngine:
         # flattened by the registry into paddle_generation_radix_*
         out["radix"] = self.cache.radix_stats()
         out["model_swaps"] = self.model_swaps
+        # the bytes the bound step is donated and rewrites in place:
+        # the page pools, or 0 beside the reason where the executor
+        # donates nothing (a CPU)
+        bound = self._ragged_bound or self._decode_bound
+        if bound is not None:
+            info = bound.audit_info()
+            out["step_donated_bytes"] = info["donated_bytes"]
+            if info["donation_skip_reason"]:
+                out["donation_skip_reason"] = info["donation_skip_reason"]
         if self._state_names:
             # the one read of the on-device load counts: never in a step
             loads = np.asarray(self.cache.state["gen_state_moe_loads"],
@@ -1157,7 +1182,6 @@ class GenerationEngine:
         # XLA compiles mid-traffic (the padding rows are junk-routed
         # and nearly free; the compile stall is not)
         B = self.lanes
-        L = self.config.num_layers
         tokens = np.zeros((B, bucket), np.int64)
         num_valid = np.zeros(B, np.int32)
         last_index = np.zeros(B, np.int64)
@@ -1175,9 +1199,6 @@ class GenerationEngine:
             "gen_last_index": last_index,
             "gen_block_tables": tables,
         }
-        for li in range(L):
-            feed[f"gen_k_pages_{li}"] = self.cache.k_pages[li]
-            feed[f"gen_v_pages_{li}"] = self.cache.v_pages[li]
 
         def span_args():
             flow = [r.ctx.span_id for r in reqs[1:] if r.ctx is not None]
@@ -1189,21 +1210,21 @@ class GenerationEngine:
         # every other subsystem (Executor.bind, one BoundStep per seq
         # bucket) — tagged for spans and the donation audit, with
         # rows_hint keeping examples/sec honest on the padded lanes
-        bound = self._exe.bind(prog, feed, fetches, scope=self._scope,
+        bound = self._exe.bind(prog, feed, fetches, scope=self._step_scope,
                                tag=f"generation/prefill[{bucket}]")
         bound.rows_hint = len(reqs)
         try:
             with tracing.span("generation/prefill", span_args,
                               parent=reqs[0].ctx):
-                outs = bound.run(feed, False)
+                outs = self._dispatch(bound, feed)
         except Exception as e:  # noqa: BLE001 — a bad prompt batch must not kill the loop
             for req in reqs:
                 self.cache.release(req.slot)
                 req.stream._finish("error", ServingError(
                     f"prefill execution failed: {e!r}"))
+            self._recover_pools()
             return
         next_tok = np.asarray(outs[0]).reshape(-1)
-        self.cache.set_buffers(list(outs[1:1 + L]), list(outs[1 + L:]))
         now = time.monotonic()
         self.metrics.inc("prefill_batches_total")
         self.metrics.inc("prefill_tokens_total", int(num_valid.sum()))
@@ -1239,10 +1260,10 @@ class GenerationEngine:
         """Before cold-prefilling queue-head prompts, ask the page
         store for their prefixes and splice any match into the local
         pool + trie — the decode-worker half of disaggregation and
-        the warm-restart path. Runs on the LOOP THREAD only (the
-        device writes in ``ingest_run`` race ``set_buffers``
-        otherwise); the TCP fetch happens outside ``self._cond`` so
-        submitters never block on the wire."""
+        the warm-restart path. Runs on the LOOP THREAD only (the page
+        bookkeeping of ``ingest_run`` is the loop's); the TCP fetch
+        happens outside ``self._cond`` so submitters never block on
+        the wire."""
         if self._page_store is None or not self.prefix_cache:
             return
         with self._cond:
@@ -1285,8 +1306,11 @@ class GenerationEngine:
     def spill_run(self, tokens) -> int:
         """Export ``tokens``' trie-resident pages to the page store
         (the prefill-worker publish path). Safe from any thread —
-        full trie pages are immutable and ``export_run`` snapshots
-        buffer refs under the cache lock. No-op without a store."""
+        full trie pages are immutable, and ``export_run`` dispatches
+        its reads under the cache's pool lock, which a step's dispatch
+        holds while the pools it was donated are deleted and replaced:
+        the reads see the arrays of before that step or of after it.
+        No-op without a store."""
         if self._page_store is None or not self.prefix_cache:
             return 0
         n, k_run, v_run, ksc, vsc = self.cache.export_run(tokens)
@@ -1322,8 +1346,30 @@ class GenerationEngine:
         if self._ragged_bound is None:
             self._ragged_bound = self._exe.bind(
                 self._ragged_prog, feed, self._ragged_fetches,
-                scope=self._scope, tag="generation/ragged_step")
+                scope=self._step_scope, tag="generation/ragged_step")
         return self._ragged_bound
+
+    def _dispatch(self, bound, feed):
+        """Dispatch one step that is donated the page pools: under the
+        cache's pool lock from the moment the old arrays are deleted
+        until the executor has stored the new ones (``BoundStep``
+        writes them back before it returns), and no longer: the caller
+        waits for the tokens outside it."""
+        with self.cache.pools_locked():
+            return bound.run(feed, False)
+
+    def _recover_pools(self):
+        """After a step that raised: one that failed on the device has
+        consumed the pools it was donated. Serve on from empty pools
+        (every sequence of the step has been failed already; the
+        trie's pages are gone with the arrays)."""
+        if self.cache.pools_alive():
+            return
+        for slot in list(self._by_slot):
+            self._retire(slot, "error", ServingError(
+                "the page pools were lost with a failed step"))
+        self.cache.drop_trie()
+        self.cache.reset_buffers()
 
     def _retire_dead_rows(self, now: float) -> None:
         """Retire cancelled/expired sequences before spending a step
@@ -1369,7 +1415,7 @@ class GenerationEngine:
         whatever its sequence needs this step — a prefill chunk, a
         decode token, or a decode token plus speculative drafts — and
         the whole batch attends raggedly over the shared page pool."""
-        R, C, L = self.lanes, self.chunk_tokens, self._kv_layers
+        R, C = self.lanes, self.chunk_tokens
         with self._phase("grow"):
             self._retire_dead_rows(time.monotonic())
             # page growth for decode rows (+ the speculative window);
@@ -1479,16 +1525,9 @@ class GenerationEngine:
                         aslots[slot] = self.adapter_store.slots_row(
                             req.adapter)
                 feed["gen_adapter_slots"] = aslots
-            for li in range(L):
-                feed[f"gen_k_pages_{li}"] = self.cache.k_pages[li]
-                feed[f"gen_v_pages_{li}"] = self.cache.v_pages[li]
-            if self.cache.quantized:
-                for li in range(L):
-                    feed[f"gen_k_scales_{li}"] = self.cache.k_scales[li]
-                    feed[f"gen_v_scales_{li}"] = self.cache.v_scales[li]
             if self._state_names:
-                # recurrent layers: the per-lane state rides the step as
-                # the pools do, rewritten whole
+                # recurrent layers: the per-lane state is fed and fetched,
+                # rewritten whole (the page pools are the step's state)
                 feed.update(self.cache.state)
                 self.metrics.inc("moe_tokens_routed_total",
                                  int(num_valid.sum()) * self.config.num_layers)
@@ -1506,25 +1545,18 @@ class GenerationEngine:
         with self._phase("step", step_args):
             t0 = time.monotonic()
             try:
-                outs = bound.run(feed, False)
+                outs = self._dispatch(bound, feed)
             except Exception as e:  # noqa: BLE001 — a bad batch must not kill the loop
                 for slot, req in active:
                     self._retire(slot, "error", ServingError(
                         f"ragged step execution failed: {e!r}"))
+                self._recover_pools()
                 return
             # where the loop waits for the device
             with tracing.annotation("generation/fetch"):
                 next_all = np.asarray(outs[0]).reshape(R, C)
-            if self.cache.quantized:
-                self.cache.set_buffers(
-                    list(outs[1:1 + L]), list(outs[1 + L:1 + 2 * L]),
-                    list(outs[1 + 2 * L:1 + 3 * L]),
-                    list(outs[1 + 3 * L:]))
-            else:
-                self.cache.set_buffers(list(outs[1:1 + L]),
-                                       list(outs[1 + L:1 + 2 * L]))
             if self._state_names:
-                self.cache.set_state(outs[-len(self._state_names):])
+                self.cache.set_state(outs[1:])
         with self._phase("emit"):
             now = time.monotonic()
             self.metrics.inc("ragged_steps_total")
@@ -1583,13 +1615,10 @@ class GenerationEngine:
             n_active = sum(1 for s, _ in active if num_valid[s] > 0)
             self.metrics.observe_decode_step(
                 (now - t0) * 1e3, n_active, R, tokens=emitted_total)
-            # the last references to the pools this step read go here,
-            # inside the phase and AFTER the clients' callbacks, where
-            # they went when the frame died (a quarter of a millisecond
-            # for the 48 arrays of GPT-3 XL): released before emit, a
-            # client that answers a finished request with its next
-            # submit reaches the queue after the next admit more often,
-            # and waits a whole step for its lane (PERF.md PR 26)
+            # the last references to what the step was fed (a hybrid
+            # model's recurrent state of before the step; the pools are
+            # not fed) go here, inside the phase and AFTER the clients'
+            # callbacks, where they went when the frame died
             del feed, outs
 
     # -- decode lane ---------------------------------------------------------
@@ -1597,7 +1626,7 @@ class GenerationEngine:
         if self._decode_bound is None:
             self._decode_bound = self._exe.bind(
                 self._decode_prog, feed, self._decode_fetches,
-                scope=self._scope, tag="generation/decode")
+                scope=self._step_scope, tag="generation/decode")
         return self._decode_bound
 
     def _make_room(self, slot: int) -> bool:
@@ -1640,7 +1669,7 @@ class GenerationEngine:
         return True
 
     def _decode_step(self):
-        Bd, L = self.lanes, self.config.num_layers
+        Bd = self.lanes
         now = time.monotonic()
         self._retire_dead_rows(now)
         if not self._by_slot:
@@ -1670,9 +1699,6 @@ class GenerationEngine:
             "gen_block_tables": np.ascontiguousarray(
                 self.cache.block_tables),
         }
-        for li in range(L):
-            feed[f"gen_k_pages_{li}"] = self.cache.k_pages[li]
-            feed[f"gen_v_pages_{li}"] = self.cache.v_pages[li]
         bound = self._bind_decode(feed)
         active = list(self._by_slot.items())
         bound.rows_hint = len(active)
@@ -1685,14 +1711,14 @@ class GenerationEngine:
         t0 = time.monotonic()
         try:
             with tracing.span("generation/decode_step", span_args):
-                outs = bound.run(feed, False)
+                outs = self._dispatch(bound, feed)
         except Exception as e:  # noqa: BLE001
             for slot, req in active:
                 self._retire(slot, "error", ServingError(
                     f"decode execution failed: {e!r}"))
+            self._recover_pools()
             return
         next_tok = np.asarray(outs[0]).reshape(-1)
-        self.cache.set_buffers(list(outs[1:1 + L]), list(outs[1 + L:]))
         now = time.monotonic()
         self.metrics.observe_decode_step((now - t0) * 1e3, len(active), Bd)
         for slot, req in active:

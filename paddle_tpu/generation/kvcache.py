@@ -13,19 +13,24 @@ surprise — exactly the property serving under heavy traffic needs.
 This class is the HOST-side manager: block tables, lengths, the free
 list, slot assignment, admission accounting. The device-side page
 buffers (jax arrays, [num_kv_heads, num_pages, page_size, head_dim]
-per layer) are held here too, but they are only ever *mutated* inside
-the compiled prefill/decode steps (kernels/paged_attention.py
-``kv_cache_write``) — the engine fetches the functionally-updated
-pools and swaps them back via ``set_buffers``. All bookkeeping methods
-are called from the engine's single step loop; the lock protects the
-metric/probe reader paths (``stats()`` / ``match_len`` from scrape and
-traffic threads).
+per layer) are owned here too. They live in ``scope`` under the names
+the step programs declare them by (``pool_names``): persistable state
+that a step's ``kv_cache_write`` rewrites under its own name, so the
+executor donates each pool to the step and stores the array the step
+returns, the same buffer written in place, back into the scope
+(``BoundStep._run_ordered``). Nothing is fed or fetched. A donated
+array is deleted at the dispatch, so whoever dispatches a read or a
+write of the pools does so under ``pools_locked()``: a reader enqueues
+before the step's write or sees the new arrays, never a deleted one.
+All bookkeeping methods are called from the engine's single step loop;
+``_lock`` protects the metric/probe reader paths (``stats()`` /
+``match_len`` from scrape and traffic threads).
 
 Two kinds of state live here. Pages hold what attention layers keep of
 a sequence. What recurrent layers keep (a state-space layer's state, a
 conv's last inputs) is in no page: ``state={feed name: (shape, dtype)}``
 names a pool of per-lane arrays ``[max_seqs, ...]`` that is created
-once, rides every step beside the page pools (``state`` in,
+once, is fed to every step and fetched back (``state`` in,
 ``set_state`` out) and is rewritten whole. ``num_layers`` is then the
 count of ATTENTION layers. A lane's state needs no release and no
 reset: the step zeroes it in the graph when the lane's row is at
@@ -83,6 +88,18 @@ class PagePoolExhausted(RuntimeError):
     (or eviction) must resolve it; never an allocation."""
 
 
+def pool_names(num_layers: int, quantized: bool = False):
+    """The scope names of the page pools, ``(k, v, k_scales, v_scales)``
+    as lists by layer (the scale lists empty for float pools): what the
+    step programs declare (generation/model.py) and what a cache keeps
+    in its scope."""
+    kinds = ("k_pages", "v_pages") + (("k_scales", "v_scales")
+                                      if quantized else ())
+    names = [[f"gen_{kind}_{i}" for i in range(num_layers)]
+             for kind in kinds]
+    return tuple(names) + ([],) * (4 - len(names))
+
+
 _SCATTER_JIT = []
 
 
@@ -91,7 +108,9 @@ def _scatter_pages(bufs, sel, blks):
     ingest path (disagg page splice) touches 2-4 buffers per layer,
     and un-jitted per-buffer ``at[].set`` dispatch costs multiples of
     a decode step. jax.jit caches per pytree shape, so the
-    power-of-two padding upstream bounds the executable set."""
+    power-of-two padding upstream bounds the executable set. The
+    buffers are donated (outputs pair with them in order, one shape a
+    kind), so a splice holds no second copy of the pools."""
     if not _SCATTER_JIT:
         import jax
 
@@ -99,7 +118,7 @@ def _scatter_pages(bufs, sel, blks):
             return [b.at[:, sel].set(x.astype(b.dtype))
                     for b, x in zip(bufs, blks)]
 
-        _SCATTER_JIT.append(jax.jit(_run))
+        _SCATTER_JIT.append(jax.jit(_run, donate_argnums=0))
     return _SCATTER_JIT[0](bufs, sel, blks)
 
 
@@ -129,7 +148,7 @@ class PagedKVCache:
                  max_pages_per_seq: int, dtype: str = "float32",
                  prefix_cache: bool = False, prefix_min_pages: int = 1,
                  trie_max_pages: int = 0, tenant_quota_pages: int = 0,
-                 state: Optional[Dict[str, tuple]] = None):
+                 state: Optional[Dict[str, tuple]] = None, scope=None):
         if num_pages < 2:
             raise ValueError("num_pages must be >= 2 (page 0 is reserved)")
         if page_size < 1 or max_seqs < 1 or max_pages_per_seq < 1:
@@ -150,15 +169,21 @@ class PagedKVCache:
         self._lock = threading.Lock()
         # device pools, one K + one V per layer (lazy: first access
         # allocates, so constructing a cache in a test costs nothing);
-        # int8 pools carry fp32 scale planes [KVH, P, ps] alongside
-        self._k_pages: Optional[List[Any]] = None
-        self._v_pages: Optional[List[Any]] = None
-        self._k_scales: Optional[List[Any]] = None
-        self._v_scales: Optional[List[Any]] = None
+        # int8 pools carry fp32 scale planes [KVH, P, ps] alongside.
+        # They are variables of ``scope``: the engine hands in a child
+        # of the predictor's scope, so two engines over one predictor
+        # keep separate pools while weights resolve through the parent
+        if scope is None:
+            from ..core.executor import Scope
+
+            scope = Scope()
+        self.scope = scope
+        self._pool_names = pool_names(self.num_layers, self.quantized)
+        self._pool_lock = threading.Lock()
         # the second kind of state: what a sequence's recurrent layers
         # carry is in no page. ``state`` names the arrays ({feed name:
         # (shape, dtype)}, first axis the lane for per-lane ones); they
-        # ride the step as the pools do and are rewritten whole. A lane
+        # are fed to the step and fetched back, rewritten whole. A lane
         # that takes a new sequence is zeroed inside the step (its first
         # row is at position 0), so release() has nothing to do here.
         self._state_spec = dict(state or {})
@@ -209,60 +234,83 @@ class PagedKVCache:
         self.tenant_quota_rejections_total = 0
 
     # -- device buffers ------------------------------------------------------
-    def _ensure_buffers(self):
-        if self._k_pages is None:
-            import jax.numpy as jnp
+    def _allocated(self) -> bool:
+        return self._pool_names[0][0] in self.scope.vars
 
-            shape = (self.num_kv_heads, self.num_pages, self.page_size,
-                     self.head_dim)
-            self._k_pages = [jnp.zeros(shape, self.dtype)
-                             for _ in range(self.num_layers)]
-            self._v_pages = [jnp.zeros(shape, self.dtype)
-                             for _ in range(self.num_layers)]
-            if self.quantized:
-                # scale 1.0 everywhere: a junk/unwritten slot
-                # dequantizes to 0.0, never to NaN/garbage
-                sshape = shape[:3]
-                self._k_scales = [jnp.ones(sshape, "float32")
-                                  for _ in range(self.num_layers)]
-                self._v_scales = [jnp.ones(sshape, "float32")
-                                  for _ in range(self.num_layers)]
+    def _ensure_buffers(self):
+        if not self._allocated():
+            self.reset_buffers()
+
+    def reset_buffers(self) -> None:
+        """Fresh zero pools: the first allocation, and what is left to
+        do after a step that failed on the device took the pools it was
+        donated with it (the trie's pages are gone with them)."""
+        import jax.numpy as jnp
+
+        shape = (self.num_kv_heads, self.num_pages, self.page_size,
+                 self.head_dim)
+        n = self.num_layers
+        # scale 1.0 everywhere: a junk/unwritten slot dequantizes to
+        # 0.0, never to NaN/garbage
+        scales = ([[jnp.ones(shape[:3], "float32") for _ in range(n)]
+                   for _ in "kv"] if self.quantized else [])
+        self.set_buffers(*([jnp.zeros(shape, self.dtype) for _ in range(n)]
+                           for _ in "kv"), *scales)
+
+    def pools_locked(self):
+        """``with cache.pools_locked():`` around the DISPATCH of whatever
+        reads the pool arrays or donates them (a step, an ingest's
+        scatter, an export's gathers), the hand-back of the new arrays
+        included, never around a wait for the device: the arrays a
+        reader finds in the scope are then alive when it enqueues its
+        read, and the device runs what was enqueued in order."""
+        return self._pool_lock
+
+    def pools_alive(self) -> bool:
+        """False once a failed step has consumed the arrays it was
+        donated and handed nothing back."""
+        return not any(a.is_deleted() for a in self.k_pages + self.v_pages)
+
+    def _pools(self, kind: int) -> List[Any]:
+        self._ensure_buffers()
+        sv = self.scope.vars
+        return [sv[n] for n in self._pool_names[kind]]
 
     @property
     def k_pages(self) -> List[Any]:
-        self._ensure_buffers()
-        return self._k_pages
+        """The live K pools by layer, as the next step will read them."""
+        return self._pools(0)
 
     @property
     def v_pages(self) -> List[Any]:
-        self._ensure_buffers()
-        return self._v_pages
+        return self._pools(1)
 
     @property
-    def k_scales(self) -> List[Any]:
-        self._ensure_buffers()
-        return self._k_scales
+    def k_scales(self) -> Optional[List[Any]]:
+        return self._pools(2) if self.quantized else None
 
     @property
-    def v_scales(self) -> List[Any]:
-        self._ensure_buffers()
-        return self._v_scales
+    def v_scales(self) -> Optional[List[Any]]:
+        return self._pools(3) if self.quantized else None
 
     def set_buffers(self, k_pages: List[Any], v_pages: List[Any],
                     k_scales: Optional[List[Any]] = None,
                     v_scales: Optional[List[Any]] = None) -> None:
-        """Swap in the functionally-updated pools fetched from a
-        prefill/decode/ragged step (scale planes too for the int8
-        pool)."""
+        """Put whole pools (scale planes too for the int8 pool) where
+        the next step reads them: into the scope, under the names the
+        step programs declare. The steps themselves never call this:
+        they rewrite the pools in place and the executor stores what
+        they return."""
         if len(k_pages) != self.num_layers or len(v_pages) != self.num_layers:
             raise ValueError("set_buffers: wrong layer count")
-        self._k_pages = list(k_pages)
-        self._v_pages = list(v_pages)
-        if self.quantized:
-            if k_scales is None or v_scales is None:
-                raise ValueError("set_buffers: int8 pool needs scale planes")
-            self._k_scales = list(k_scales)
-            self._v_scales = list(v_scales)
+        if self.quantized and (k_scales is None or v_scales is None):
+            raise ValueError("set_buffers: int8 pool needs scale planes")
+        arrays = (k_pages, v_pages) + ((k_scales, v_scales)
+                                       if self.quantized else ())
+        for names, arrs in zip(self._pool_names, arrays):
+            self.scope.vars.update(zip(names, arrs))
+        # one bump for the lot: bound steps re-resolve their state
+        self.scope._bump_generation()
 
     @property
     def state(self) -> Dict[str, Any]:
@@ -566,13 +614,15 @@ class PagedKVCache:
         pools). Safe against a concurrently running step: full
         trie-resident pages are immutable by construction (writes only
         ever target positions >= length; growth pops fresh pages), and
-        the buffer refs are snapshotted under the lock."""
+        the gathers are dispatched under ``pools_locked()``, which the
+        step's dispatch holds too: they read the arrays of before the
+        step or of after it, never one it has been donated."""
         empty = (0, None, None, None, None)
         if not self.prefix_cache:
             return empty
         tokens = np.asarray(tokens).reshape(-1)
         with self._lock:
-            if self._k_pages is None:
+            if not self._allocated():
                 return empty
             pids: List[int] = []
             node = self._root
@@ -585,24 +635,22 @@ class PagedKVCache:
                 node = child
                 if max_pages and len(pids) >= max_pages:
                     break
-            kbufs = list(self._k_pages)
-            vbufs = list(self._v_pages)
-            ksb = list(self._k_scales) if self.quantized else None
-            vsb = list(self._v_scales) if self.quantized else None
             self.exported_pages_total += len(pids)
         if not pids:
             return empty
         sel = np.asarray(pids, np.int32)
-        k_run = np.stack([np.asarray(b[:, sel]).transpose(1, 0, 2, 3)
-                          for b in kbufs], axis=1)
-        v_run = np.stack([np.asarray(b[:, sel]).transpose(1, 0, 2, 3)
-                          for b in vbufs], axis=1)
+        with self._pool_lock:
+            picked = [[b[:, sel] for b in self._pools(kind)]
+                      for kind in range(4 if self.quantized else 2)]
+        # the wait for the device, outside the lock
+        k_run, v_run = (np.stack([np.asarray(x).transpose(1, 0, 2, 3)
+                                  for x in xs], axis=1)
+                        for xs in picked[:2])
         k_sc = v_sc = None
-        if ksb is not None:
-            k_sc = np.stack([np.asarray(b[:, sel]).transpose(1, 0, 2)
-                             for b in ksb], axis=1)
-            v_sc = np.stack([np.asarray(b[:, sel]).transpose(1, 0, 2)
-                             for b in vsb], axis=1)
+        if self.quantized:
+            k_sc, v_sc = (np.stack([np.asarray(x).transpose(1, 0, 2)
+                                    for x in xs], axis=1)
+                          for xs in picked[2:])
         return len(pids), k_run, v_run, k_sc, v_sc
 
     def ingest_run(self, tokens, k_run, v_run, k_scales=None,
@@ -616,8 +664,9 @@ class PagedKVCache:
         write; caps (``trie_max_pages``, the per-tenant quota, pool
         pressure) truncate the run — a partial ingest just matches
         less, never wrong tokens. MUST be called from the engine's
-        step-loop thread: the device writes race ``set_buffers``
-        otherwise. Returns pages ingested."""
+        step-loop thread (the host bookkeeping is the loop's); the
+        scatter donates the pools and hands the new arrays back under
+        ``pools_locked()``, as a step does. Returns pages ingested."""
         if not self.prefix_cache:
             return 0
         tokens = np.asarray(tokens).reshape(-1)
@@ -681,27 +730,17 @@ class PagedKVCache:
         sel = np.zeros(width, np.int32)
         sel[:n] = [p for _, p in fresh]
         idx = [i for i, _ in fresh] + [fresh[0][0]] * (width - n)
-        bufs, blks = [], []
-        for li in range(self.num_layers):
-            bufs.append(self._k_pages[li])
-            blks.append(np.stack([k_run[i, li] for i in idx], axis=1))
-            bufs.append(self._v_pages[li])
-            blks.append(np.stack([v_run[i, li] for i in idx], axis=1))
-            if self.quantized:
-                bufs.append(self._k_scales[li])
-                blks.append(np.stack(
-                    [np.asarray(k_scales)[i, li] for i in idx], axis=1))
-                bufs.append(self._v_scales[li])
-                blks.append(np.stack(
-                    [np.asarray(v_scales)[i, li] for i in idx], axis=1))
-        out = _scatter_pages(bufs, sel, blks)
-        per = 4 if self.quantized else 2
-        for li in range(self.num_layers):
-            self._k_pages[li] = out[per * li]
-            self._v_pages[li] = out[per * li + 1]
-            if self.quantized:
-                self._k_scales[li] = out[per * li + 2]
-                self._v_scales[li] = out[per * li + 3]
+        runs = [k_run, v_run] + ([np.asarray(k_scales), np.asarray(v_scales)]
+                                 if self.quantized else [])
+        per = len(runs)
+        blks = [np.stack([run[i, li] for i in idx], axis=1)
+                for li in range(self.num_layers) for run in runs]
+        with self._pool_lock:
+            pools = [self._pools(kind) for kind in range(per)]
+            out = _scatter_pages(
+                [pools[kind][li] for li in range(self.num_layers)
+                 for kind in range(per)], sel, blks)
+            self.set_buffers(*(out[kind::per] for kind in range(per)))
         return len(fresh)
 
     def trie_leaf_runs(self) -> List[np.ndarray]:

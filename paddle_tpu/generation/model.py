@@ -29,6 +29,16 @@ idle lane — through ``kernels/ragged_paged_attention``. The engine's
 prefill/decode pair remains for mode="two_lane" (the identity
 oracle).
 
+The page pools are STATE, not feeds: every builder declares them as
+persistable variables (``_page_pools``) that the layer's cache-write op
+reads and rewrites under one name, the ``ParamOut = Param`` convention
+of the optimizer ops. ``analyze_block_state`` therefore lists them as
+rewritten state, the executor donates them to the step, XLA writes the
+new rows into the same buffers, and ``BoundStep`` stores the arrays the
+step returns back into the scope the engine bound it with (the
+``PagedKVCache``'s). A program that fed them would make XLA copy every
+pool every step: a fed array is never donated.
+
 Feed-name contract (the engine assembles these every step):
   gen_tokens       [B, S] / [B, 1] / [B, chunk] int64
   gen_pos_ids      [B, chunk] int64  ragged only: absolute position
@@ -44,6 +54,7 @@ Feed-name contract (the engine assembles these every step):
   gen_last_index   [B] int64   prefill only: index of the true last
                                prompt token (length - 1)
   gen_block_tables [B, max_pages_per_seq] int32
+State (scope variables, ``kvcache.pool_names``):
   gen_k_pages_{l} / gen_v_pages_{l}   the per-layer page pools
   gen_k_scales_{l} / gen_v_scales_{l} int8 pools only: fp32 scale
                                planes [kv_heads, pages, page_size]
@@ -60,6 +71,7 @@ from ..core.framework import Program, program_guard, unique_name
 from ..models.gpt import GPTConfig, _attr
 from ..models.hybrid import HybridConfig, hybrid_decoder
 from ..param_attr import ParamAttr
+from .kvcache import pool_names
 
 __all__ = ["CacheGeometry", "build_lm_program", "build_prefill_program",
            "build_decode_program", "build_ragged_step_program",
@@ -78,27 +90,22 @@ class CacheGeometry:
         return self.max_pages_per_seq * self.page_size
 
 
-def _page_feeds(cfg: GPTConfig, geom: CacheGeometry, dtype: str = "float32"):
-    kvh = cfg.num_heads
-    d = cfg.hidden_size // cfg.num_heads
-    shape = [kvh, geom.num_pages, geom.page_size, d]
-    kps = [layers.data(f"gen_k_pages_{i}", shape, append_batch_size=False,
-                       dtype=dtype)
-           for i in range(cfg.num_layers)]
-    vps = [layers.data(f"gen_v_pages_{i}", shape, append_batch_size=False,
-                       dtype=dtype)
-           for i in range(cfg.num_layers)]
-    return kps, vps
-
-
-def _scale_feeds(cfg: GPTConfig, geom: CacheGeometry):
-    kvh = cfg.num_heads
-    shape = [kvh, geom.num_pages, geom.page_size]
-    kss = [layers.data(f"gen_k_scales_{i}", shape, append_batch_size=False)
-           for i in range(cfg.num_layers)]
-    vss = [layers.data(f"gen_v_scales_{i}", shape, append_batch_size=False)
-           for i in range(cfg.num_layers)]
-    return kss, vss
+def _page_pools(main: Program, num_layers: int, num_kv_heads: int,
+                head_dim: int, geom: CacheGeometry, dtype: str = "float32"):
+    """Declare the page pools of ``num_layers`` attention layers as
+    persistable variables of ``main``; returns ``(k, v, k_scales,
+    v_scales)`` lists by layer, the scale lists ``[None] * num_layers``
+    unless ``dtype`` is int8. No startup op: the ``PagedKVCache`` makes
+    the arrays."""
+    block = main.global_block()
+    shape = [num_kv_heads, geom.num_pages, geom.page_size, head_dim]
+    names = pool_names(num_layers, dtype == "int8")
+    pools = [[block.create_var(name=n, shape=shp, dtype=dt, persistable=True,
+                               stop_gradient=True) for n in kind]
+             for kind, shp, dt in zip(
+                 names, (shape, shape, shape[:3], shape[:3]),
+                 (dtype, dtype, "float32", "float32"))]
+    return tuple(p or [None] * num_layers for p in pools)
 
 
 def _ln(x, name):
@@ -189,8 +196,7 @@ def build_lm_program(cfg: GPTConfig, seq_len: int):
 def build_prefill_program(cfg: GPTConfig, seq_len: int, geom: CacheGeometry):
     """Prefill lane: forward the prompt window, write its K/V into the
     page pool, emit the first greedy token per row — all one
-    executable. Returns (program, fetch_vars) where fetch order is
-    [next_token, k_pages_0.., v_pages_0..]."""
+    executable. Returns (program, [next_token])."""
     main, startup = Program(), Program()
     with program_guard(main, startup), unique_name.guard():
         tokens = layers.data("gen_tokens", [seq_len], dtype="int64")
@@ -199,22 +205,22 @@ def build_prefill_program(cfg: GPTConfig, seq_len: int, geom: CacheGeometry):
         last_index = layers.data("gen_last_index", [], dtype="int64")
         tables = layers.data("gen_block_tables", [geom.max_pages_per_seq],
                              dtype="int32")
-        kps, vps = _page_feeds(cfg, geom)
+        kps, vps, _, _ = _page_pools(
+            main, cfg.num_layers, cfg.num_heads,
+            cfg.hidden_size // cfg.num_heads, geom)
         from ..kernels import kv_cache_write_layer
 
         x = layers.elementwise_add(
             _embed(tokens, cfg),
             _pos_embed(layers.assign(
                 np.arange(seq_len, dtype="int64")[None, :]), cfg))
-        out_pages = []
         for i in range(cfg.num_layers):
             pre = f"dec{i}"
             ln1 = _ln(x, f"{pre}_ln1")
             q, k, v = _qkv_split(ln1, cfg, pre)
-            ko, vo = kv_cache_write_layer(
+            kv_cache_write_layer(
                 kps[i], vps[i], k, v, tables, positions, num_valid,
                 cfg.num_heads)
-            out_pages.append((ko, vo))
             if cfg.use_flash_attention:
                 from ..kernels import flash_attention_layer
 
@@ -232,9 +238,7 @@ def build_prefill_program(cfg: GPTConfig, seq_len: int, geom: CacheGeometry):
             layers.elementwise_mul(logits, layers.unsqueeze(sel, [2])),
             dim=[1])                                # [B, V]
         next_tok = layers.argmax(last_logits, axis=-1)   # [B]
-    fetches = [next_tok] + [p[0] for p in out_pages] + \
-        [p[1] for p in out_pages]
-    return main, fetches
+    return main, [next_tok]
 
 
 def build_ragged_step_program(cfg: GPTConfig, geom: CacheGeometry,
@@ -255,8 +259,7 @@ def build_ragged_step_program(cfg: GPTConfig, geom: CacheGeometry,
     column for speculative verification (greedy target tokens at each
     draft offset).
 
-    Returns (program, fetches) with fetch order
-    [next_tokens(R*C), k_pages.., v_pages.. (, k_scales.., v_scales..)].
+    Returns (program, [next_tokens(R*C)]).
     """
     quantized = kv_dtype == "int8"
     main, startup = Program(), Program()
@@ -267,44 +270,36 @@ def build_ragged_step_program(cfg: GPTConfig, geom: CacheGeometry,
         num_valid = layers.data("gen_num_valid", [], dtype="int32")
         tables = layers.data("gen_block_tables", [geom.max_pages_per_seq],
                              dtype="int32")
-        kps, vps = _page_feeds(cfg, geom,
-                               "int8" if quantized else "float32")
-        kss = vss = [None] * cfg.num_layers
-        if quantized:
-            kss, vss = _scale_feeds(cfg, geom)
+        kps, vps, kss, vss = _page_pools(
+            main, cfg.num_layers, cfg.num_heads,
+            cfg.hidden_size // cfg.num_heads, geom,
+            "int8" if quantized else "float32")
         from ..kernels import (kv_cache_write_layer,
                                quantized_kv_cache_write_layer,
                                ragged_paged_attention_layer)
 
         x = layers.elementwise_add(_embed(tokens, cfg),
                                    _pos_embed(pos_ids, cfg))   # [R, C, H]
-        out_pages = []
         for i in range(cfg.num_layers):
             pre = f"dec{i}"
             ln1 = _ln(x, f"{pre}_ln1")
             q, k, v = _qkv_split(ln1, cfg, pre)
             if quantized:
-                ko, vo, kso, vso = quantized_kv_cache_write_layer(
+                quantized_kv_cache_write_layer(
                     kps[i], vps[i], kss[i], vss[i], k, v, tables,
                     positions, num_valid, cfg.num_heads)
             else:
-                ko, vo = kv_cache_write_layer(
+                kv_cache_write_layer(
                     kps[i], vps[i], k, v, tables, positions, num_valid,
                     cfg.num_heads)
-                kso = vso = None
-            out_pages.append((ko, vo, kso, vso))
             ctx = ragged_paged_attention_layer(
-                q, ko, vo, tables, positions, num_valid, cfg.num_heads,
-                k_scales_var=kso, v_scales_var=vso)
+                q, kps[i], vps[i], tables, positions, num_valid,
+                cfg.num_heads, k_scales_var=kss[i], v_scales_var=vss[i])
             x = _proj_ffn(x, ctx, cfg, pre)
         logits = _head(x, cfg)                      # [R, C, V]
         next_tok = layers.argmax(
             layers.reshape(logits, [-1, cfg.vocab_size]), axis=-1)  # [R*C]
-    fetches = ([next_tok] + [p[0] for p in out_pages]
-               + [p[1] for p in out_pages])
-    if quantized:
-        fetches += [p[2] for p in out_pages] + [p[3] for p in out_pages]
-    return main, fetches
+    return main, [next_tok]
 
 
 def build_hybrid_step_program(cfg: HybridConfig, geom: CacheGeometry,
@@ -316,8 +311,9 @@ def build_hybrid_step_program(cfg: HybridConfig, geom: CacheGeometry,
 
     * page pools ``gen_k_pages_{j}`` / ``gen_v_pages_{j}`` for the j-th
       ATTENTION layer only, ``[num_kv_heads, pages, page_size,
-      head_dim]``: grouped queries, the kernel's own 1/sqrt(head_dim)
-      with the attention multiplier folded into q;
+      head_dim]``, rewritten in place as state like the dense step's:
+      grouped queries, the kernel's own 1/sqrt(head_dim) with the
+      attention multiplier folded into q;
     * the per-lane recurrent state of ``cfg.state_shapes(lanes)`` fed as
       ``gen_state_*`` and fetched back, rewritten whole: a row advances
       its Mamba layers by its valid tokens, and a row at position 0
@@ -327,7 +323,7 @@ def build_hybrid_step_program(cfg: HybridConfig, geom: CacheGeometry,
       row's columns).
 
     Returns (program, fetches) with fetch order
-    [next_tokens(R*C), k_pages.., v_pages.., state.. (feed order)].
+    [next_tokens(R*C), state.. (feed order)].
     """
     if kv_dtype == "int8":
         raise ValueError("a hybrid step keeps float pages: int8 scale "
@@ -340,28 +336,22 @@ def build_hybrid_step_program(cfg: HybridConfig, geom: CacheGeometry,
         num_valid = layers.data("gen_num_valid", [], dtype="int32")
         tables = layers.data("gen_block_tables", [geom.max_pages_per_seq],
                              dtype="int32")
-        shape = [cfg.num_kv_heads, geom.num_pages, geom.page_size,
-                 cfg.head_dim]
-        pools = {i: (layers.data(f"gen_k_pages_{j}", shape, dtype=kv_dtype,
-                                 append_batch_size=False),
-                     layers.data(f"gen_v_pages_{j}", shape, dtype=kv_dtype,
-                                 append_batch_size=False))
-                 for j, i in enumerate(cfg.attention_layers)}
+        kps, vps, _, _ = _page_pools(
+            main, len(cfg.attention_layers), cfg.num_kv_heads, cfg.head_dim,
+            geom, kv_dtype)
+        pools = dict(zip(cfg.attention_layers, zip(kps, vps)))
         state = {name: layers.data(name, list(shp), dtype=dt,
                                    append_batch_size=False)
                  for name, (shp, dt) in cfg.state_shapes(-1).items()}
         from ..kernels import (kv_cache_write_layer,
                                ragged_paged_attention_layer)
 
-        out_pages = {}
-
         def attention(i, q, k, v):
             kp, vp = pools[i]
-            ko, vo = kv_cache_write_layer(kp, vp, k, v, tables, positions,
-                                          num_valid, cfg.num_kv_heads)
-            out_pages[i] = (ko, vo)
+            kv_cache_write_layer(kp, vp, k, v, tables, positions,
+                                 num_valid, cfg.num_kv_heads)
             return ragged_paged_attention_layer(
-                q, ko, vo, tables, positions, num_valid, cfg.num_heads)
+                q, kp, vp, tables, positions, num_valid, cfg.num_heads)
 
         # no speculative rows here, so the engine reads one token a row,
         # the one after its last valid position: the head runs on that
@@ -375,16 +365,12 @@ def build_hybrid_step_program(cfg: HybridConfig, geom: CacheGeometry,
                                            head_at=last)
         next_tok = layers.reshape(layers.expand(
             layers.argmax(logits, axis=-1), [1, chunk]), [-1])      # [R*C]
-    fetches = ([next_tok] + [out_pages[i][0] for i in cfg.attention_layers]
-               + [out_pages[i][1] for i in cfg.attention_layers]
-               + [state_out[name] for name in state])
-    return main, fetches
+    return main, [next_tok] + [state_out[name] for name in state]
 
 
 def build_decode_program(cfg: GPTConfig, geom: CacheGeometry):
     """Decode lane: one new token per sequence through the paged
-    cache. Fetch order matches prefill: [next_token, k_pages..,
-    v_pages..]."""
+    cache. Returns (program, [next_token]) as prefill does."""
     main, startup = Program(), Program()
     with program_guard(main, startup), unique_name.guard():
         tokens = layers.data("gen_tokens", [1], dtype="int64")
@@ -393,27 +379,25 @@ def build_decode_program(cfg: GPTConfig, geom: CacheGeometry):
         attend = layers.data("gen_attend_lens", [], dtype="int32")
         tables = layers.data("gen_block_tables", [geom.max_pages_per_seq],
                              dtype="int32")
-        kps, vps = _page_feeds(cfg, geom)
+        kps, vps, _, _ = _page_pools(
+            main, cfg.num_layers, cfg.num_heads,
+            cfg.hidden_size // cfg.num_heads, geom)
         from ..kernels import kv_cache_write_layer, paged_attention_layer
 
         x = layers.elementwise_add(
             layers.unsqueeze(_embed(tokens, cfg), [1]),
             layers.unsqueeze(_pos_embed(positions, cfg), [1]))  # [B, 1, H]
-        out_pages = []
         for i in range(cfg.num_layers):
             pre = f"dec{i}"
             ln1 = _ln(x, f"{pre}_ln1")
             q, k, v = _qkv_split(ln1, cfg, pre)
-            ko, vo = kv_cache_write_layer(
+            kv_cache_write_layer(
                 kps[i], vps[i], k, v, tables, positions, num_valid,
                 cfg.num_heads)
-            out_pages.append((ko, vo))
-            ctx = paged_attention_layer(q, ko, vo, tables, attend,
+            ctx = paged_attention_layer(q, kps[i], vps[i], tables, attend,
                                         cfg.num_heads)
             x = _proj_ffn(x, ctx, cfg, pre)
         logits = _head(x, cfg)                      # [B, 1, V]
         next_tok = layers.argmax(
             layers.reshape(logits, [-1, cfg.vocab_size]), axis=-1)  # [B]
-    fetches = [next_tok] + [p[0] for p in out_pages] + \
-        [p[1] for p in out_pages]
-    return main, fetches
+    return main, [next_tok]

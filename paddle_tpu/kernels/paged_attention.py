@@ -20,13 +20,16 @@ them; PTL020-022 re-infer their shapes through the same lowerings):
                    PADDLE_TPU_KERNEL_INTERPRET CI mode — it runs the
                    pure-JAX reference below, which is also the
                    numerics oracle the tests diff against.
-  kv_cache_write   scatter new K/V rows into the page pool at
-                   positions derived from the block table. Covers both
+  kv_cache_write   write new K/V rows into the page pool at
+                   positions derived from the block table, page by
+                   page, in the pool's own layout (``write_page_rows``:
+                   in place when the pool is donated). Covers both
                    lanes: prefill writes a whole [B, S] prompt window,
                    decode writes the single new row per sequence.
-                   Rows flagged invalid are routed to the reserved
-                   junk page 0, so inactive decode lanes in the fixed
-                   batch cost a wasted write, never a corrupted page.
+                   A window page that takes no valid row is routed to
+                   the reserved junk page 0 and rewrites what is
+                   there, so inactive decode lanes in the fixed batch
+                   cost a wasted write, never a corrupted page.
 
 The page-pool layout matches the jax kernel exactly:
 k_pages/v_pages [num_kv_heads, total_pages, page_size, head_dim],
@@ -137,38 +140,92 @@ def paged_attention(q, k_pages, v_pages, lengths, page_indices, *,
                                       page_indices, scale)
 
 
+def window_pages(page_indices, positions, num_valid, S: int, ps: int):
+    """Which pages the windows ``positions[b] .. positions[b] + S - 1``
+    fall into, and what each slot of those pages takes:
+
+      page [B, T]      the pool page under the window's t-th page; junk
+                       page 0 where that page takes no valid row (an
+                       idle lane, batch padding, a short window)
+      src  [B, T * ps] the window row that lands in each slot
+      ok   [B, T, ps]  whether that row is real; elsewhere the slot
+                       keeps what it holds
+
+    T is the most pages a window of S rows can touch. No two windows
+    share a page that takes rows (the engine writes at positions >= a
+    sequence's length, into pages that sequence alone holds)."""
+    T = (S + ps - 2) // ps + 1
+    page_indices = page_indices.astype(jnp.int32)
+    positions = positions.astype(jnp.int32)
+    num_valid = num_valid.astype(jnp.int32)
+    col0, slot0 = positions // ps, positions % ps
+    t = jnp.arange(T, dtype=jnp.int32)[None, :]
+    touched = (num_valid[:, None] > 0) & (t * ps < (slot0 + num_valid)[:, None])
+    col = jnp.clip(col0[:, None] + t, 0, page_indices.shape[1] - 1)
+    page = jnp.where(touched,
+                     jnp.take_along_axis(page_indices, col, axis=1), 0)
+    row = (t[:, :, None] * ps + jnp.arange(ps, dtype=jnp.int32)[None, None, :]
+           - slot0[:, None, None])                              # [B, T, ps]
+    ok = touched[:, :, None] & (row >= 0) & (row < num_valid[:, None, None])
+    return page, jnp.clip(row, 0, S - 1).reshape(-1, T * ps), ok
+
+
+def write_page_rows(pool, new, page, src, ok):
+    """Write window rows into a pool, whole pages at a time: gather the
+    pages the windows touch, put the new rows into their slots, store
+    the pages back.
+
+    pool [KVH, P, ps, ...] (K/V pages, or the scale planes of an int8
+    pool), new [KVH, B, S, ...]; ``page``/``src``/``ok`` from
+    ``window_pages``.
+
+    Why not one scatter of rows (``pool.at[:, page, slot].set(rows)``,
+    what this was): XLA's scatter wants the indexed dims outermost, so
+    for a pool whose pages are its second dim it works in the layout
+    [P, ps, KVH, D] and converts the WHOLE pool into it and back out of
+    it for the attention kernel, which reads the pool as stored: two
+    copies of every pool every step, donated or not (PERF.md, PR 30:
+    19.8 ms of a 28.8 ms GPT-3 XL step). Seen as [KVH * P, ps, ...]
+    the pool is the same bytes (the tiled dims are untouched), pages
+    are its outermost dim, and both the gather and the scatter of
+    whole pages work on it as it lies: with the pool donated the
+    update is in place."""
+    KVH, P, ps = pool.shape[:3]
+    B, T = page.shape
+    tail = pool.shape[3:]
+    ones = (1,) * len(tail)
+    flat = pool.reshape((KVH * P, ps) + tail)
+    idx = (jnp.arange(KVH, dtype=jnp.int32)[:, None, None] * P
+           + page[None]).reshape(-1)
+    held = flat[idx].reshape((KVH, B, T, ps) + tail)
+    rows = jnp.take_along_axis(
+        new.astype(pool.dtype), src.reshape((1, B, T * ps) + ones),
+        axis=2).reshape(held.shape)
+    pages = jnp.where(ok.reshape((1, B, T, ps) + ones), rows, held)
+    return flat.at[idx].set(
+        pages.reshape((-1, ps) + tail)).reshape(pool.shape)
+
+
 def kv_cache_write(k_pages, v_pages, k_new, v_new, page_indices,
                    positions, num_valid):
-    """Functional scatter of new K/V rows into the page pool.
+    """Write new K/V rows into the page pool.
 
     k_new/v_new:  [B, S, KVH, D] rows for positions
                   positions[b] .. positions[b] + S - 1
     positions:    [B] int32 — each sequence's first absolute slot
                   (decode: the current length; prefill: 0)
     num_valid:    [B] int32 — rows of S that are real; the rest (batch
-                  padding, idle decode lanes) are routed to junk page 0
+                  padding, idle decode lanes) are written nowhere
 
-    Returns (k_pages', v_pages'). Pure functional update — on TPU the
-    executor's donation machinery aliases the pool buffers, on CPU XLA
-    copies (the smoke-bench regime, where the pool is small).
+    Returns (k_pages', v_pages'): a functional update that XLA does in
+    place when the pools are donated, as the step programs' are
+    (``write_page_rows``); where they are not it costs one copy.
     """
-    B, S, KVH, D = k_new.shape
-    ps = int(k_pages.shape[2])
-    page_indices = page_indices.astype(jnp.int32)
-    positions = positions.astype(jnp.int32)
-    num_valid = num_valid.astype(jnp.int32)
-    offs = positions[:, None] + jnp.arange(S, dtype=jnp.int32)[None, :]
-    valid = jnp.arange(S, dtype=jnp.int32)[None, :] < num_valid[:, None]
-    table_col = jnp.clip(offs // ps, 0, page_indices.shape[1] - 1)
-    page = jnp.take_along_axis(page_indices, table_col, axis=1)   # [B, S]
-    page = jnp.where(valid, page, 0)        # invalid rows -> junk page 0
-    slot = jnp.where(valid, offs % ps, 0)
-    # target selection [KVH, B, S, D]; values arrive as [KVH, B, S, D]
-    kv_k = jnp.transpose(k_new, (2, 0, 1, 3)).astype(k_pages.dtype)
-    kv_v = jnp.transpose(v_new, (2, 0, 1, 3)).astype(v_pages.dtype)
-    k_pages = k_pages.at[:, page, slot, :].set(kv_k)
-    v_pages = v_pages.at[:, page, slot, :].set(kv_v)
-    return k_pages, v_pages
+    S, ps = int(k_new.shape[1]), int(k_pages.shape[2])
+    where = window_pages(page_indices, positions, num_valid, S, ps)
+    return tuple(
+        write_page_rows(pool, jnp.transpose(new, (2, 0, 1, 3)), *where)
+        for pool, new in ((k_pages, k_new), (v_pages, v_new)))
 
 
 # -- program-level layers ----------------------------------------------------
@@ -198,24 +255,22 @@ def paged_attention_layer(q_var, k_pages_var, v_pages_var, tables_var,
 def kv_cache_write_layer(k_pages_var, v_pages_var, k_var, v_var,
                          tables_var, positions_var, num_valid_var,
                          num_heads: int):
-    """Emit the ``kv_cache_write`` op; returns the (functionally)
-    updated page-pool Variables, which downstream paged_attention ops
-    read and the engine fetches back each step."""
+    """Emit the ``kv_cache_write`` op. It writes ``OutKPages`` /
+    ``OutVPages`` onto the pool Variables it reads (``ParamOut = Param``,
+    as the optimizer ops do), so persistable pools are rewritten state
+    that the executor donates and XLA updates in place; the attention
+    ops after it read the same Variables. Returns them."""
     from ..layer_helper import LayerHelper
-    from ..layers.nn import _out
 
-    helper = LayerHelper("kv_cache_write")
-    out_k = _out(helper, k_pages_var, shape=k_pages_var.shape)
-    out_v = _out(helper, v_pages_var, shape=v_pages_var.shape)
-    helper.append_op(
+    LayerHelper("kv_cache_write").append_op(
         type="kv_cache_write",
         inputs={"KPages": [k_pages_var], "VPages": [v_pages_var],
                 "K": [k_var], "V": [v_var], "BlockTables": [tables_var],
                 "Positions": [positions_var], "NumValid": [num_valid_var]},
-        outputs={"OutKPages": [out_k], "OutVPages": [out_v]},
+        outputs={"OutKPages": [k_pages_var], "OutVPages": [v_pages_var]},
         attrs={"num_heads": num_heads},
     )
-    return out_k, out_v
+    return k_pages_var, v_pages_var
 
 
 # -- op registration ---------------------------------------------------------

@@ -83,6 +83,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
+from .paged_attention import window_pages, write_page_rows
 from .quant import blockwise_dequantize, blockwise_quantize
 
 NEG_INF = -1e30
@@ -413,34 +414,23 @@ def quantized_kv_cache_write(k_pages, v_pages, k_scales, v_scales,
                              num_valid):
     """int8 twin of paged_attention.kv_cache_write: each new [D] row
     quantizes to int8 with one fp32 max-abs/127 scale (the
-    kernels/quant.py block unit with block = head_dim), then scatters
-    into the int8 pool + the [KVH, P, ps] scale planes. Invalid rows
-    route to junk page 0 exactly like the fp32 write. Pure functional;
-    XLA fuses quantize + scatter into the surrounding step."""
+    kernels/quant.py block unit with block = head_dim), then goes into
+    the int8 pool + the [KVH, P, ps] scale planes page by page, exactly
+    like the fp32 write (``write_page_rows``: in place when the pools
+    are donated). Pure functional."""
     B, S, KVH, D = k_new.shape
-    ps = int(k_pages.shape[2])
-    page_indices = page_indices.astype(jnp.int32)
-    positions = positions.astype(jnp.int32)
-    num_valid = num_valid.astype(jnp.int32)
-    offs = positions[:, None] + jnp.arange(S, dtype=jnp.int32)[None, :]
-    valid = jnp.arange(S, dtype=jnp.int32)[None, :] < num_valid[:, None]
-    table_col = jnp.clip(offs // ps, 0, page_indices.shape[1] - 1)
-    page = jnp.take_along_axis(page_indices, table_col, axis=1)   # [B, S]
-    page = jnp.where(valid, page, 0)        # invalid rows -> junk page 0
-    slot = jnp.where(valid, offs % ps, 0)
-    # [KVH, B, S, D] rows -> blockwise int8 (one scale per [D] row)
-    kq, ks = blockwise_quantize(
-        jnp.transpose(k_new, (2, 0, 1, 3)).astype(jnp.float32)
-        .reshape(KVH * B * S, D))
-    vq, vs = blockwise_quantize(
-        jnp.transpose(v_new, (2, 0, 1, 3)).astype(jnp.float32)
-        .reshape(KVH * B * S, D))
-    kq = kq.reshape(KVH, B, S, D)
-    vq = vq.reshape(KVH, B, S, D)
-    k_pages = k_pages.at[:, page, slot, :].set(kq)
-    v_pages = v_pages.at[:, page, slot, :].set(vq)
-    k_scales = k_scales.at[:, page, slot].set(ks.reshape(KVH, B, S))
-    v_scales = v_scales.at[:, page, slot].set(vs.reshape(KVH, B, S))
+    where = window_pages(page_indices, positions, num_valid, S,
+                         int(k_pages.shape[2]))
+    out = []
+    for pool, scales, new in ((k_pages, k_scales, k_new),
+                              (v_pages, v_scales, v_new)):
+        # [KVH, B, S, D] rows -> blockwise int8 (one scale per [D] row)
+        q, sc = blockwise_quantize(
+            jnp.transpose(new, (2, 0, 1, 3)).astype(jnp.float32)
+            .reshape(KVH * B * S, D))
+        out.append((write_page_rows(pool, q.reshape(KVH, B, S, D), *where),
+                    write_page_rows(scales, sc.reshape(KVH, B, S), *where)))
+    (k_pages, k_scales), (v_pages, v_scales) = out
     return k_pages, v_pages, k_scales, v_scales
 
 
@@ -477,28 +467,24 @@ def quantized_kv_cache_write_layer(k_pages_var, v_pages_var, k_scales_var,
                                    v_scales_var, k_var, v_var, tables_var,
                                    positions_var, num_valid_var,
                                    num_heads: int):
-    """Emit ``kv_cache_write_q``; returns the functionally updated
-    (k_pages, v_pages, k_scales, v_scales) Variables the downstream
-    ragged attention reads and the engine fetches back."""
+    """Emit ``kv_cache_write_q``: like ``kv_cache_write_layer`` it
+    writes its outputs onto the (k_pages, v_pages, k_scales, v_scales)
+    Variables it reads, so the int8 pools and their scale planes are
+    donated state rewritten in place. Returns them."""
     from ..layer_helper import LayerHelper
-    from ..layers.nn import _out
 
-    helper = LayerHelper("kv_cache_write_q")
-    out_k = _out(helper, k_pages_var, shape=k_pages_var.shape)
-    out_v = _out(helper, v_pages_var, shape=v_pages_var.shape)
-    out_ks = _out(helper, k_scales_var, shape=k_scales_var.shape)
-    out_vs = _out(helper, v_scales_var, shape=v_scales_var.shape)
-    helper.append_op(
+    pools = (k_pages_var, v_pages_var, k_scales_var, v_scales_var)
+    LayerHelper("kv_cache_write_q").append_op(
         type="kv_cache_write_q",
         inputs={"KPages": [k_pages_var], "VPages": [v_pages_var],
                 "KScales": [k_scales_var], "VScales": [v_scales_var],
                 "K": [k_var], "V": [v_var], "BlockTables": [tables_var],
                 "Positions": [positions_var], "NumValid": [num_valid_var]},
-        outputs={"OutKPages": [out_k], "OutVPages": [out_v],
-                 "OutKScales": [out_ks], "OutVScales": [out_vs]},
+        outputs=dict(zip(("OutKPages", "OutVPages", "OutKScales",
+                          "OutVScales"), ([p] for p in pools))),
         attrs={"num_heads": num_heads},
     )
-    return out_k, out_v, out_ks, out_vs
+    return pools
 
 
 # -- op registration ---------------------------------------------------------

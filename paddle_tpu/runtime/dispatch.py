@@ -42,6 +42,7 @@ import json
 import logging
 import os
 import queue as _queue_mod
+import re
 import threading
 import time
 import weakref
@@ -360,6 +361,39 @@ def _globalizing_normalizer(norm, sharding):
         return jax.make_array_from_process_local_data(sharding, arr)
 
     return globalize
+
+
+def hlo_donation_aliases(compiled, hlo_text: str):
+    """What XLA made of a compiled block's donation, read from its
+    optimized HLO (``BoundStep.aot_compiled().as_text()``, or an
+    ``Executor.aot_compile`` for a described chip): ``{donated state
+    name: written name whose output its buffer is aliased onto, or
+    None}``. Donation only offers a buffer; jax pairs offered inputs
+    with outputs of one shape and type in order, so a name aliased onto
+    ANOTHER name's output (or onto none) is a buffer XLA has to copy
+    into. In-place state reads ``name -> name`` for every name."""
+    # jit drops unused arguments, so a parameter's number is not its
+    # position: its op_name says which of step_fn's *args it is
+    arg_of_param = {
+        int(m.group(1)): int(m.group(2)) for m in re.finditer(
+            r'parameter\((\d+)\)[^\n]*?op_name="args\[(\d+)\]"', hlo_text)}
+    header = re.search(r"input_output_alias=\{(.*?)\}, \w+=", hlo_text)
+    out_of_arg = {}
+    for m in re.finditer(r"\{(\d*)\}: \((\d+), \{\}",
+                         header.group(1) if header else ""):
+        arg = arg_of_param.get(int(m.group(2)))
+        if arg is not None:
+            out_of_arg[arg] = int(m.group(1) or 0)
+    n_feed, n_fetch = len(compiled.feed_names), len(compiled.fetch_names)
+    state_pos = {n: i for i, n in enumerate(compiled.state_names)}
+    written = compiled.written_names
+    report = {}
+    for n in getattr(compiled, "donated_names", ()) or ():
+        out = out_of_arg.get(n_feed + state_pos[n])
+        j = None if out is None else out - n_fetch
+        report[n] = (written[j] if j is not None and 0 <= j < len(written)
+                     else None)
+    return report
 
 
 # -- the bound step ---------------------------------------------------------
@@ -753,6 +787,10 @@ class BoundStep:
             "donation_missed": ([] if skip else
                                 [n for n in donatable if n not in donated]),
             "donation_skip_reason": skip,
+            # what the donated arrays hold, as the scope has them now
+            "donated_bytes": sum(
+                int(getattr(self.scope.find_var(n), "nbytes", 0))
+                for n in donated),
             "host_sync_calls": self.host_sync_calls,
             "xla_analysis": dict(getattr(c, "analysis", None) or {}),
         }
@@ -770,6 +808,12 @@ class BoundStep:
         return self.compiled.fn.lower(
             self.base_key, np.int32(0), *self.feed_avals,
             *self.state_vals).compile()
+
+    def donation_aliases(self):
+        """``hlo_donation_aliases`` of this step's optimized HLO (one
+        lower + compile, as ``aot_compiled``)."""
+        return hlo_donation_aliases(self.compiled,
+                                    self.aot_compiled().as_text())
 
     def _first_call(self, fn, counter, ordered):
         """First invocation of a fresh compiled block: this is where

@@ -24,6 +24,7 @@ import pytest
 
 import paddle_tpu as fluid
 from paddle_tpu.generation import GenerationEngine
+from paddle_tpu.generation.kvcache import pool_names
 from paddle_tpu.generation.model import (CacheGeometry,
                                          build_hybrid_step_program)
 from paddle_tpu.inference import Config, create_predictor
@@ -341,10 +342,12 @@ def _drive_step(plan, lanes, chunk, decode_after):
     scope = _scope_with(WEIGHTS)
     state = {n: jnp.zeros([lanes if s == -1 else s for s in shp], dt)
              for n, (shp, dt) in HCFG.state_shapes(-1).items()}
-    n_kv = len(HCFG.attention_layers)
-    shape = (HCFG.num_kv_heads, 40, 4, HCFG.head_dim)
-    kp = [jnp.zeros(shape) for _ in range(n_kv)]
-    vp = [jnp.zeros(shape) for _ in range(n_kv)]
+    # the page pools are the step's own state: it finds them in the
+    # scope, rewrites them and the executor stores them back
+    for names in pool_names(len(HCFG.attention_layers))[:2]:
+        for name in names:
+            scope.set_var(name, jnp.zeros(
+                (HCFG.num_kv_heads, 40, 4, HCFG.head_dim)))
     tables = np.zeros((lanes, 16), np.int32)
     for r in range(lanes):
         tables[r] = 1 + r * 12 + np.arange(16) % 12
@@ -362,13 +365,9 @@ def _drive_step(plan, lanes, chunk, decode_after):
         feed = {"gen_tokens": toks, "gen_pos_ids": np.zeros_like(toks),
                 "gen_positions": pos, "gen_num_valid": nv,
                 "gen_block_tables": tables, **state}
-        for j in range(n_kv):
-            feed[f"gen_k_pages_{j}"] = kp[j]
-            feed[f"gen_v_pages_{j}"] = vp[j]
         outs = exe.run(main, feed=feed, fetch_list=fetches + [logits_var],
                        scope=scope, return_numpy=False)
-        kp, vp = list(outs[1:1 + n_kv]), list(outs[1 + n_kv:1 + 2 * n_kv])
-        state = dict(zip(state, outs[1 + 2 * n_kv:-1]))
+        state = dict(zip(state, outs[1:-1]))
         logits = np.asarray(outs[-1])                      # [lanes, 1, V]
         tokens = np.asarray(outs[0]).reshape(lanes, chunk)
         for r in plan:
@@ -501,8 +500,9 @@ def test_engine_refuses_what_recurrent_state_cannot_serve(predictor, kwargs,
 def test_gpt_engine_step_feeds_and_fetches_are_the_parents(tmp_path):
     """The dense decoder's ragged step takes and gives exactly what it
     did before recurrent state existed, in the program and in the dict
-    the engine assembles each step: 5 scheduler feeds and 2 pools a
-    layer in, tokens and 2 pools a layer out, no `gen_state_*`."""
+    the engine assembles each step: 5 scheduler feeds in, tokens out,
+    no `gen_state_*`; 2 pools a layer are its state, neither fed nor
+    fetched."""
     from paddle_tpu.generation.model import GPTConfig, build_lm_program
 
     cfg = GPTConfig(vocab_size=97, hidden_size=32, num_layers=2, num_heads=4,
@@ -514,10 +514,8 @@ def test_gpt_engine_step_feeds_and_fetches_are_the_parents(tmp_path):
         exe.run(startup)
         fluid.io.save_inference_model(str(tmp_path), ["tokens"],
                                       [fetches["logits"]], exe, main)
-    want = sorted(
-        ["gen_tokens", "gen_pos_ids", "gen_positions", "gen_num_valid",
-         "gen_block_tables"] + [f"gen_{kv}_pages_{i}" for kv in "kv"
-                                for i in range(2)])
+    want = sorted(["gen_tokens", "gen_pos_ids", "gen_positions",
+                   "gen_num_valid", "gen_block_tables"])
     seen = []
     with GenerationEngine(create_predictor(Config(str(tmp_path))), cfg,
                           mode="ragged", page_size=4, num_pages=16,
@@ -527,7 +525,9 @@ def test_gpt_engine_step_feeds_and_fetches_are_the_parents(tmp_path):
         eng._bind_ragged = lambda feed: seen.append(sorted(feed)) or bind(feed)
         assert len(eng.generate(np.arange(1, 7), max_new_tokens=3)) == 3
         assert eng._state_names == () and eng.cache.state == {}
-        assert len(eng._ragged_fetches) == 1 + 2 * 2
+        assert len(eng._ragged_fetches) == 1
+        assert eng._ragged_bound.compiled.donatable_names == [
+            f"gen_{kv}_pages_{i}" for i in range(2) for kv in "kv"]
         st = eng.stats()
     assert seen and all(names == want for names in seen)
     assert "moe_held_assignments_total" not in st

@@ -344,11 +344,13 @@ def _child():
                 "gen_positions": np.zeros(Rl, np.int64),
                 "gen_num_valid": np.zeros(Rl, np.int32),
                 "gen_block_tables": np.zeros((Rl, maxp), np.int32)}
+        scope = fluid.Scope()
+        # the page pools are state of the step, donated and rewritten
+        # in place: they come from the scope like the weights
         for li in range(cfg.num_layers):
             for kv in "kv":
-                feed[f"gen_{kv}_pages_{li}"] = jax.ShapeDtypeStruct(
-                    (Hh, Pp, psz, Dd), jnp.float32)
-        scope = fluid.Scope()
+                scope.set_var(f"gen_{kv}_pages_{li}",
+                              np.zeros((Hh, Pp, psz, Dd), np.float32))
         with fluid.scope_guard(scope):
             exe = fluid.Executor(fluid.TPUPlace())
             exe.run(startup)
@@ -402,14 +404,14 @@ def _child():
                 "gen_positions": np.zeros(lanes, np.int64),
                 "gen_num_valid": np.zeros(lanes, np.int32),
                 "gen_block_tables": np.zeros((lanes, maxp), np.int32)}
-        for j in range(len(hcfg.attention_layers)):
-            for kv in "kv":
-                feed[f"gen_{kv}_pages_{j}"] = np.zeros(
-                    (hcfg.num_kv_heads, geom.num_pages, geom.page_size,
-                     hcfg.head_dim), ml_dtypes.bfloat16)
         for name, (shape, dt) in hcfg.state_shapes(lanes).items():
             feed[name] = np.zeros(shape, dt)
         scope = fluid.Scope()
+        for j in range(len(hcfg.attention_layers)):
+            for kv in "kv":         # state of the step, as the weights
+                scope.set_var(f"gen_{kv}_pages_{j}", np.zeros(
+                    (hcfg.num_kv_heads, geom.num_pages, geom.page_size,
+                     hcfg.head_dim), ml_dtypes.bfloat16))
         for name, shape, _init in ref.spec(cfg):
             scope.set_var(name, np.zeros(shape, ml_dtypes.bfloat16))
         exe = fluid.Executor(fluid.TPUPlace())

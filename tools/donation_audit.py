@@ -40,6 +40,7 @@ from __future__ import annotations
 import argparse
 import json
 import os
+import re
 import shutil
 import sys
 import tempfile
@@ -148,16 +149,56 @@ def _phase_generation(fluid, tmpdir):
                                       [fetches["logits"]], exe, main)
     pred = create_predictor(Config(lm_dir))
     pred._exe._force_donation = True
-    eng = generation.GenerationEngine(
-        pred, cfg, page_size=8, num_pages=64, max_decode_batch=4,
-        prefill_buckets=(16, seq))
     rng = np.random.RandomState(3)
-    streams = [eng.submit(rng.randint(1, cfg.vocab_size, 7).astype(np.int64),
-                          max_new_tokens=4) for _ in range(3)]
-    for s in streams:
-        s.result(timeout=300)
-    eng.close(drain=True)
-    return [pred, eng]
+    engines = []
+    # both modes: the one ragged executable, and two_lane's prefill
+    # buckets + decode step; all of them rewrite the engine's page
+    # pools as donated state (generation_pools_check)
+    for mode in ("ragged", "two_lane"):
+        eng = generation.GenerationEngine(
+            pred, cfg, page_size=8, num_pages=64, max_decode_batch=4,
+            prefill_buckets=(16, seq), mode=mode)
+        streams = [eng.submit(
+            rng.randint(1, cfg.vocab_size, 7).astype(np.int64),
+            max_new_tokens=4) for _ in range(3)]
+        for s in streams:
+            s.result(timeout=300)
+        eng.close(drain=True)
+        engines.append(eng)
+    return [pred] + engines
+
+
+def generation_pools_check(rows):
+    """The page pools are the largest state a generation step rewrites
+    and were, fed and fetched, invisible here until PR 30: every
+    ``generation/`` executable of the audit's report rows (or of
+    ``--check-static``'s static plans) must list each of its program's
+    pools (``gen_k_pages_*`` ...) as donated. Returns violations."""
+    violations = []
+    steps = [r for r in rows if str(r["tag"]).startswith("generation/")]
+    tags = {str(r["tag"]).split("[")[0] for r in steps}
+    for want in ("generation/ragged_step", "generation/prefill",
+                 "generation/decode"):
+        if want not in tags:
+            violations.append(
+                f"generation phase ran no {want!r} executable — the "
+                "audit lost its coverage of the page pools")
+    for r in steps:
+        donated = r.get("donated", r.get("static_donatable"))
+        pools = [n for n in r.get("pools", ()) if n not in donated]
+        if pools or not r.get("pools"):
+            violations.append(
+                f"page pools not donated: generation / {r['tag']} "
+                f"donates {sorted(donated)} and leaves out "
+                f"{pools or 'every pool (it declares none as state)'} — "
+                "the step would copy each of them every step")
+    return violations
+
+
+def _pools_of(bound):
+    """The page-pool variables a bound step's program declares."""
+    return sorted(n for n in bound.block.vars
+                  if re.fullmatch(r"gen_[kv]_(pages|scales)_\d+", n))
 
 
 def _phase_partition(fluid, tmpdir):
@@ -408,8 +449,13 @@ def run_audit():
         "donation_missed": [],
     }}
     for site, bounds in sites.items():
-        rows = sorted((b.audit_info() for b in bounds),
-                      key=lambda r: r["tag"])
+        rows = []
+        for b in bounds:
+            row = b.audit_info()
+            if site == "generation":
+                row["pools"] = _pools_of(b)
+            rows.append(row)
+        rows.sort(key=lambda r: r["tag"])
         report["sites"][site] = rows
         report["summary"]["total_executables"] += len(rows)
         syncs = sum(r["host_sync_calls"] for r in rows)
@@ -457,6 +503,8 @@ def static_cross_check(report, sites, allow):
                 "runtime_donatable": runtime_don,
                 "agrees": static_don == runtime_don,
             }
+            if site == "generation":
+                row["pools"] = _pools_of(b)
             static_rows.append(row)
             donatable_by_site.setdefault(site, set()).update(static_don)
             if not row["agrees"]:
@@ -531,12 +579,14 @@ def main():
 
     report, sites = run_audit()
     allow = load_allowlist()
-    violations = check(report, allow)
+    violations = check(report, allow) + generation_pools_check(
+        report["sites"]["generation"])
     if args.check_static:
         static_rows, static_violations = static_cross_check(
             report, sites, allow)
         report["static_plans"] = static_rows
-        violations = violations + static_violations
+        violations = violations + static_violations + generation_pools_check(
+            [r for r in static_rows if r["site"] == "generation"])
     report["violations"] = violations
     report["allowlist"] = allow
 
