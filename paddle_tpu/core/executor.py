@@ -522,12 +522,12 @@ class Executor:
         # hogwild path: concurrent steps over a shared scope must not
         # alias-donate the same param buffers
         self.disable_donation = False
-        # tools/dispatch_bench.py pre-PR emulation: donate even on CPU
-        # (the pre-dispatch-cache executor always donated)
+        # donate even on the CPU, where the executor otherwise donates
+        # nothing (tools/donation_audit.py, tests/test_generation_donation.py)
         self._force_donation = False
         # hot-path dispatch (runtime/dispatch): fully-resolved BoundSteps
         # keyed on the cheap raw signature; fast_dispatch=False forces
-        # the slow path every call (dispatch-overhead benchmarking).
+        # the slow path every call. No caller sets it (ROADMAP queue 3).
         # LRU-capped: each entry pins a scope's state arrays via its
         # cached refs, and dead scopes / superseded flag generations
         # mint new keys without retiring old ones
@@ -1208,7 +1208,7 @@ class Executor:
     def export_fn(self, program, feed, fetch_list, scope=None, mesh=None):
         """Return (raw_fn, example_args) for a program — the un-jitted
         pure step function plus concrete arguments. Used by
-        __graft_entry__ and bench.py."""
+        __graft_entry__."""
         scope = scope or global_scope()
         block = program.global_block()
         feed_vals, _ = self._prepare_feed(block, dict(feed))

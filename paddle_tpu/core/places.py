@@ -54,7 +54,7 @@ class TPUPlace(Place):
     """The native target: a device of jax's default backend — the TPU
     where one is attached, the CPU under JAX_PLATFORMS=cpu (tests).
     Code that must not run without a chip asserts the platform itself
-    (chip_smoke.py, bench.py)."""
+    (chip_smoke.py, benchmark/run.py)."""
 
     _backend = None  # jax's default backend
 

@@ -18,10 +18,9 @@ streams finished KV pages between them through a host-RAM page store:
 Because the decode worker re-derives the first output token from the
 spliced prefix, the split topology is token-identical to co-located
 greedy serving — bit-identical with int8 KV pools or
-``disagg_wire_encoding="raw"`` (tests/test_disagg.py gates this).
-``tools/disagg_bench.py --smoke`` gates decode ITL flat under
-prefill-saturating load, wire bytes <= 0.3x fp32, and warm-start TTFT
-<= 0.5x cold.
+``disagg_wire_encoding="raw"`` (tests/test_disagg.py gates this, the
+wire bytes <= 0.3x fp32 and the warm start of a fresh decode worker;
+no cell of the benchmark times the split topology yet).
 """
 
 from __future__ import annotations

@@ -17,8 +17,8 @@ scope, run as one jitted greedy loop over the whole batch of contexts
 forwards, not k x rows. ``from_predictor(pred, cfg, num_layers=n)``
 truncates to the first n decoder layers for a genuinely smaller draft;
 with the full layer stack the draft replicates the target and the
-acceptance rate approaches 1.0 (the bench's upper-bound
-configuration — tools/generation_bench.py --spec).
+acceptance rate approaches 1.0 (the upper-bound configuration
+tests/test_ragged.py::test_spec_decode_greedy_equivalence runs).
 
 The draft runs OUTSIDE the ragged executable on purpose: its batch
 shape is [rows, max_position] with its own (cheap) compile, and the

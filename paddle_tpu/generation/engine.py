@@ -30,8 +30,8 @@ does what modern LLM serving does instead:
   speculative draft tokens, or nothing (idle lane). Prompts longer
   than ``chunk_tokens`` prefill in chunks ACROSS steps (chunked
   prefill), so a fat prompt arriving mid-traffic costs every running
-  sequence a bounded slice per step instead of a whole-prompt stall —
-  the decode-ITL interference gate in tools/generation_bench.py.
+  sequence a bounded slice per step instead of a whole-prompt stall
+  (token identity: tests/test_ragged.py; the tails: PERF.md).
 * **Speculative decoding** (``spec_tokens`` + a ``generation.draft``
   model): the draft proposes k tokens per sequence, the target
   verifies all of them in the SAME ragged call (its argmax at every

@@ -47,7 +47,7 @@ pages store blockwise-int8 values plus one fp32 scale per
 block = head_dim. A page then costs ~1/3.6 the fp32 bytes
 (``page_bytes``), so the same HBM budget holds ~3.6x the pages and
 ~2x+ the resident sequences — the capacity multiplier
-tools/generation_bench.py --int8 gates.
+tests/test_ragged.py::test_int8_capacity_arithmetic holds.
 
 **Radix prefix cache** (``prefix_cache=True``, ragged engine only):
 every page carries a REFCOUNT, and full (page-aligned) token runs are

@@ -98,7 +98,7 @@ def _pallas_mode() -> Optional[str]:
     # PADDLE_TPU_KERNEL_INTERPRET is the shared interpret switch the
     # other fused kernels (layer_norm, softmax_xent) use — honoring it
     # here keeps CI smoke coverage real: with only the flash-specific
-    # var, tests/test_bench_smoke.py's flash stages would take the
+    # var, tests/test_models_zoo.py's flash cases would take the
     # reference path on CPU
     if (os.environ.get("PADDLE_TPU_FLASH_INTERPRET", "")
             or os.environ.get("PADDLE_TPU_KERNEL_INTERPRET", "")):

@@ -3,7 +3,7 @@
 Reference analogue: the "book"/dist test model definitions
 (tests/book/, tests/unittests/dist_mnist.py, dist_se_resnext.py,
 dist_transformer.py) — canonical models exercising the stack, also used
-by bench.py and __graft_entry__.py.
+by benchmark/, chip_smoke.py and __graft_entry__.py.
 """
 
 from .bert import BertConfig, build_bert_pretrain, apply_megatron_sharding

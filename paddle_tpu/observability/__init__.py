@@ -38,8 +38,8 @@ Live flags (flags.py): ``observability_metrics``,
 ``observability_flight_capacity``, ``observability_dump_dir``,
 ``observability_xla_analysis``, ``observability_fleet_endpoints``,
 ``observability_fleet_timeout_s``, plus the ``slo_*`` family.
-``tools/obs_bench.py --smoke`` gates the enabled-path per-step
-overhead at <3% of a bare step (propagation codec included).
+tests/test_observability.py holds the scrape's families and the flight
+dumps; no cell of the benchmark turns these flags on.
 """
 
 from __future__ import annotations

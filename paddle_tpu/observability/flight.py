@@ -75,8 +75,8 @@ def _get_ring() -> collections.deque:
 def note(kind: str, **fields) -> None:
     """Append one entry. Safe from any thread; silently a no-op when
     the recorder is disabled. This runs per STEP and per span — the
-    direct flag-store read and the single uncontended lock keep it at
-    ~1us (covered by the obs_bench <3% gate)."""
+    direct flag-store read and the single uncontended lock keep it
+    cheap."""
     if not _flags["observability_flight"]:
         return
     entry = {"kind": kind, "t": fields.pop("t", None) or time.time()}

@@ -605,7 +605,7 @@ class _StepTelemetry:
     """Per-step telemetry. NOT registry instruments per field: a step
     is the hottest path in the process, so all counters live behind
     ONE lock and export through a scrape-time collector like every
-    other subsystem (the <3% obs_bench gate covers ``record``)."""
+    other subsystem."""
 
     __slots__ = ("_lock", "steps", "examples", "wall_ms_sum", "hist",
                  "last_ms", "last_eps")

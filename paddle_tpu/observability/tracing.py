@@ -34,8 +34,7 @@ two lock-free id draws, parentage from the thread-local stack,
 assembled across threads and processes) — ``executor/step`` and
 ``generation/step`` do, as before; the other phases of the two loops are
 ``annotation``s, which stay on the off path, so the flag still costs one
-ring entry a step. ``tools/obs_bench.py`` gates the combined
-metrics+tracing per-step cost at <3% of a bare step.
+ring entry a step.
 """
 
 from __future__ import annotations

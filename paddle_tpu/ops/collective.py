@@ -116,8 +116,8 @@ def _collective_bucket_reduce(ctx, op, ins):
     env = ctx.axis_env or {}
     axis = env.get("collective_axis")
     if axis is None or env.get("collective_skip_reduce"):
-        # collective_skip_reduce: the bench's compute-only timing
-        # variant — same program shape, collectives elided
+        # collective_skip_reduce (CollectivePlan.skip_reduce, which no
+        # caller sets): same program shape, collectives elided
         return {"Out": list(xs)}
     size = int(env.get("collective_axis_size", 1))
     quantized = op.attrs.get("quantization", "none") == "int8"

@@ -37,7 +37,8 @@ as the cross-replica mean (== the global batch mean for equal shards).
 ``collective_quantization="int8"`` swaps each bucket's psum for the
 EQuARX-style two-shot blockwise exchange (kernels/quant.py): ~3.9x
 fewer wire bytes at block 256, one quantization step of error per
-phase, gated by tools/collective_bench.py's loss-trajectory check.
+phase (tests/test_collectives.py::test_int8_zero1_trains_close_to_fp32
+holds the loss trajectory).
 """
 
 from __future__ import annotations
@@ -168,9 +169,10 @@ class CollectivePlan:
         self.quant_block = int(quant_block)
         self.bucket_mb = float(bucket_mb)
         self.axis = axis
-        # timing-only debug mode (tools/collective_bench.py): lower the
-        # bucket ops as identity so a compute-only baseline step can be
-        # measured; toggling re-keys the executable (fingerprint+bump)
+        # timing-only debug mode: lower the bucket ops as identity so a
+        # compute-only baseline step can be measured; toggling re-keys
+        # the executable (fingerprint+bump). Nothing but its test
+        # sets it (ROADMAP queue 3, orphan knobs)
         self.skip_reduce = False
         self._dp: Optional[int] = None
         self._exchange = False  # set by attach(): real int8 exchange?

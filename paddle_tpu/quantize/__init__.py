@@ -10,9 +10,8 @@ persistable consumed only as the right-hand operand of ``mul`` /
   * quantizes the Scope value ONCE into a device-resident int8/fp8
     buffer plus an fp32 scale plane (``kernels/quant_matmul
     .quantize_weight``) and DROPS the fp32 original from the Scope —
-    the HBM win is real, not a shadow copy (verified by
-    ``tools/quant_bench.py`` against the executable's XLA
-    memory_analysis bytes);
+    the HBM win is real, not a shadow copy (tests/test_quantize.py
+    holds the originals gone from Scope and Program);
   * repoints every consumer op onto the registered quantized ops
     (``quantized_fc`` / ``quantized_matmul``), which carry the scale
     tracking through the matmul (dequantize-in-registers on TPU, a
@@ -70,8 +69,8 @@ _QUANTIZED_OPS = {"quantized_fc", "quantized_matmul"}
 class QuantizeReport:
     """What the rewrite did, per variable: quantized (with the byte
     accounting) or skipped (with the reason). ``summary()`` gives the
-    headline: weight bytes before/after and the ratio the quant_bench
-    gate checks."""
+    headline: weight bytes before/after and their ratio
+    (tests/test_quantize.py holds it under 0.5)."""
 
     def __init__(self, mode: str, block: int):
         self.mode = mode

@@ -421,12 +421,11 @@ def _upload(store, rng, aid, rank, n_targets=2):
     return store.upload(aid, fac, alpha=2.0 * rank)
 
 
-@pytest.mark.slow
 def test_mixed_adapter_batch_matches_sequential(lm_dir):
     """THE multiplexing proof at test scale: 4 distinct adapters + a
     base row submitted together through ONE ragged executable are
     token-identical to per-adapter sequential runs on dedicated
-    engines (tools/adapter_bench.py scales this to 8)."""
+    engines."""
     rng = np.random.RandomState(7)
     prompt = np.asarray([3, 11, 5, 2, 17, 8], np.int64)
     eng = _adapter_engine(lm_dir, lanes=5)
@@ -482,7 +481,6 @@ def _shadow_store(eng):
                         slots_per_bucket=3)
 
 
-@pytest.mark.slow
 def test_hot_swap_zero_drop_same_executable(lm_dir):
     """Hot base swap under live submissions: zero failed requests, the
     SAME BoundStep object (no rebind, no recompile), no new persistent

@@ -8,8 +8,9 @@ Proof layers, per the subsystem's contract:
   producer, consumer repointing, idempotence, flag gating);
 * numerics: the bucketed fp32 path is BIT-identical to the PR-8
   monolithic GSPMD path (losses and updated params) — including under
-  ZeRO-1, a dp x tp mesh, and clip-by-global-norm — and degrades to
-  exactly the monolithic result when no mesh is attached; int8
+  ZeRO-1 and a dp x tp mesh, and to an ulp under clip-by-global-norm
+  (the compiler sums a gradient's squares in another order) — and
+  degrades to exactly the monolithic result when no mesh is attached; int8
   composes with ZeRO-1 (tuple-spec moments included) within the
   quantization tolerance;
 * the quantization kernel: round-trip error bounded by the per-block
@@ -221,17 +222,25 @@ def test_bucketed_fp32_bit_identical_on_dp_tp_mesh():
     assert mono == buck
 
 
-def test_bucketed_fp32_bit_identical_with_global_norm_clip():
-    """Clip-by-global-norm must see the REDUCED (true global) grads —
-    the planner reduces before the clip ops, so the clip scale matches
-    the monolithic path's exactly."""
+def test_bucketed_fp32_matches_to_an_ulp_with_global_norm_clip():
+    """Clip-by-global-norm must see the REDUCED (true global) grads:
+    the planner reduces before the clip ops, and the reduced grads are
+    bit-equal to the monolithic path's (the test above; the same holds
+    here). What differs is the compiler's: each ``reduce_sum(square(g))``
+    sums one gradient's elements in another order when g comes out of
+    the bucket's psum than when it comes out of GSPMD's all-reduce
+    (up to 6 ulps on a per-gradient sum at step 0, 1 ulp on the norm),
+    so the clip scale, the weights (1.5e-8) and, from step 4 on, the
+    loss move by an ulp. A clip that saw local grads would move the
+    loss in the second decimal."""
     clip = fluid.clip.GradientClipByGlobalNorm(0.5)
     mono, _ = _train(lambda m: fluid.CompiledProgram(m)
                      .with_partitioning(_cfg()), clip=clip)
     buck, _ = _train(lambda m: fluid.CompiledProgram(m)
                      .with_partitioning(_cfg(collective_bucket_mb=0.001)),
                      clip=clip)
-    assert mono == buck
+    np.testing.assert_array_max_ulp(np.float32(mono), np.float32(buck),
+                                    maxulp=2)
 
 
 def test_planned_program_without_mesh_degrades_to_monolithic():

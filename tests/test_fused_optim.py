@@ -314,6 +314,44 @@ def test_interpret_pallas_matches_oracle(monkeypatch):
                                        atol=1e-6, rtol=1e-6)
 
 
+def test_reference_lowering_moves_no_more_bytes_than_the_chain():
+    """The fused op off the chip lowers to ``_reference_adam``: by
+    XLA's own count of bytes accessed it may not move more than the
+    unfused chain it replaces (a wrapper that grew a copy or an output
+    would). The Mosaic kernel's own traffic is the ``fused_adam_*``
+    rows of tools/aot_check.py (``temp_bytes`` 0 on a v5e)."""
+    import jax
+    import jax.numpy as jnp
+
+    from paddle_tpu.kernels import fused_optim as fo
+
+    rng = np.random.RandomState(0)
+    p = jnp.asarray(rng.randn(64, 256), jnp.float32)
+    g = jnp.asarray(rng.randn(64, 256), jnp.float32)
+    m, v = jnp.zeros_like(p), jnp.zeros_like(p)
+    b1p, b2p = jnp.float32(0.9), jnp.float32(0.999)
+
+    def chain(p, g, m1, m2, lr):      # ops/optim.py's adam math
+        lr_t = lr * jnp.sqrt(1 - b2p) / (1 - b1p)
+        m1n = 0.9 * m1 + (1 - 0.9) * g
+        m2n = 0.999 * m2 + (1 - 0.999) * jnp.square(g)
+        return p - lr_t * m1n / (jnp.sqrt(m2n) + 1e-8), m1n, m2n
+
+    def fused(p, g, m1, m2, lr):
+        lr_t = lr * jnp.sqrt(1 - b2p) / (1 - b1p)
+        return fo._reference_adam(p, g, m1, m2, lr_t, lr, None,
+                                  0.9, 0.999, 1e-8, 0.0)
+
+    def bytes_of(fn):
+        cost = jax.jit(fn).lower(p, g, m, v, jnp.float32(1e-3)) \
+            .compile().cost_analysis()
+        if isinstance(cost, (list, tuple)):
+            cost = cost[0]
+        return float(cost["bytes accessed"])
+
+    assert bytes_of(fused) <= bytes_of(chain) * 1.01
+
+
 def test_bf16_param_f32_moments(monkeypatch):
     """Mixed-precision layout (bf16 params, f32 moments) through the
     interpret-mode kernel: dtypes preserved, values near the f32

@@ -566,7 +566,7 @@ COVERED_ELSEWHERE = {
     # PR-9 gradient-collective planner (tests/test_collectives.py:
     # bucketed fp32 bit-identity vs monolithic x4 trajectories, int8
     # quant round-trip bound, exchange==psum-form equivalence, and
-    # tools/collective_bench.py loss-trajectory accuracy gate)
+    # the int8 loss-trajectory tolerance)
     'collective_bucket_reduce',
     # round-4 MoE (tests/test_moe.py: dense training, ep parity,
     # capacity drops, gpt integration)

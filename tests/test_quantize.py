@@ -332,7 +332,6 @@ def test_flag_consumed_at_predictor_construction(lm_dir):
     assert np.all(np.isfinite(logits))
 
 
-@pytest.mark.slow
 def test_fully_quantized_ragged_engine_through_churn_eviction(lm_dir):
     """THE serving proof: int8 weights + int8 KV pages together, token
     agreement with the fp32 engine through slot churn, pool-pressure

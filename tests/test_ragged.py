@@ -532,7 +532,7 @@ def test_spec_decode_garbage_draft_still_greedy(predictor, oracle):
     assert st["spec_accepted_total"] == 0
 
 
-@pytest.mark.slow  # eviction-pressure + HTTP round trip; ragged-bench CI job
+@pytest.mark.slow  # eviction-pressure + HTTP round trip; slow-tests CI job
 def test_spec_decode_through_eviction_and_http(predictor, oracle):
     """Spec decode under pool pressure (evict/resume) AND through the
     streamed HTTP endpoint stays greedy-identical, with the usage
@@ -630,7 +630,7 @@ def test_int8_engine_generates_and_frees_pages(predictor, oracle):
     assert eng.cache.quantized
 
 
-@pytest.mark.slow  # builds a tiny LM + HTTP stack; ragged-bench CI job
+@pytest.mark.slow  # builds a tiny LM + HTTP stack; slow-tests CI job
 def test_stalled_socket_frees_quantized_pages():
     """Regression (ISSUE 13 satellite): a stalled /v1/generate client
     over the INT8 engine is cancelled and its quantized pages + scale

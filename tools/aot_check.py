@@ -40,6 +40,40 @@ _CHILD_ENV = {
 }
 
 
+def _load(path):
+    """A file of benchmark/ as a module, by path: the benchmark is not
+    a package of the program."""
+    import importlib.util
+
+    spec = importlib.util.spec_from_file_location(
+        os.path.basename(path)[:-3], path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+def headline_step():
+    """(main, startup, loss, feed) of the cell bert_base_s512_1chip:
+    benchmark/configs/bert_base_pretrain.json built by
+    benchmark/models/bert_program.py, one batch of
+    benchmark/traffic/pretrain_s512.json's batch_per_chip rows."""
+    import numpy as np
+
+    bench = os.path.join(HERE, "benchmark")
+    with open(os.path.join(bench, "configs",
+                           "bert_base_pretrain.json")) as f:
+        cfg = json.load(f)
+    with open(os.path.join(bench, "traffic", "pretrain_s512.json")) as f:
+        traffic = json.load(f)
+    model = _load(os.path.join(bench, "models", "bert_program.py"))
+    seq = traffic["seq_len"]
+    main_prog, startup, loss_var = model.build(cfg, seq)
+    (feed,) = model.make_feeds(np.random.default_rng(0), 1,
+                               traffic["batch_per_chip"], seq,
+                               cfg["vocab_size"])
+    return main_prog, startup, loss_var, feed
+
+
 def _child():
     import numpy as np
     import jax
@@ -367,8 +401,6 @@ def _child():
     # recurrent state, bfloat16 pages. A v5e compile failure or a step
     # that does not fit 16 GB shows here, before a chip call.
     def hybrid_step_program():
-        import importlib.util
-
         import ml_dtypes
 
         import paddle_tpu as fluid
@@ -376,20 +408,12 @@ def _child():
             CacheGeometry, build_hybrid_step_program)
 
         bench = os.path.join(HERE, "benchmark")
-
-        def load(path):
-            spec = importlib.util.spec_from_file_location(
-                os.path.basename(path)[:-3], path)
-            mod = importlib.util.module_from_spec(spec)
-            spec.loader.exec_module(mod)
-            return mod
-
         with open(os.path.join(bench, "configs",
                                "granite4_h_small_serve.json")) as f:
             cfg = json.load(f)
-        ref = load(os.path.join(bench, "models",
-                                "granite_hybrid_reference.py"))
-        hcfg = load(os.path.join(
+        ref = _load(os.path.join(bench, "models",
+                                 "granite_hybrid_reference.py"))
+        hcfg = _load(os.path.join(
             bench, "models", "granite_hybrid_program.py")).hybrid_config(cfg)
         eng = cfg["engine"]
         lanes, chunk = eng["lanes"], eng["chunk_tokens"]
@@ -432,54 +456,28 @@ def _child():
         group="hybrid", lanes=32, heads=128, head_dim=64, state=128,
         chunk=16)
 
-    # -- the bench stages: full train steps at their REAL shapes -------
-    # the exact (kind, model, batch, seq) of bench.py's stage ladder,
-    # params + adam state as abstract args, full fwd+bwd+update. This
-    # is also the only chipless answer to "does batch 32 seq 512 /
-    # resnet batch 256 even fit 16 GB v5e HBM".
-    def stage_step(kind, model, batch, seq, flash, tag):
-        import bench
+    # -- the headline: the train cell's own step at its REAL shapes ----
+    # params + adam state from the startup program, full fwd+bwd+
+    # update through the Executor's own compile path (what
+    # Executor.run binds and donates), not a private jit. Also the
+    # chipless answer to "does batch 24 seq 512 fit 16 GB of v5e HBM".
+    if os.environ.get("PT_AOT_HEADLINE", "1") == "1":
+        main_prog, startup, loss_var, feed = headline_step()
 
-        import paddle_tpu as fluid
-        from paddle_tpu.contrib.mixed_precision import decorate
+        def headline():
+            import paddle_tpu as fluid
 
-        opt = decorate(fluid.optimizer.Adam(1e-4), init_loss_scaling=1.0,
-                       use_dynamic_loss_scaling=False,
-                       dest_dtype="bfloat16")
-        build = {"bert": bench._build_bert, "gpt": bench._build_gpt,
-                 "resnet": bench._build_resnet}[kind]
-        main_prog, startup, loss_var, cfg = build(fluid, model, seq, opt,
-                                                  flash)
-
-        def compile_fn():
-            # the Executor's own compile path (what Executor.run binds
-            # and donates), not a private jit around export_fn
             scope = fluid.Scope()
             with fluid.scope_guard(scope):
                 exe = fluid.Executor(fluid.TPUPlace())
                 exe.run(startup)
-                batch_data = bench._batch_for(kind, np, batch, seq, cfg)
-                return exe.aot_compile(main_prog, batch_data, [loss_var],
+                return exe.aot_compile(main_prog, feed, [loss_var],
                                        scope=scope, devices=[dev])
 
-        record(f"stage_{tag}", compile_fn,
-               kind=kind, model=model, batch=batch, seq=seq, flash=flash)
-
-    if os.environ.get("PT_AOT_HEADLINE", "1") == "1":
-        stage_step("bert", "base", 16, 512, True,
-                   "headline_bert_base_s512_flash")
-    if os.environ.get("PT_AOT_STAGES", "0") == "1":
-        import bench
-
-        seen = set()
-        for st in bench.STAGES:
-            key = (st["kind"], st["model"], st["batch"], st["seq"],
-                   st["flash"])
-            if key in seen or st["tag"] == "headline":
-                continue
-            seen.add(key)
-            stage_step(st["kind"], st["model"], st["batch"], st["seq"],
-                       st["flash"], st["tag"])
+        batch, seq = feed["src_ids"].shape
+        record("stage_headline_bert_base_s512_flash", headline,
+               config="bert_base_pretrain", traffic="pretrain_s512",
+               batch=batch, seq=seq)
 
     # -- MULTICHIP: distributed paths compiled for a real v5e x4 -------
     # Executor.aot_compile re-lays the CompiledProgram's mesh onto the

@@ -66,9 +66,6 @@ millions-of-requests regime (the harness is open-loop and O(1) per
 request, so scale is bounded by wall clock, not memory). Prints one
 JSON object; --out FILE also writes it (CI uploads the artifact, so
 the goodput trajectory accumulates per commit).
-
-tools/serving_bench.py reuses `run_overload_comparison` for its
-FIFO-vs-SLO section.
 """
 
 from __future__ import annotations
@@ -238,6 +235,20 @@ def _drive_plan(plan, duration_s, submit_one):
 # -- model + stack -----------------------------------------------------------
 
 
+def export_model(fluid, path):
+    """Tiny MLP classifier; single-row requests make batching visible."""
+    main, startup = fluid.Program(), fluid.Program()
+    with fluid.program_guard(main, startup), fluid.unique_name.guard():
+        x = fluid.layers.data("x", [16])
+        h = fluid.layers.fc(x, 32, act="relu")
+        out = fluid.layers.fc(h, 10, act="softmax")
+    scope = fluid.Scope()
+    with fluid.scope_guard(scope):
+        exe = fluid.Executor(fluid.CPUPlace())
+        exe.run(startup)
+        fluid.io.save_inference_model(path, ["x"], [out], exe, main)
+
+
 def build_predict_stack(tmp_dir, max_batch=8, buckets=(1, 2, 4, 8)):
     """Tiny MLP predictor with batch bucketing, every bucket warmed
     (compiles outside any measured loop; warmup also populates the
@@ -246,7 +257,6 @@ def build_predict_stack(tmp_dir, max_batch=8, buckets=(1, 2, 4, 8)):
 
     import paddle_tpu as fluid
     from paddle_tpu.inference import Config, create_predictor
-    from serving_bench import export_model
 
     model_dir = os.path.join(tmp_dir, "mlp")
     export_model(fluid, model_dir)
