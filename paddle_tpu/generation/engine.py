@@ -58,18 +58,53 @@ does what modern LLM serving does instead:
   as every other reader or writer of the pools does: ``spill_run``
   from another thread reads the arrays of before a step or of after
   it. ``stats()["step_donated_bytes"]`` says that it engaged.
+* **One step ahead** (ragged mode). The loop keeps one step in
+  flight: an iteration is admit, grow, assemble, bind, dispatch of step
+  N+1 WHILE STEP N RUNS, and only then the wait for step N's tokens
+  and their emit. The jitted call's argument handling, the feeds'
+  transfers and all of the loop's Python happen under step N's device
+  time, and the device runs back to back. Nothing the host does
+  between two steps needs the value of the token just sampled except
+  to feed it back, so it stays on the device: a decode row's input is
+  merged in from the previous step's output (``_carry_tokens``, one
+  small jitted call ahead of the step; the step's own executable and
+  arguments are what they were). Known a step early: lengths,
+  positions, page growth (each row in flight counts:
+  ``_GenRequest.ahead``) and the finish by ``max_new`` or the position
+  window (the lane takes no row after the one that samples its last
+  token). Learned a step late: EOS, cancel, deadline; the row already
+  computed for such a sequence is dropped at its emit, never streamed,
+  and its K/V landed in pages the sequence still owned when the write
+  was enqueued (the device runs what was enqueued in order, so a page
+  freed at emit(N) and reused by step N+2 is written after). Whatever
+  needs every token on the host or no step running reads the step in
+  flight out first (``_drain_inflight``): speculative drafting (an
+  engine with a draft never runs ahead), a pool-dry eviction, a base
+  swap, the close, a step that raised. Never more than one step is
+  ahead. With a lane free, nothing queued and a step in flight the
+  loop waits for ``submit`` before it admits (``_hold_for_submit``:
+  admission at the last moment, while the device is busy anyway), so a
+  finished request's successor joins the next step and not the one
+  after by a race. Counters, beside the ``loop_*`` ones:
+  ``steps_dispatched_ahead_total``, ``device_carried_tokens_total``,
+  ``discarded_rows_total``, ``inflight_drains_<reason>_total``,
+  ``admit_holds_total`` / ``admit_hold_us_total``. ``two_lane`` runs
+  its serial loop as before.
 * **Loop phases** (ragged mode). One iteration of the step loop is
   partitioned, with nothing left between them, into ``wait`` (starved:
-  no queue, no live lane), ``admit`` (page-store consult, prefix
-  lookup, lane + page reservation), ``grow`` (retire dead rows, page
-  growth / eviction, the speculative budget, the adapter check),
-  ``draft`` (only with speculative rows), ``assemble`` (the numpy
-  batch, block tables, the feed dict: tokens, positions, tables, and
-  a hybrid model's recurrent state), ``bind``, ``step`` (the dispatch
-  with the pools donated, under the cache's pool lock, then the wait
-  for the tokens — ``generation/fetch``) and ``emit`` (advance, stop
-  conditions, the clients' ``on_token`` callbacks, trie publish and,
-  last, the release of what the step was fed).
+  no queue, no live lane), ``admit`` (the hold for a submitter,
+  page-store consult, prefix lookup, lane + page reservation), ``grow``
+  (retire dead rows, page growth / eviction, the speculative budget,
+  the adapter check), ``draft`` (only with speculative rows),
+  ``assemble`` (the numpy batch, block tables, the feed dict: tokens,
+  positions, tables, and a hybrid model's recurrent state), ``bind``,
+  ``step`` (the token merge and the dispatch of the new step with the
+  pools donated, under the cache's pool lock, then the wait for the
+  tokens of the step before it — ``generation/fetch``) and ``emit``
+  (of that earlier step: advance, stop conditions, the clients'
+  ``on_token`` callbacks, trie publish). What a step was fed is
+  released at its dispatch. The last step of a burst is read by an
+  iteration that dispatches nothing (grow, step, emit).
   Each is the range ``generation/<phase>`` on the
   profiler's clock — always on, so any attached profiler session reads
   the device's idle gaps in these terms — and, at the same boundary,
@@ -113,6 +148,7 @@ from __future__ import annotations
 
 import collections
 import contextlib
+import functools
 import threading
 import time
 from typing import Any, Dict, List, Optional, Sequence
@@ -136,6 +172,9 @@ _DONE = object()  # stream sentinel
 # them (module docstring, "Loop phases")
 LOOP_PHASES = ("wait", "admit", "grow", "draft", "assemble", "bind",
                "step", "emit")
+# why a step in flight was read and emitted before the loop went on
+# (module docstring, "One step ahead")
+DRAIN_REASONS = ("draft", "evict", "swap", "close", "error")
 
 
 class GenerationStream:
@@ -259,7 +298,7 @@ class _GenRequest:
     __slots__ = ("prompt", "orig_prompt", "max_new", "eos_id", "deadline",
                  "stream", "enqueue_t", "slot", "pending", "n_generated",
                  "ctx", "admit_seq", "last_tok_t", "prefill_off", "drafts",
-                 "tenant", "store_checked", "adapter")
+                 "tenant", "store_checked", "adapter", "ahead")
 
     def __init__(self, prompt, max_new, eos_id, deadline, stream, ctx,
                  tenant=None, adapter=None):
@@ -281,6 +320,15 @@ class _GenRequest:
         self.tenant = tenant            # traffic identity (trie quotas)
         self.store_checked = False      # page-store consult done once
         self.adapter = adapter          # resident LoRA adapter id (or None)
+        # positions the request's row in the step in flight writes:
+        # dispatched, not yet emitted (0: no such row)
+        self.ahead = 0
+
+    def token_in_flight(self) -> bool:
+        """The request's row in the step in flight samples a token of
+        its answer: a decode row, or its prompt's final chunk."""
+        return (self.ahead > 0 and
+                self.prefill_off + self.ahead >= int(self.prompt.size))
 
 
 class _Phase:
@@ -307,6 +355,39 @@ class _Phase:
         self.metrics.inc(self.counter,
                          (time.perf_counter_ns() - self.t0) // 1000)
         return False
+
+
+class _StepInFlight:
+    """A ragged step that was dispatched and whose tokens the host has
+    not read: ``rows`` are ``(slot, request, num_valid)`` as they were
+    assembled, ``tokens`` the device array ``[lanes * chunk]`` whose
+    copy to the host started at the dispatch, ``t0`` when that was."""
+
+    __slots__ = ("rows", "tokens", "t0")
+
+    def __init__(self, rows, tokens, t0):
+        self.rows, self.tokens, self.t0 = rows, tokens, t0
+
+
+def _carry_tokens(tokens, prev, col):
+    """``gen_tokens`` of a step: the host's ``[lanes, chunk]`` rows with
+    column 0 of every lane whose ``col`` is not -1 taken from the
+    previous step's output ``prev[lane, col]``: a decode row's input is
+    the token its sequence sampled a step before, which the host may not
+    have read yet."""
+    import jax.numpy as jnp
+
+    prev = prev.reshape(tokens.shape).astype(tokens.dtype)
+    carried = jnp.take_along_axis(prev, jnp.maximum(col, 0)[:, None], axis=1)
+    return tokens.at[:, :1].set(
+        jnp.where(col[:, None] >= 0, carried, tokens[:, :1]))
+
+
+@functools.lru_cache(maxsize=None)
+def _carry_fn():
+    import jax
+
+    return jax.jit(_carry_tokens)
 
 
 class GenerationMetrics:
@@ -339,7 +420,19 @@ class GenerationMetrics:
                  # the graph). The pairs that landed on held experts are
                  # counted on the device (stats(): moe_held_assignments_
                  # total, moe_expert_load_max / _mean)
-                 "moe_tokens_routed_total", "state_lane_resets_total"
+                 "moe_tokens_routed_total", "state_lane_resets_total",
+                 # ragged mode, one step ahead: steps dispatched before
+                 # their predecessor's tokens were read (over
+                 # ragged_steps_total: the share the overlap engaged
+                 # on), decode rows whose input token never left the
+                 # device, rows computed for a sequence that had ended
+                 # (EOS, cancel, deadline: never emitted), and the waits
+                 # for a submitter before an admission with a free lane
+                 "steps_dispatched_ahead_total",
+                 "device_carried_tokens_total", "discarded_rows_total",
+                 "admit_holds_total", "admit_hold_us_total"
+                 ) + tuple(f"inflight_drains_{r}_total"
+                           for r in DRAIN_REASONS
                  # ragged mode: wall microseconds of the loop thread by
                  # phase, counted where the generation/<phase> span
                  # closes (GenerationEngine._phase)
@@ -683,6 +776,20 @@ class GenerationEngine:
         self.model_version = str(model_version or "base")
         self.model_swaps = 0
         self._pending_swap = None
+        # one step ahead (module docstring): the step whose tokens are
+        # not read yet; the newest step's token array, from which the
+        # next step's decode rows take their input on the device; and
+        # what bounds the hold before an admission: when the last
+        # tokens arrived, the device's last step and the loop's own
+        # time from admit to dispatch
+        self._inflight: Optional[_StepInFlight] = None
+        self._run_ahead = True          # False: the serial order (tests)
+        self._prev_tokens = None
+        self._last_fetch_t = 0.0
+        self._step_s = 0.0
+        self._last_took = 0.0
+        self._host_s = 0.0
+        self._iter_t0 = 0.0
 
         self._cond = threading.Condition()
         self._queue: "collections.deque[_GenRequest]" = collections.deque()
@@ -905,7 +1012,9 @@ class GenerationEngine:
             if info["donation_skip_reason"]:
                 out["donation_skip_reason"] = info["donation_skip_reason"]
         if self._state_names:
-            # the one read of the on-device load counts: never in a step
+            # the one read of the on-device load counts: never in a step.
+            # With a step in flight it is that step's output, and the
+            # read waits for it (any thread may: nothing is drained)
             loads = np.asarray(self.cache.state["gen_state_moe_loads"],
                                np.int64)
             out["moe_held_assignments_total"] = int(loads.sum())
@@ -1033,7 +1142,8 @@ class GenerationEngine:
         try:
             while True:
                 with self._cond:
-                    if not self._queue and not self._by_slot:
+                    if (not self._queue and not self._by_slot
+                            and self._inflight is None):
                         # starved, not slow: nothing queued, no lane live
                         with self._phase("wait"):
                             while (not self._queue and not self._by_slot
@@ -1041,18 +1151,23 @@ class GenerationEngine:
                                    and self._pending_swap is None):
                                 self._cond.wait(0.05)
                     if self._stop or (self._closed and not self._queue
-                                      and not self._by_slot):
+                                      and not self._by_slot
+                                      and self._inflight is None):
                         break
                     swap, self._pending_swap = self._pending_swap, None
                 if swap is not None:
                     # the serving pointer flips BETWEEN steps, on the
-                    # loop thread: no in-flight batch ever reads a
-                    # half-swapped scope
+                    # loop thread, with none in flight: no batch ever
+                    # reads a half-swapped scope
+                    with self._phase("emit"):
+                        self._drain_inflight("swap")
                     self._apply_swap(*swap)
                 if self.mode == "ragged":
                     with self._phase("admit"):
+                        self._hold_for_submit()
+                        self._iter_t0 = time.monotonic()
                         self._admit_ragged()
-                    if self._by_slot:
+                    if self._by_slot or self._inflight is not None:
                         self._ragged_step()
                 else:
                     self._admit_and_prefill()
@@ -1068,6 +1183,12 @@ class GenerationEngine:
             with self._cond:
                 self._closed = True
                 swap, self._pending_swap = self._pending_swap, None
+            try:
+                # the tokens of a step in flight were computed: they go
+                # out before their streams are closed
+                self._drain_inflight("close")
+            except Exception:  # noqa: BLE001 — the streams below must still end
+                self._inflight = None
             if swap is not None:
                 # a swap staged against a closing engine still lands
                 # (scope outlives the loop) so its waiter never hangs
@@ -1080,6 +1201,36 @@ class GenerationEngine:
                     "engine closed mid-generation"))
             self._by_slot.clear()
             self.metrics.set_gauges(0, 0)
+
+    def _hold_for_submit(self) -> None:
+        """Admission at the last moment. With a lane free, nothing queued
+        and a step in flight, whether a finished request's successor
+        joins the next step is a race between its client's thread and
+        this loop. The device is busy with the step in flight, so the
+        loop can afford to wait for ``submit`` (which notifies
+        ``_cond``): until half of the device's last step has passed
+        since the step in flight began, and no longer than leaves twice
+        the loop's own time from admit to dispatch before it ends. With
+        a queue waiting it never waits."""
+        if self._inflight is None:
+            return
+        until = self._last_fetch_t + min(
+            0.5 * self._step_s, self._step_s - 2.0 * self._host_s)
+
+        def over():
+            return (self._queue or self._stop or self._closed
+                    or self._pending_swap is not None
+                    or time.monotonic() >= until)
+
+        with self._cond:
+            if over() or self.cache.free_slots() <= 0:
+                return
+            t0 = time.perf_counter_ns()
+            while not over():
+                self._cond.wait(max(until - time.monotonic(), 0.0))
+            self.metrics.inc("admit_holds_total")
+            self.metrics.inc("admit_hold_us_total",
+                             (time.perf_counter_ns() - t0) // 1000)
 
     def _fail_queued(self, err: BaseException):
         with self._cond:
@@ -1384,17 +1535,25 @@ class GenerationEngine:
                 self.metrics.inc("expired_total")
 
     def _grow_or_evict(self, slot: int) -> bool:
-        """Grow slot's page chain by one token; a dry pool evicts
-        (youngest first) and a truly stuck row finishes early
-        ("capacity"). False when the slot was retired. Shared eviction
-        policy for both engine modes."""
+        """Grow slot's page chain by one token past what is cached or in
+        flight; a dry pool evicts (youngest first) and a truly stuck row
+        finishes early ("capacity"). False when the slot was retired.
+        Shared eviction policy for both engine modes. An eviction needs
+        every token on the host (the victim resumes from prompt +
+        emitted), so a step in flight is drained first, and what it
+        retired may already be room enough."""
         while True:
+            req = self._by_slot[slot]
             try:
                 self.cache.ensure_capacity(
-                    slot, int(self.cache.lengths[slot]) + 1)
+                    slot, int(self.cache.lengths[slot]) + req.ahead + 1)
                 return True
             except PagePoolExhausted:
-                if not self._make_room(slot):
+                if self._inflight is not None:
+                    self._drain_inflight("evict")
+                    if self._by_slot.get(slot) is not req:
+                        return False
+                elif not self._make_room(slot):
                     self._retire(slot, "capacity")
                     return False
 
@@ -1410,11 +1569,26 @@ class GenerationEngine:
                           req.max_new - req.n_generated - 1,
                           self.config.max_position - L - 2))
 
+    def _last_token_in_flight(self, req: _GenRequest) -> bool:
+        """The finish by length is known before its token is: a row in
+        flight samples this request's last token (``max_new``, or the
+        position window), so the lane takes no further row and retires
+        when that token is emitted. (EOS, cancel and deadline are
+        learned at the emit: a row computed past them is discarded.)"""
+        return req.token_in_flight() and (
+            req.n_generated + 1 >= req.max_new
+            or (int(self.cache.lengths[req.slot]) + req.ahead + 1
+                >= self.config.max_position))
+
     def _ragged_step(self):
         """ONE mixed executable run: every active lane contributes
         whatever its sequence needs this step — a prefill chunk, a
         decode token, or a decode token plus speculative drafts — and
-        the whole batch attends raggedly over the shared page pool."""
+        the whole batch attends raggedly over the shared page pool.
+
+        The step is dispatched while its predecessor still runs, and
+        the predecessor's tokens are read and emitted after that
+        (module docstring, "One step ahead")."""
         R, C = self.lanes, self.chunk_tokens
         with self._phase("grow"):
             self._retire_dead_rows(time.monotonic())
@@ -1424,9 +1598,11 @@ class GenerationEngine:
             # (youngest first), then finishes the stuck row early.
             spec_rows: List = []
             for slot, req in list(self._by_slot.items()):
-                if slot not in self._by_slot:
+                if self._by_slot.get(slot) is not req:
                     continue
-                if req.prefill_off < int(req.prompt.size):
+                if self._last_token_in_flight(req):
+                    continue
+                if req.prefill_off + req.ahead < int(req.prompt.size):
                     continue
                 req.drafts = None
                 k = self._spec_budget(slot, req)
@@ -1439,8 +1615,6 @@ class GenerationEngine:
                     except PagePoolExhausted:
                         pass
                 self._grow_or_evict(slot)
-            if not self._by_slot:
-                return
             if self.adapter_store is not None:
                 # a force-evicted adapter fails ITS rows here, before
                 # they cost a step — never the whole batch
@@ -1453,8 +1627,6 @@ class GenerationEngine:
                         self.adapter_store.slots_row(req.adapter)
                     except AdapterMissing as e:
                         self._retire(slot, "error", ServingError(str(e)))
-                if not self._by_slot:
-                    return
             spec_rows = [(s, r, k) for s, r, k in spec_rows
                          if s in self._by_slot]
         if spec_rows:
@@ -1477,149 +1649,264 @@ class GenerationEngine:
                     dr = np.asarray(dr, np.int64).reshape(-1)[:k]
                     req.drafts = dr
                     self.metrics.inc("spec_proposed_total", int(dr.size))
-        with self._phase("assemble"):
-            tokens = np.zeros((R, C), np.int64)
-            pos_ids = np.zeros((R, C), np.int64)
-            positions = np.zeros(R, np.int64)
-            num_valid = np.zeros(R, np.int32)
-            for slot, req in self._by_slot.items():
-                if req.prefill_off < int(req.prompt.size):
-                    off = req.prefill_off
-                    c = min(C, int(req.prompt.size) - off)
-                    tokens[slot, :c] = req.prompt[off:off + c]
-                    pos_ids[slot, :c] = np.arange(off, off + c)
-                    positions[slot] = off
-                    num_valid[slot] = c
-                else:
-                    dr = (req.drafts if req.drafts is not None
-                          else np.zeros(0, np.int64))
-                    row = np.concatenate(
-                        [np.asarray([req.pending], np.int64), dr])
-                    L0 = int(self.cache.lengths[slot])
-                    tokens[slot, :row.size] = row
-                    pos_ids[slot, :row.size] = np.arange(L0, L0 + row.size)
-                    positions[slot] = L0
-                    num_valid[slot] = row.size
-            live = positions + num_valid
-            self.metrics.inc("attn_live_pages_total", int(
-                (-(-live[num_valid > 0] // self.geom.page_size)).sum()))
-            self.metrics.inc("attn_table_pages_total",
-                             R * self.geom.max_pages_per_seq)
-            feed = {
-                "gen_tokens": tokens,
-                "gen_pos_ids": pos_ids,
-                "gen_positions": positions,
-                "gen_num_valid": num_valid,
-                "gen_block_tables": np.ascontiguousarray(
-                    self.cache.block_tables),
-            }
-            if self.adapter_store is not None:
-                # per-row adapter slots, fed exactly like a block
-                # table: zeros = the reserved zero adapter (base-only
-                # rows / idle lanes), so the base path is identity by
-                # construction
-                aslots = np.zeros((R, self.adapter_store.n_buckets),
-                                  np.int32)
-                for slot, req in self._by_slot.items():
-                    if req.adapter is not None:
-                        aslots[slot] = self.adapter_store.slots_row(
-                            req.adapter)
-                feed["gen_adapter_slots"] = aslots
-            if self._state_names:
-                # recurrent layers: the per-lane state is fed and fetched,
-                # rewritten whole (the page pools are the step's state)
-                feed.update(self.cache.state)
-                self.metrics.inc("moe_tokens_routed_total",
-                                 int(num_valid.sum()) * self.config.num_layers)
-        with self._phase("bind"):
-            bound = self._bind_ragged(feed)
-            active = list(self._by_slot.items())
-            bound.rows_hint = len(active)
+        rows = [(slot, req) for slot, req in self._by_slot.items()
+                if not self._last_token_in_flight(req)]
+        if not rows and self._inflight is None:
+            return
+        if rows:
+            with self._phase("assemble"):
+                feed, rows, carry = self._assemble(rows)
+            with self._phase("bind"):
+                bound = self._bind_ragged(feed)
+                bound.rows_hint = len(rows)
 
         def step_args():
-            flow = [r.ctx.span_id for _, r in active if r.ctx is not None]
-            return {"n": len(active), "lanes": R, "chunk": C,
-                    "new_tokens": int(num_valid.sum()),
+            flow = [r.ctx.span_id for _, r, _ in rows if r.ctx is not None]
+            return {"n": len(rows), "lanes": R, "chunk": C,
+                    "new_tokens": sum(nv for _, _, nv in rows),
                     **({"flow_from": flow} if flow else {})}
 
         with self._phase("step", step_args):
-            t0 = time.monotonic()
-            try:
-                outs = self._dispatch(bound, feed)
-            except Exception as e:  # noqa: BLE001 — a bad batch must not kill the loop
-                for slot, req in active:
-                    self._retire(slot, "error", ServingError(
-                        f"ragged step execution failed: {e!r}"))
-                self._recover_pools()
-                return
+            due = []
+            if rows:
+                try:
+                    step = self._dispatch_ahead(bound, feed, rows, carry)
+                except Exception as e:  # noqa: BLE001 — a bad batch must not kill the loop
+                    self._fail_step(rows, e)
+                    return
+                del feed
+                if self._inflight is not None:
+                    due.append(self._inflight)
+                self._inflight = step
+                if not self._run_ahead or self.spec_tokens > 0:
+                    # a proposal needs the context's last token: an
+                    # engine with a draft never runs ahead
+                    if self.spec_tokens > 0:
+                        self.metrics.inc("inflight_drains_draft_total")
+                    due.append(step)
+                    self._inflight = None
+            else:
+                due.append(self._inflight)
+                self._inflight = None
             # where the loop waits for the device
+            fetched = [(st, self._fetch(st)) for st in due]
+        if fetched:
+            with self._phase("emit"):
+                for st, tokens in fetched:
+                    self._emit_step(st, tokens)
+
+    def _assemble(self, lanes):
+        """The numpy batch of one step over ``lanes`` (slot, request):
+        returns the feed dict, the rows as ``_StepInFlight`` keeps them
+        and, per lane, the column of the step in flight whose token the
+        row starts from (-1: the host's ``gen_tokens`` hold it)."""
+        R, C = self.lanes, self.chunk_tokens
+        tokens = np.zeros((R, C), np.int64)
+        pos_ids = np.zeros((R, C), np.int64)
+        positions = np.zeros(R, np.int64)
+        num_valid = np.zeros(R, np.int32)
+        carry = np.full(R, -1, np.int32)
+        rows = []
+        for slot, req in lanes:
+            off = req.prefill_off + req.ahead
+            if off < int(req.prompt.size):
+                c = min(C, int(req.prompt.size) - off)
+                tokens[slot, :c] = req.prompt[off:off + c]
+                pos_ids[slot, :c] = np.arange(off, off + c)
+                positions[slot] = off
+                num_valid[slot] = c
+                rows.append((slot, req, c))
+                continue
+            dr = (req.drafts if req.drafts is not None
+                  else np.zeros(0, np.int64))
+            L0 = int(self.cache.lengths[slot]) + req.ahead
+            if req.token_in_flight():
+                # the token this row starts from is the last valid
+                # column of the request's row in the step in flight
+                carry[slot] = req.ahead - 1
+            else:
+                tokens[slot, 0] = req.pending
+            tokens[slot, 1:1 + dr.size] = dr
+            pos_ids[slot, :1 + dr.size] = np.arange(L0, L0 + 1 + dr.size)
+            positions[slot] = L0
+            num_valid[slot] = 1 + dr.size
+            rows.append((slot, req, 1 + int(dr.size)))
+        live = positions + num_valid
+        self.metrics.inc("attn_live_pages_total", int(
+            (-(-live[num_valid > 0] // self.geom.page_size)).sum()))
+        self.metrics.inc("attn_table_pages_total",
+                         R * self.geom.max_pages_per_seq)
+        feed = {
+            "gen_tokens": tokens,
+            "gen_pos_ids": pos_ids,
+            "gen_positions": positions,
+            "gen_num_valid": num_valid,
+            "gen_block_tables": np.ascontiguousarray(
+                self.cache.block_tables),
+        }
+        if self.adapter_store is not None:
+            # per-row adapter slots, fed exactly like a block
+            # table: zeros = the reserved zero adapter (base-only
+            # rows / idle lanes), so the base path is identity by
+            # construction
+            aslots = np.zeros((R, self.adapter_store.n_buckets), np.int32)
+            for slot, req in lanes:
+                if req.adapter is not None:
+                    aslots[slot] = self.adapter_store.slots_row(req.adapter)
+            feed["gen_adapter_slots"] = aslots
+        if self._state_names:
+            # recurrent layers: the per-lane state is fed and fetched,
+            # rewritten whole (the page pools are the step's state)
+            feed.update(self.cache.state)
+            self.metrics.inc("moe_tokens_routed_total",
+                             int(num_valid.sum()) * self.config.num_layers)
+        return feed, rows, carry
+
+    def _dispatch_ahead(self, bound, feed, rows, carry) -> _StepInFlight:
+        """Enqueue one step behind whatever the device is running and
+        return without waiting for it. The decode rows' input tokens
+        are merged in on the device from the newest step's output (one
+        small jitted call, so the step's own executable and arguments
+        are what they were); the copy of the new tokens to the host
+        starts here, and a hybrid model's new state replaces the old in
+        the cache at once, so the host keeps no reference to what the
+        step was fed."""
+        t0 = time.monotonic()
+        if self._prev_tokens is None:
+            import jax.numpy as jnp
+
+            # before the engine's first step: nothing to carry from
+            self._prev_tokens = jnp.zeros(
+                self.lanes * self.chunk_tokens, jnp.int32)
+        feed["gen_tokens"] = _carry_fn()(
+            feed["gen_tokens"], self._prev_tokens, carry)
+        outs = self._dispatch(bound, feed)
+        self._prev_tokens = outs[0]
+        if hasattr(outs[0], "copy_to_host_async"):
+            outs[0].copy_to_host_async()
+        if self._state_names:
+            self.cache.set_state(outs[1:])
+        for _, req, nv in rows:
+            req.ahead += nv
+        if self._inflight is not None:
+            self.metrics.inc("steps_dispatched_ahead_total")
+        self.metrics.inc("device_carried_tokens_total",
+                         int((carry >= 0).sum()))
+        self._host_s = time.monotonic() - self._iter_t0
+        return _StepInFlight(rows, outs[0], t0)
+
+    def _fail_step(self, rows, e: BaseException) -> None:
+        """A step raised, at its dispatch or when its tokens were read:
+        its sequences fail; a step still in flight is read out (rows of
+        the failed sequences are discarded there), and the pools, the
+        carried tokens and a hybrid model's state start afresh if the
+        device took them with it."""
+        for slot, req, _ in rows:
+            if self._by_slot.get(slot) is req:
+                self._retire(slot, "error", ServingError(
+                    f"ragged step execution failed: {e!r}"))
+        self._drain_inflight("error")
+        self._prev_tokens = None
+        if self._state_names:
+            self.cache.reset_state()
+        self._recover_pools()
+
+    def _fetch(self, step: _StepInFlight):
+        """Wait for a dispatched step's tokens ``[lanes, chunk]``; None
+        when the step failed on the device (its sequences are failed
+        here)."""
+        try:
             with tracing.annotation("generation/fetch"):
-                next_all = np.asarray(outs[0]).reshape(R, C)
-            if self._state_names:
-                self.cache.set_state(outs[1:])
-        with self._phase("emit"):
-            now = time.monotonic()
-            self.metrics.inc("ragged_steps_total")
-            emitted_total = 0
-            for slot, req in active:
-                if slot not in self._by_slot:
-                    continue
-                nv = int(num_valid[slot])
-                if nv <= 0:
-                    continue
-                if req.prefill_off < int(req.prompt.size):
-                    # a prefill chunk: its K/V is cached now; the FINAL
-                    # chunk additionally samples the first token
-                    # (TTFT). Publish BEFORE _emit: a request retiring
-                    # on its very first token must still leave its
-                    # prompt pages in the trie for the siblings behind
-                    # it.
-                    self.cache.advance(slot, nv)
-                    req.prefill_off += nv
-                    self.metrics.inc("prefill_chunks_total")
-                    self.metrics.inc("prefill_tokens_total", nv)
-                    if self.prefix_cache:
-                        self.cache.publish(slot, req.prompt,
-                                           tenant=req.tenant)
-                    if req.prefill_off >= int(req.prompt.size):
-                        self.metrics.inc("prefill_batches_total")
-                        self._emit(req, int(next_all[slot, nv - 1]), now)
-                        emitted_total += 1
-                else:
-                    # decode / speculative verify: next_all[slot, j] IS
-                    # the greedy token after position start+j, so draft
-                    # j is accepted iff it equals the target's token at
-                    # its own offset — the emitted stream is
-                    # greedy-identical by construction, whatever the
-                    # draft proposed
-                    dr = req.drafts if req.drafts is not None else ()
-                    for j in range(nv):
-                        if j > 0:
-                            if int(dr[j - 1]) != int(next_all[slot, j - 1]):
-                                break       # rejected: the tail is dead
-                            self.metrics.inc("spec_accepted_total")
-                            req.stream.accepted_draft_tokens += 1
-                        self.cache.advance(slot)
-                        emitted_total += 1
-                        self._emit(req, int(next_all[slot, j]), now)
-                        if slot not in self._by_slot:
-                            break       # retired (eos/length/deadline)
-                    if self.prefix_cache and slot in self._by_slot:
-                        # decode-produced full pages join the trie too:
-                        # only positions < length publish, and rejected
-                        # drafts live strictly at positions >= length
-                        self.cache.publish(slot, np.concatenate(
-                            [req.orig_prompt,
-                             np.asarray(req.stream._tokens, np.int64)]),
-                            tenant=req.tenant)
-            n_active = sum(1 for s, _ in active if num_valid[s] > 0)
-            self.metrics.observe_decode_step(
-                (now - t0) * 1e3, n_active, R, tokens=emitted_total)
-            # the last references to what the step was fed (a hybrid
-            # model's recurrent state of before the step; the pools are
-            # not fed) go here, inside the phase and AFTER the clients'
-            # callbacks, where they went when the frame died
-            del feed, outs
+                return np.asarray(step.tokens).reshape(
+                    self.lanes, self.chunk_tokens)
+        except Exception as e:  # noqa: BLE001 — a bad batch must not kill the loop
+            self._fail_step(step.rows, e)
+            return None
+
+    def _drain_inflight(self, reason: str) -> None:
+        """Read and emit the step in flight, if any, before something
+        that needs every token on the host or no step running: an
+        eviction, a base swap, the close, a failed step (and, each step,
+        an engine with a draft). Runs inside the caller's phase."""
+        step, self._inflight = self._inflight, None
+        if step is None:
+            return
+        self.metrics.inc(f"inflight_drains_{reason}_total")
+        self._emit_step(step, self._fetch(step))
+
+    def _emit_step(self, step: _StepInFlight, next_all) -> None:
+        """The host's half of a step whose tokens have arrived: advance,
+        stop conditions, the clients' ``on_token`` callbacks, trie
+        publish. A row whose sequence has ended since the dispatch (EOS
+        a step earlier, cancel, deadline) is dropped, never emitted."""
+        if next_all is None:
+            return
+        now = time.monotonic()
+        self.metrics.inc("ragged_steps_total")
+        emitted_total = n_active = 0
+        for slot, req, nv in step.rows:
+            n_active += 1
+            if self._by_slot.get(slot) is not req:
+                self.metrics.inc("discarded_rows_total")
+                continue
+            req.ahead -= nv
+            if req.prefill_off < int(req.prompt.size):
+                # a prefill chunk: its K/V is cached now; the FINAL
+                # chunk additionally samples the first token
+                # (TTFT). Publish BEFORE _emit: a request retiring
+                # on its very first token must still leave its
+                # prompt pages in the trie for the siblings behind
+                # it.
+                self.cache.advance(slot, nv)
+                req.prefill_off += nv
+                self.metrics.inc("prefill_chunks_total")
+                self.metrics.inc("prefill_tokens_total", nv)
+                if self.prefix_cache:
+                    self.cache.publish(slot, req.prompt,
+                                       tenant=req.tenant)
+                if req.prefill_off >= int(req.prompt.size):
+                    self.metrics.inc("prefill_batches_total")
+                    self._emit(req, int(next_all[slot, nv - 1]), now)
+                    emitted_total += 1
+            else:
+                # decode / speculative verify: next_all[slot, j] IS
+                # the greedy token after position start+j, so draft
+                # j is accepted iff it equals the target's token at
+                # its own offset — the emitted stream is
+                # greedy-identical by construction, whatever the
+                # draft proposed
+                dr = req.drafts if req.drafts is not None else ()
+                for j in range(nv):
+                    if j > 0:
+                        if int(dr[j - 1]) != int(next_all[slot, j - 1]):
+                            break       # rejected: the tail is dead
+                        self.metrics.inc("spec_accepted_total")
+                        req.stream.accepted_draft_tokens += 1
+                    self.cache.advance(slot)
+                    emitted_total += 1
+                    self._emit(req, int(next_all[slot, j]), now)
+                    if slot not in self._by_slot:
+                        break       # retired (eos/length/deadline)
+                if self.prefix_cache and self._by_slot.get(slot) is req:
+                    # decode-produced full pages join the trie too:
+                    # only positions < length publish, and rejected
+                    # drafts live strictly at positions >= length
+                    self.cache.publish(slot, np.concatenate(
+                        [req.orig_prompt,
+                         np.asarray(req.stream._tokens, np.int64)]),
+                        tenant=req.tenant)
+        # a step dispatched behind another took the device from when
+        # the other's tokens arrived, not from its own dispatch
+        began = max(step.t0, self._last_fetch_t)
+        self.metrics.observe_decode_step(
+            (now - began) * 1e3, n_active, self.lanes, tokens=emitted_total)
+        if step.t0 < self._last_fetch_t:
+            # the device's step, as the hold takes it: the shorter of the
+            # last two, so that one slow step (a compile, a pause) bounds
+            # no wait
+            self._step_s = min(now - began, self._last_took)
+            self._last_took = now - began
+        self._last_fetch_t = now
 
     # -- decode lane ---------------------------------------------------------
     def _bind_decode(self, feed):
@@ -1777,8 +2064,9 @@ class GenerationEngine:
     def _warmup(self):
         """Compile every executable before serving traffic, so no
         request ever pays an XLA compile mid-generation. Ragged mode
-        has exactly ONE executable to warm (a two-token request driven
-        through prefill-chunk + decode phases of the same program);
+        has exactly ONE executable to warm (a two-token prompt driven
+        through prefill-chunk + decode phases of the same program, a
+        step ahead as the loop runs it);
         two_lane warms the whole prefill-bucket ladder + decode."""
         if self.mode == "ragged":
             if self.spec_tokens > 0 and hasattr(self._draft, "warmup"):
@@ -1786,16 +2074,21 @@ class GenerationEngine:
                 # the no-compile-mid-generation contract too
                 self._draft.warmup(self.spec_tokens)
             slot = self.cache.allocate_slot(2)
-            req = _GenRequest(np.asarray([0, 0], np.int64), 1, None,
-                              None, GenerationStream(self), None)
+            # three tokens: the prompt's chunk, then decode rows whose
+            # input is carried on the device from the step before, so
+            # every shape the loop will dispatch has run once
+            req = _GenRequest(np.asarray([0, 0], np.int64),
+                              max(1, min(3, self.config.max_position - 2)),
+                              None, None, GenerationStream(self), None)
             req.slot = slot
             self._by_slot[slot] = req
             try:
-                for _ in range(4):
+                for _ in range(8):
                     if slot not in self._by_slot:
                         break
                     self._ragged_step()
             finally:
+                self._drain_inflight("close")
                 if slot in self._by_slot:
                     self._retire(slot, "length")
                 elif self.cache.is_active(slot):
@@ -1813,6 +2106,8 @@ class GenerationEngine:
                 # nor count in the experts' loads
                 self.cache.reset_state()
             self.metrics.__init__()
+            # nor its compiling steps bound a hold
+            self._step_s = self._last_took = 0.0
             return
         for bucket in self._seq_buckets:
             slot = self.cache.allocate_slot(2)

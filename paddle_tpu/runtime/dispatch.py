@@ -409,7 +409,7 @@ class BoundStep:
         "executor", "compiled", "scope", "block", "base_key",
         "feed_plan", "state_vals", "written_into_state", "scope_gen",
         "n_fetch", "benchmark", "obs_tel", "rows_hint",
-        "host_sync_calls", "feed_avals", "__weakref__",
+        "host_sync_calls", "feed_avals", "feed_shardings", "__weakref__",
     )
 
     def __init__(self, executor, compiled, scope, block, raw_dtypes,
@@ -421,6 +421,10 @@ class BoundStep:
         # the normalized feed signature this step was bound for, in
         # compiled.feed_names order (aot_compiled lowers against it)
         self.feed_avals = feed_avals
+        # the shardings of the committed jax.Arrays among the feeds of
+        # the last run (None for every other feed): jit specialises on
+        # them, so aot_compiled lowers against them
+        self.feed_shardings: Optional[List[Any]] = None
         self.scope = scope
         self.block = block
         self.benchmark = bool(flag("benchmark"))
@@ -556,6 +560,9 @@ class BoundStep:
         if entry_gen != self.scope_gen:
             self._resolve_state()
             entry_gen = self.scope_gen
+        self.feed_shardings = [
+            v.sharding if getattr(v, "committed", False) else None
+            for v in ordered]
         ex = self.executor
         ex._run_counter += 1
         compiled = self.compiled
@@ -801,13 +808,20 @@ class BoundStep:
         device runs, ``.memory_analysis()``/``.cost_analysis()`` the
         target's own accounting. jax exposes these only on an
         AOT-compiled object, not on the jit path, so this costs one
-        lower + compile — with the persistent compilation cache on, a
-        deserialization of what the first step compiled."""
+        lower + compile — jax's own answer from memory when the step
+        has run: a feed that last arrived as a committed jax.Array (the
+        output of another computation) is lowered with its sharding,
+        which is what jit specialised on."""
+        import jax
+
         if self.scope_gen != scope_chain_generation(self.scope):
             self._resolve_state()
+        avals = [a if s is None
+                 else jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=s)
+                 for a, s in zip(self.feed_avals, self.feed_shardings
+                                 or [None] * len(self.feed_avals))]
         return self.compiled.fn.lower(
-            self.base_key, np.int32(0), *self.feed_avals,
-            *self.state_vals).compile()
+            self.base_key, np.int32(0), *avals, *self.state_vals).compile()
 
     def donation_aliases(self):
         """``hlo_donation_aliases`` of this step's optimized HLO (one

@@ -24,8 +24,11 @@ CFG = GPTConfig(vocab_size=97, hidden_size=32, num_layers=2, num_heads=4,
 SEQ = 48
 PHASES = ["generation/" + p for p in LOOP_PHASES]
 COUNTERS = [f"loop_{p}_us_total" for p in LOOP_PHASES]
-# what one iteration of the loop looks like, by the phases' initials
-ITERATION = re.compile(r"(w?a(g(d?)mbse)?)+")
+# what one iteration of the loop looks like, by the phases' initials: a
+# step is dispatched (assemble, bind, step) before its predecessor's
+# tokens are emitted, and the last step of a burst is read by an
+# iteration that dispatches nothing
+ITERATION = re.compile(r"(w?a(gd?(mb)?se?)?)+")
 INITIAL = {"generation/wait": "w", "generation/admit": "a",
            "generation/grow": "g", "generation/draft": "d",
            "generation/assemble": "m", "generation/bind": "b",
@@ -93,14 +96,17 @@ def test_phase_spans_without_the_flag(predictor):
     events, steps = _run_traced(predictor)
     names = [e["name"] for e in events]
     assert steps >= 8
-    assert names.count("generation/step") == steps
+    # one step phase a dispatched step, and one more a request: the
+    # iteration that only waits for the last step's tokens
+    assert names.count("generation/step") == steps + 2
+    assert names.count("generation/bind") == steps
     assert set(PHASES) - {"generation/draft"} <= set(names)
     assert "generation/draft" not in names       # no draft model
     assert not [n for n in names if "[" in n]
     assert "generation/submit" not in names      # request tracing: flag only
     loop = _loop_events(events)
-    # in order: every iteration is [wait] admit [grow [draft] assemble
-    # bind step emit]
+    # in order: every iteration is [wait] admit [grow [draft] [assemble
+    # bind] step [emit]]
     assert ITERATION.fullmatch("".join(INITIAL[e["name"]] for e in loop))
     # and one at a time (time.time() pairs: allow a microsecond)
     for a, b in zip(loop, loop[1:]):
@@ -176,9 +182,12 @@ def test_flag_on_keeps_parentage_and_flow(predictor):
     assert not [n for n in by_name if "[" in n]
     (submit,) = by_name["generation/submit"]
     steps = by_name["generation/step"]
-    # every step that carried the request points back at its submit span
-    assert all(submit["span_id"] in s["flow_from"] for s in steps)
-    assert all(s["n"] == 1 and s["new_tokens"] >= 1 for s in steps)
+    # every step that carried the request points back at its submit
+    # span; the last iteration dispatches nothing and only reads the
+    # step in flight
+    assert [s["n"] for s in steps] == [1] * (len(steps) - 1) + [0]
+    assert all(submit["span_id"] in s["flow_from"] for s in steps[:-1])
+    assert all(s["new_tokens"] >= 1 for s in steps[:-1])
     # the jitted call is the step span's child, in its trace
     step_ids = {s["span_id"]: s["trace_id"] for s in steps}
     assert by_name["executor/step"]

@@ -449,3 +449,46 @@ def test_serving_request_spans_flow_into_batch_execute(obs_flags):
     # the jit step under the worker joined the same trace
     step = by_name.get("executor/step")
     assert step is not None and step["trace_id"] == submit["trace_id"]
+
+
+# -- the generation loop's run-ahead counters ---------------------------------
+
+
+def test_generation_overlap_counters_in_stats_and_scrape(tmp_path):
+    """What the ragged loop counts about running one step ahead of the
+    device is in ``engine.stats()`` and in the one scrape, as
+    ``paddle_generation_*`` beside the ``loop_*`` phase counters."""
+    from paddle_tpu.generation import GenerationEngine
+    from paddle_tpu.generation.engine import DRAIN_REASONS
+    from paddle_tpu.generation.model import GPTConfig, build_lm_program
+    from paddle_tpu.inference import Config, create_predictor
+
+    cfg = GPTConfig(vocab_size=97, hidden_size=32, num_layers=2, num_heads=4,
+                    ffn_size=64, max_position=64, hidden_dropout=0.0,
+                    attention_dropout=0.0)
+    main, startup, _feeds, fetches = build_lm_program(cfg, 48)
+    with fluid.scope_guard(fluid.Scope()):
+        exe = fluid.Executor(fluid.TPUPlace())
+        exe.run(startup)
+        fluid.io.save_inference_model(str(tmp_path), ["tokens"],
+                                      [fetches["logits"]], exe, main)
+    names = (["steps_dispatched_ahead_total", "device_carried_tokens_total",
+              "discarded_rows_total", "admit_holds_total",
+              "admit_hold_us_total"]
+             + [f"inflight_drains_{r}_total" for r in DRAIN_REASONS])
+    with GenerationEngine(create_predictor(Config(str(tmp_path))), cfg,
+                          page_size=4, num_pages=32, max_decode_batch=2,
+                          chunk_tokens=6) as eng:
+        eng.generate(np.asarray([5, 9, 2, 7], np.int64), max_new_tokens=8,
+                     eos_id=None, timeout=300)
+        stats = eng.stats()
+        text = observability.to_prometheus_text()
+    for name in names + ["loop_step_us_total", "loop_emit_us_total"]:
+        assert name in stats, name
+        assert f"paddle_generation_{name}{{" in text, name
+    # seven of the eight steps were dispatched behind their predecessor,
+    # each with its decode token still on the device
+    assert stats["ragged_steps_total"] == 8
+    assert stats["steps_dispatched_ahead_total"] == 7
+    assert stats["device_carried_tokens_total"] == 7
+    assert stats["discarded_rows_total"] == 0
