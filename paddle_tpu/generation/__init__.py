@@ -41,11 +41,11 @@ reference on CPU CI).
 
 from .draft import DraftModel, HostDraft
 from .engine import GenerationEngine, GenerationMetrics, GenerationStream
-from .kvcache import PagedKVCache, PagePoolExhausted
-from .model import (CacheGeometry, GPTConfig, HybridConfig,
+from .kvcache import PagedKVCache, PagePoolExhausted, WindowKind
+from .model import (CacheGeometry, GPTConfig, HybridConfig, MiMoConfig,
                     build_decode_program, build_hybrid_step_program,
-                    build_lm_program, build_prefill_program,
-                    build_ragged_step_program)
+                    build_lm_program, build_mimo_step_program,
+                    build_prefill_program, build_ragged_step_program)
 
 __all__ = [
     "GenerationEngine",
@@ -63,4 +63,7 @@ __all__ = [
     "build_ragged_step_program",
     "HybridConfig",
     "build_hybrid_step_program",
+    "MiMoConfig",
+    "build_mimo_step_program",
+    "WindowKind",
 ]

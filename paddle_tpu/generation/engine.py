@@ -159,9 +159,11 @@ from ..observability import tracing
 from ..serving.engine import (DeadlineExceeded, EngineClosed, Overloaded,
                               RequestCancelled, ServingError)
 from ..serving.metrics import StreamingHistogram
-from .kvcache import PagedKVCache, PagePoolExhausted
-from .model import (CacheGeometry, HybridConfig, build_decode_program,
-                    build_hybrid_step_program, build_prefill_program,
+from .kvcache import (PagedKVCache, PagePoolExhausted, WindowKind,
+                      window_ring_pages)
+from .model import (CacheGeometry, HybridConfig, MiMoConfig,
+                    build_decode_program, build_hybrid_step_program,
+                    build_mimo_step_program, build_prefill_program,
                     build_ragged_step_program)
 
 __all__ = ["GenerationEngine", "GenerationStream", "GenerationMetrics"]
@@ -414,6 +416,10 @@ class GenerationMetrics:
                  # attention kernel walks) against the width of their
                  # block tables (lanes x max_pages_per_seq)
                  "attn_live_pages_total", "attn_table_pages_total",
+                 # the same walk by kind of attention layer (a model
+                 # with window layers: the pages that overlap the window)
+                 "attn_live_pages_full_total",
+                 "attn_live_pages_window_total",
                  # recurrent layers and experts in the step (a hybrid
                  # config): valid tokens x expert layers, and lanes that
                  # took a new sequence (its state restarts from zero in
@@ -631,27 +637,45 @@ class GenerationEngine:
         self._seq_buckets = tuple(sorted(
             {min(b, max_seq) for b in prefill_buckets} | {max_seq}))
         maxp = -(-max_seq // self.page_size)
-        self.geom = CacheGeometry(num_pages=self.num_pages,
-                                  page_size=self.page_size,
-                                  max_pages_per_seq=maxp)
         # what the model is, decided here once: a hybrid config (a list
         # of attention and recurrent layers, models/hybrid.py) pages its
         # attention layers only and carries a per-lane recurrent state
-        # beside the pools; the step loop only ever sees how many pooled
-        # layers there are and which extra arrays ride the step
+        # beside the pools; a MiMo config (models/mimo.py) pages two
+        # kinds of attention layer, full and window, in pools of their
+        # own; the step loop only ever sees how many pooled layers there
+        # are, which extra arrays ride the step, and whether the cache
+        # has window pages to turn over
         hybrid = isinstance(config, HybridConfig)
+        mimo = isinstance(config, MiMoConfig)
         self._kv_layers = (len(config.attention_layers) if hybrid
+                           else len(config.layers_of("full")) if mimo
                            else config.num_layers)
+        self._expert_layers = (config.num_layers if hybrid
+                               else len(config.expert_layers) if mimo else 0)
         self._state_names: tuple = ()
-        state = None
-        if hybrid:
-            self._refuse_for_recurrent_state(page_store)
+        state = window = None
+        if hybrid or mimo:
+            refuse = (self._refuse_for_recurrent_state if hybrid
+                      else self._refuse_for_window_layers)
+            refuse(page_store)
             state = config.state_shapes(self.lanes)
             self._state_names = tuple(state)
+        if mimo and config.layers_of("window"):
+            window = WindowKind(
+                len(config.layers_of("window")), config.window_kv_heads,
+                config.window, window_ring_pages(
+                    config.window, self.chunk_tokens, self.page_size))
+        self.geom = CacheGeometry(
+            num_pages=self.num_pages, page_size=self.page_size,
+            max_pages_per_seq=maxp,
+            window_num_pages=(self.lanes * window.pages_per_seq + 1
+                              if window else 0),
+            window_pages_per_seq=window.pages_per_seq if window else 0)
         self.cache = PagedKVCache(
             self._kv_layers,
-            config.num_kv_heads if hybrid else config.num_heads,
-            config.hidden_size // config.num_heads,
+            config.num_heads if not (hybrid or mimo) else config.num_kv_heads,
+            config.v_dim if mimo else config.hidden_size // config.num_heads,
+            k_dim=config.k_dim if mimo else None, window=window,
             num_pages=self.num_pages, page_size=self.page_size,
             max_seqs=self.lanes, max_pages_per_seq=maxp,
             dtype=self.kv_dtype, state=state, scope=self._step_scope,
@@ -689,6 +713,7 @@ class GenerationEngine:
             # THE executable: one mixed prefill+decode program for the
             # engine's whole life, one BoundStep per step
             build = (build_hybrid_step_program if hybrid
+                     else build_mimo_step_program if mimo
                      else build_ragged_step_program)
             self._ragged_prog, self._ragged_fetches = build(
                 config, self.geom, self.chunk_tokens, self.kv_dtype)
@@ -831,6 +856,36 @@ class GenerationEngine:
             raise ValueError(
                 "quantize_weights with a hybrid config: the rewrite knows "
                 "mul ops only, not the stored-type products of its layers")
+
+    def _refuse_for_window_layers(self, page_store):
+        """A model with window layers keeps, of those layers, only the
+        pages a query can still reach: what counts on every page of a
+        sequence being there is refused at construction, by what it
+        would need."""
+        if self.mode != "ragged":
+            raise ValueError(
+                "a config with window layers needs the ragged engine: the "
+                "two_lane programs know one kind of page pool")
+        if self.prefix_cache:
+            raise ValueError(
+                "prefix_cache with window layers: a shared prefix's full "
+                "pages are in the trie, its window pages were recycled; it "
+                "needs the window's last pages kept at page boundaries")
+        if self._draft is not None or self.spec_tokens > 0:
+            raise ValueError(
+                "speculative decoding with window layers: a rejected "
+                "draft's rows have recycled pages the accepted prefix "
+                "still needs; it needs recycling held back by the draft")
+        if page_store is not None:
+            raise ValueError(
+                "page_store with window layers: exported runs carry the "
+                "full layers' pages only; it needs the window pages on "
+                "the wire")
+        if self.quantize_weights != "off" or self.kv_dtype == "int8":
+            raise ValueError(
+                "quantize_weights or int8 pages with a MiMo config: the "
+                "rewrite knows mul ops only, and int8 scale planes know "
+                "no window and no split keys")
 
     # -- lifecycle -----------------------------------------------------------
     def start(self) -> "GenerationEngine":
@@ -1021,6 +1076,12 @@ class GenerationEngine:
             out["moe_expert_load_max"] = int(loads.max())
             out["moe_expert_load_mean"] = float(loads.mean())
             out["recurrent_state_bytes"] = self.cache.state_bytes()
+        # pages by kind of attention layer (window: 0 without any)
+        out["kv_pages_resident_full"] = out["cache"]["pages_resident_full"]
+        out["kv_pages_resident_window"] = \
+            out["cache"]["pages_resident_window"]
+        out["kv_window_pages_recycled_total"] = \
+            out["cache"]["window_pages_recycled_total"]
         if self._page_store is not None:
             lk = self.store_lookups_total
             # flattened into paddle_generation_store_* — this WORKER's
@@ -1629,6 +1690,13 @@ class GenerationEngine:
                         self._retire(slot, "error", ServingError(str(e)))
             spec_rows = [(s, r, k) for s, r, k in spec_rows
                          if s in self._by_slot]
+            if self.cache.window is not None:
+                # window layers: pages behind each row's window go back
+                # to their free list, fresh ones cover the row's tokens
+                for slot, req in self._by_slot.items():
+                    if not self._last_token_in_flight(req):
+                        self.cache.window_step(
+                            slot, *self._row_extent(slot, req))
         if spec_rows:
             # batched drafting: ONE propose() call covers every
             # speculative row, so draft cost amortizes over the batch
@@ -1695,6 +1763,14 @@ class GenerationEngine:
                 for st, tokens in fetched:
                     self._emit_step(st, tokens)
 
+    def _row_extent(self, slot: int, req: "_GenRequest"):
+        """(first position, tokens) of the row the next step takes of a
+        request without drafts: a prefill chunk, or one decode token."""
+        off = req.prefill_off + req.ahead
+        if off < int(req.prompt.size):
+            return off, min(self.chunk_tokens, int(req.prompt.size) - off)
+        return int(self.cache.lengths[slot]) + req.ahead, 1
+
     def _assemble(self, lanes):
         """The numpy batch of one step over ``lanes`` (slot, request):
         returns the feed dict, the rows as ``_StepInFlight`` keeps them
@@ -1732,8 +1808,8 @@ class GenerationEngine:
             num_valid[slot] = 1 + dr.size
             rows.append((slot, req, 1 + int(dr.size)))
         live = positions + num_valid
-        self.metrics.inc("attn_live_pages_total", int(
-            (-(-live[num_valid > 0] // self.geom.page_size)).sum()))
+        walked = int((-(-live[num_valid > 0] // self.geom.page_size)).sum())
+        self.metrics.inc("attn_live_pages_total", walked)
         self.metrics.inc("attn_table_pages_total",
                          R * self.geom.max_pages_per_seq)
         feed = {
@@ -1754,12 +1830,22 @@ class GenerationEngine:
                 if req.adapter is not None:
                     aslots[slot] = self.adapter_store.slots_row(req.adapter)
             feed["gen_adapter_slots"] = aslots
+        if self.cache.window is not None:
+            ps, on = self.geom.page_size, num_valid > 0
+            first = np.maximum(positions - self.cache.window.window + 1,
+                               0) // ps
+            self.metrics.inc("attn_live_pages_full_total", walked)
+            self.metrics.inc("attn_live_pages_window_total", int(
+                ((live - 1) // ps - first + 1)[on].sum()))
+            # a copy: the ring's entries are rewritten for the next step
+            # while this one may still read the array it was fed
+            feed["gen_block_tables_window"] = self.cache.window_tables.copy()
         if self._state_names:
             # recurrent layers: the per-lane state is fed and fetched,
             # rewritten whole (the page pools are the step's state)
             feed.update(self.cache.state)
             self.metrics.inc("moe_tokens_routed_total",
-                             int(num_valid.sum()) * self.config.num_layers)
+                             int(num_valid.sum()) * self._expert_layers)
         return feed, rows, carry
 
     def _dispatch_ahead(self, bound, feed, rows, carry) -> _StepInFlight:

@@ -37,6 +37,26 @@ reset: the step zeroes it in the graph when the lane's row is at
 position 0. Prefix sharing, page export and ingest see pages only, so
 the engine refuses them for a model with such state.
 
+**Two kinds of attention layer** (``window=WindowKind(...)``): FULL
+layers keep every page of a sequence, as above; WINDOW layers attend
+the last ``window`` keys only, so they have pools of their own (their
+own KV-head count and page count, ``window_k_pages`` / ``window_v_pages``)
+and a sequence holds a page there only while a query can still reach
+it. The block table a window layer sees is a RING of ``pages_per_seq``
+entries (``window_tables``): the page of positions ``p * page_size ..``
+lies at entry ``p % pages_per_seq``. ``window_step(slot, start, n)``,
+called before every step that takes ``n`` tokens of the sequence from
+position ``start``, returns the pages wholly behind ``start - window +
+1`` to the window free list and takes fresh ones up to ``start + n -
+1``: the device runs steps in the order they were dispatched, so a page
+released here is rewritten only by a later step than any that reads it.
+The window pools hold ``max_seqs * pages_per_seq`` pages by
+construction, so they never run dry and admission counts full pages
+only. Keys may be wider than values (``k_dim``): the K pools then take
+the split page layout of kernels/ragged_paged_attention.py. Prefix
+sharing, page export and ingest see full pages only, so the engine
+refuses them for a model with window layers.
+
 Page 0 is permanently reserved as the JUNK page: idle decode lanes and
 batch-padding rows point their tables at it, so their (discarded)
 writes can never corrupt a live sequence.
@@ -75,12 +95,14 @@ identical).
 
 from __future__ import annotations
 
+import dataclasses
 import threading
 from typing import Any, Dict, List, Optional, Tuple
 
 import numpy as np
 
-__all__ = ["PagedKVCache", "PagePoolExhausted"]
+__all__ = ["PagedKVCache", "PagePoolExhausted", "WindowKind",
+           "key_page_shape", "window_ring_pages"]
 
 
 class PagePoolExhausted(RuntimeError):
@@ -88,16 +110,46 @@ class PagePoolExhausted(RuntimeError):
     (or eviction) must resolve it; never an allocation."""
 
 
-def pool_names(num_layers: int, quantized: bool = False):
+def pool_names(num_layers: int, quantized: bool = False, prefix: str = ""):
     """The scope names of the page pools, ``(k, v, k_scales, v_scales)``
     as lists by layer (the scale lists empty for float pools): what the
     step programs declare (generation/model.py) and what a cache keeps
-    in its scope."""
+    in its scope. ``prefix`` "w": the window layers' pools."""
     kinds = ("k_pages", "v_pages") + (("k_scales", "v_scales")
                                       if quantized else ())
-    names = [[f"gen_{kind}_{i}" for i in range(num_layers)]
+    names = [[f"gen_{prefix}{kind}_{i}" for i in range(num_layers)]
              for kind in kinds]
     return tuple(names) + ([],) * (4 - len(names))
+
+
+def key_page_shape(page_size: int, k_dim: int, v_dim: int):
+    """``[rows, width]`` of one K page of one KV head: ``[page_size,
+    k_dim]``, or for keys wider than their values the split layout
+    ``[page_size + k_dim - v_dim, v_dim]`` (page_size == v_dim), whose
+    minor dimension is whole lane tiles where ``k_dim`` (192) is not."""
+    if k_dim == v_dim:
+        return (page_size, k_dim)
+    if k_dim < v_dim or page_size != v_dim:
+        raise ValueError(
+            f"keys {k_dim} wide on values {v_dim} wide: the split page "
+            f"layout needs k_dim > v_dim == page_size, got page_size "
+            f"{page_size}")
+    return (page_size + k_dim - v_dim, v_dim)
+
+
+def window_ring_pages(window: int, chunk: int, page_size: int) -> int:
+    """Pages the keys ``start - window + 1 .. start + chunk - 1`` of one
+    step can touch: the width of a window layer's ring table."""
+    return (window + chunk - 1 + page_size - 2) // page_size + 1
+
+
+@dataclasses.dataclass(frozen=True)
+class WindowKind:
+    """The window layers of a model with two kinds of attention layer."""
+    num_layers: int
+    num_kv_heads: int
+    window: int
+    pages_per_seq: int          # the ring's width (window_ring_pages)
 
 
 _SCATTER_JIT = []
@@ -148,7 +200,9 @@ class PagedKVCache:
                  max_pages_per_seq: int, dtype: str = "float32",
                  prefix_cache: bool = False, prefix_min_pages: int = 1,
                  trie_max_pages: int = 0, tenant_quota_pages: int = 0,
-                 state: Optional[Dict[str, tuple]] = None, scope=None):
+                 state: Optional[Dict[str, tuple]] = None, scope=None,
+                 k_dim: Optional[int] = None,
+                 window: Optional[WindowKind] = None):
         if num_pages < 2:
             raise ValueError("num_pages must be >= 2 (page 0 is reserved)")
         if page_size < 1 or max_seqs < 1 or max_pages_per_seq < 1:
@@ -162,6 +216,13 @@ class PagedKVCache:
         self.max_pages_per_seq = int(max_pages_per_seq)
         self.dtype = dtype
         self.quantized = dtype == "int8"
+        # keys as wide as values unless told; a second kind of layer
+        self.k_dim = int(k_dim or head_dim)
+        self.window = window
+        if (window is not None or self.k_dim != self.head_dim) and (
+                self.quantized or prefix_cache):
+            raise ValueError("window layers and keys wider than values "
+                             "take float pages and no prefix cache")
         self.prefix_cache = bool(prefix_cache)
         self.prefix_min_pages = max(1, int(prefix_min_pages))
         self.trie_max_pages = max(0, int(trie_max_pages))
@@ -179,6 +240,8 @@ class PagedKVCache:
             scope = Scope()
         self.scope = scope
         self._pool_names = pool_names(self.num_layers, self.quantized)
+        self._wpool_names = pool_names(
+            window.num_layers if window else 0, prefix="w")[:2]
         self._pool_lock = threading.Lock()
         # the second kind of state: what a sequence's recurrent layers
         # carry is in no page. ``state`` names the arrays ({feed name:
@@ -212,6 +275,14 @@ class PagedKVCache:
         # a sibling published the same token run onto a DIFFERENT page
         # first — this chain stays private from that depth on
         self._pub_dead = [False] * max_seqs
+        # window layers: a ring table a lane, the logical pages a lane
+        # holds there (logical page -> pool page), a free list of its own
+        ring = window.pages_per_seq if window else 0
+        self.window_num_pages = max_seqs * ring + 1 if window else 0
+        self.window_tables = np.zeros((max_seqs, max(ring, 1)), np.int32)
+        self._wpages_of: List[Dict[int, int]] = [{} for _ in range(max_seqs)]
+        self._wfree = list(range(self.window_num_pages - 1, 0, -1))
+        self.window_pages_recycled_total = 0
         self.evictions_total = 0
         self.allocations_total = 0
         # radix counters (radix_stats -> paddle_generation_radix_*)
@@ -254,8 +325,17 @@ class PagedKVCache:
         # 0.0, never to NaN/garbage
         scales = ([[jnp.ones(shape[:3], "float32") for _ in range(n)]
                    for _ in "kv"] if self.quantized else [])
-        self.set_buffers(*([jnp.zeros(shape, self.dtype) for _ in range(n)]
-                           for _ in "kv"), *scales)
+        k_page = key_page_shape(self.page_size, self.k_dim, self.head_dim)
+        shapes = (shape[:2] + k_page, shape)
+        if self.window is not None:
+            w = self.window
+            for names, page in zip(self._wpool_names, (k_page, shape[2:])):
+                for name in names:
+                    self.scope.vars[name] = jnp.zeros(
+                        (w.num_kv_heads, self.window_num_pages) + page,
+                        self.dtype)
+        self.set_buffers(*([jnp.zeros(shp, self.dtype) for _ in range(n)]
+                           for shp in shapes), *scales)
 
     def pools_locked(self):
         """``with cache.pools_locked():`` around the DISPATCH of whatever
@@ -269,7 +349,9 @@ class PagedKVCache:
     def pools_alive(self) -> bool:
         """False once a failed step has consumed the arrays it was
         donated and handed nothing back."""
-        return not any(a.is_deleted() for a in self.k_pages + self.v_pages)
+        return not any(a.is_deleted() for a in (
+            self.k_pages + self.v_pages + self.window_k_pages
+            + self.window_v_pages))
 
     def _pools(self, kind: int) -> List[Any]:
         self._ensure_buffers()
@@ -284,6 +366,19 @@ class PagedKVCache:
     @property
     def v_pages(self) -> List[Any]:
         return self._pools(1)
+
+    def _window_pools(self, kind: int) -> List[Any]:
+        self._ensure_buffers()
+        return [self.scope.vars[n] for n in self._wpool_names[kind]]
+
+    @property
+    def window_k_pages(self) -> List[Any]:
+        """The live K pools of the window layers (empty without any)."""
+        return self._window_pools(0)
+
+    @property
+    def window_v_pages(self) -> List[Any]:
+        return self._window_pools(1)
 
     @property
     def k_scales(self) -> Optional[List[Any]]:
@@ -340,23 +435,31 @@ class PagedKVCache:
 
     @staticmethod
     def page_bytes(num_kv_heads: int, head_dim: int, page_size: int,
-                   dtype: str) -> int:
+                   dtype: str, k_dim: Optional[int] = None) -> int:
         """HBM bytes ONE page costs per layer (K + V, scale planes
         included for int8) — the capacity arithmetic the int8 bench
-        gates its ~2x-resident-sequences claim on."""
+        gates its ~2x-resident-sequences claim on. ``k_dim``: the keys'
+        width where it is not the values' ``head_dim``."""
         slots = num_kv_heads * page_size
         if dtype == "int8":
             return 2 * (slots * head_dim + 4 * slots)   # int8 body + scales
-        import numpy as np
+        import jax.numpy as jnp
 
-        item = 2 if dtype == "bfloat16" else np.dtype(dtype).itemsize
-        return 2 * slots * head_dim * item
+        return (slots * (head_dim + (k_dim or head_dim))
+                * jnp.dtype(dtype).itemsize)
 
     def pool_bytes(self) -> int:
-        """Total device bytes of the page pools across layers."""
-        return (self.num_layers * self.num_pages
-                * self.page_bytes(self.num_kv_heads, self.head_dim,
-                                  self.page_size, self.dtype))
+        """Total device bytes of the page pools across layers, both
+        kinds."""
+        total = (self.num_layers * self.num_pages
+                 * self.page_bytes(self.num_kv_heads, self.head_dim,
+                                   self.page_size, self.dtype, self.k_dim))
+        if self.window is not None:
+            total += (self.window.num_layers * self.window_num_pages
+                      * self.page_bytes(self.window.num_kv_heads,
+                                        self.head_dim, self.page_size,
+                                        self.dtype, self.k_dim))
+        return total
 
     # -- capacity accounting -------------------------------------------------
     def pages_needed(self, n_tokens: int) -> int:
@@ -886,6 +989,38 @@ class PagedKVCache:
                 pages.append(p)
                 self.allocations_total += 1
 
+    def window_step(self, slot: int, start: int, n: int) -> None:
+        """Before a step takes ``n`` tokens of ``slot`` from position
+        ``start``: the window layers' pages wholly behind ``start -
+        window + 1`` go back to the free list (no later query reaches
+        them), and fresh ones cover the positions up to ``start + n -
+        1``. Never dry: the pools hold a full ring a lane."""
+        w = self.window
+        if w is None or n <= 0:
+            return
+        ps, ring = self.page_size, w.pages_per_seq
+        first = max(start - w.window + 1, 0) // ps
+        last = (start + n - 1) // ps
+        if last - first >= ring:
+            raise ValueError(
+                f"a step of {n} tokens at {start} spans {last - first + 1} "
+                f"window pages > the ring's {ring}")
+        with self._lock:
+            held, row = self._wpages_of[slot], self.window_tables[slot]
+            for p in [p for p in held if p < first]:
+                self._wfree.append(held.pop(p))
+                row[p % ring] = 0
+                self.window_pages_recycled_total += 1
+            for p in range(first, last + 1):
+                if p not in held:
+                    held[p] = self._wfree.pop()
+                    row[p % ring] = held[p]
+
+    def _release_window_locked(self, slot: int) -> None:
+        self._wfree.extend(self._wpages_of[slot].values())
+        self._wpages_of[slot] = {}
+        self.window_tables[slot, :] = 0
+
     def advance(self, slot: int, n: int = 1) -> int:
         self.lengths[slot] += n
         return int(self.lengths[slot])
@@ -907,6 +1042,7 @@ class PagedKVCache:
             self._published_of[slot] = 0
             self._pub_node[slot] = None
             self._pub_dead[slot] = False
+            self._release_window_locked(slot)
 
     def evict(self, slot: int) -> None:
         """Preemption: identical to release, but counted — the engine
@@ -936,6 +1072,13 @@ class PagedKVCache:
                 "evictions_total": self.evictions_total,
                 "page_allocations_total": self.allocations_total,
                 "pool_bytes": self.pool_bytes(),
+                # by kind of layer (window: 0 without window layers)
+                "pages_resident_full": in_use,
+                "pages_resident_window": sum(
+                    len(h) for h in self._wpages_of),
+                "window_pages_total": max(self.window_num_pages - 1, 0),
+                "window_pages_recycled_total":
+                    self.window_pages_recycled_total,
             }
 
     def radix_stats(self) -> Dict[str, Any]:
@@ -1083,3 +1226,41 @@ class PagedKVCache:
                 raise AssertionError(
                     f"page leak: {len(fs)} free + {len(in_use)} in use "
                     f"!= {self.usable_pages}")
+            self._check_window_locked()
+
+    def _check_window_locked(self) -> None:
+        """The window kind's invariants: a lane holds at most a ring of
+        pages, consecutive, each at its ring entry and nowhere else; no
+        page twice; free + held covers the window pool exactly."""
+        if self.window is None:
+            return
+        ring = self.window.pages_per_seq
+        seen: Dict[int, int] = {}
+        for slot, held in enumerate(self._wpages_of):
+            if held and not self._active[slot]:
+                raise AssertionError(
+                    f"inactive slot {slot} holds window pages")
+            if len(held) > ring or (
+                    held and max(held) - min(held) >= ring):
+                raise AssertionError(
+                    f"slot {slot} holds window pages {sorted(held)} past "
+                    f"a ring of {ring}")
+            row = np.zeros(ring, np.int32)
+            for logical, page in held.items():
+                if page <= 0 or page in seen:
+                    raise AssertionError(
+                        f"window page {page} of slot {slot} is the junk "
+                        f"page or also slot {seen.get(page)}'s")
+                seen[page] = slot
+                row[logical % ring] = page
+            if not np.array_equal(row, self.window_tables[slot]):
+                raise AssertionError(
+                    f"window table/ring mismatch at slot {slot}")
+        free = set(self._wfree)
+        if len(free) != len(self._wfree) or free & set(seen):
+            raise AssertionError("window free list repeats or holds a "
+                                 "page in use")
+        if len(free) + len(seen) != self.window_num_pages - 1:
+            raise AssertionError(
+                f"window page leak: {len(free)} free + {len(seen)} held "
+                f"!= {self.window_num_pages - 1}")

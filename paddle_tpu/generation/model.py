@@ -70,12 +70,14 @@ from .. import layers, nets
 from ..core.framework import Program, program_guard, unique_name
 from ..models.gpt import GPTConfig, _attr
 from ..models.hybrid import HybridConfig, hybrid_decoder
+from ..models.mimo import MiMoConfig, mimo_decoder
 from ..param_attr import ParamAttr
-from .kvcache import pool_names
+from .kvcache import key_page_shape, pool_names
 
 __all__ = ["CacheGeometry", "build_lm_program", "build_prefill_program",
            "build_decode_program", "build_ragged_step_program",
-           "build_hybrid_step_program", "GPTConfig", "HybridConfig"]
+           "build_hybrid_step_program", "build_mimo_step_program",
+           "GPTConfig", "HybridConfig", "MiMoConfig"]
 
 
 @dataclasses.dataclass(frozen=True)
@@ -84,6 +86,10 @@ class CacheGeometry:
     num_pages: int
     page_size: int
     max_pages_per_seq: int
+    # window layers (a model with two kinds of attention layer): their
+    # pools' page count and the width of their ring tables
+    window_num_pages: int = 0
+    window_pages_per_seq: int = 0
 
     @property
     def max_tokens_per_seq(self) -> int:
@@ -91,19 +97,25 @@ class CacheGeometry:
 
 
 def _page_pools(main: Program, num_layers: int, num_kv_heads: int,
-                head_dim: int, geom: CacheGeometry, dtype: str = "float32"):
+                head_dim: int, geom: CacheGeometry, dtype: str = "float32",
+                k_dim=None, window: bool = False):
     """Declare the page pools of ``num_layers`` attention layers as
     persistable variables of ``main``; returns ``(k, v, k_scales,
     v_scales)`` lists by layer, the scale lists ``[None] * num_layers``
-    unless ``dtype`` is int8. No startup op: the ``PagedKVCache`` makes
-    the arrays."""
+    unless ``dtype`` is int8. ``k_dim``: keys wider than the values'
+    ``head_dim`` (``kvcache.key_page_shape``); ``window``: the window
+    layers' pools, with their own names and page count. No startup op:
+    the ``PagedKVCache`` makes the arrays."""
     block = main.global_block()
-    shape = [num_kv_heads, geom.num_pages, geom.page_size, head_dim]
-    names = pool_names(num_layers, dtype == "int8")
+    pages = geom.window_num_pages if window else geom.num_pages
+    shape = [num_kv_heads, pages, geom.page_size, head_dim]
+    k_shape = shape[:2] + list(key_page_shape(
+        geom.page_size, k_dim or head_dim, head_dim))
+    names = pool_names(num_layers, dtype == "int8", "w" if window else "")
     pools = [[block.create_var(name=n, shape=shp, dtype=dt, persistable=True,
                                stop_gradient=True) for n in kind]
              for kind, shp, dt in zip(
-                 names, (shape, shape, shape[:3], shape[:3]),
+                 names, (k_shape, shape, shape[:3], shape[:3]),
                  (dtype, dtype, "float32", "float32"))]
     return tuple(p or [None] * num_layers for p in pools)
 
@@ -363,6 +375,83 @@ def build_hybrid_step_program(cfg: HybridConfig, geom: CacheGeometry,
         logits, state_out = hybrid_decoder(cfg, tokens, attention,
                                            num_valid, positions, state,
                                            head_at=last)
+        next_tok = layers.reshape(layers.expand(
+            layers.argmax(logits, axis=-1), [1, chunk]), [-1])      # [R*C]
+    return main, [next_tok] + [state_out[name] for name in state]
+
+
+def build_mimo_step_program(cfg: MiMoConfig, geom: CacheGeometry,
+                            chunk: int, kv_dtype: str = "float32"):
+    """The ragged executable of a MiMo-V2 decoder (models/mimo.py): the
+    [lanes, chunk] window and feed contract of
+    ``build_hybrid_step_program`` (``gen_pos_ids`` feeds the rotary
+    embedding), with page pools by KIND of attention layer:
+
+    * ``gen_k_pages_{j}`` / ``gen_v_pages_{j}`` for the j-th FULL layer,
+      ``cfg.num_kv_heads`` heads, read through ``gen_block_tables``;
+    * ``gen_wk_pages_{j}`` / ``gen_wv_pages_{j}`` for the j-th WINDOW
+      layer, ``cfg.window_kv_heads`` heads and ``geom.window_num_pages``
+      pages, read through the ring tables ``gen_block_tables_window``
+      [lanes, geom.window_pages_per_seq]: the kernel walks only the
+      pages that overlap a lane's window, under a name of its own
+      (``ragged_paged_attention_window``), with the layer's sink;
+    * keys ``cfg.k_dim`` wide in the split page layout, values
+      ``cfg.v_dim``; float pages: the products round to bfloat16 unless
+      the pages are float32; a decode lane multiplies its one token's
+      rows only (``lean_decode``);
+    * the experts' load counts ``gen_state_moe_loads`` fed and fetched.
+
+    Returns (program, fetches) with fetch order [next_tokens(R*C),
+    gen_state_moe_loads].
+    """
+    if kv_dtype == "int8":
+        raise ValueError("a MiMo step keeps float pages: int8 scale "
+                         "planes know no window and no split keys")
+    main, startup = Program(), Program()
+    with program_guard(main, startup), unique_name.guard():
+        tokens = layers.data("gen_tokens", [chunk], dtype="int64")
+        pos_ids = layers.data("gen_pos_ids", [chunk], dtype="int64")
+        positions = layers.data("gen_positions", [], dtype="int64")
+        num_valid = layers.data("gen_num_valid", [], dtype="int32")
+        tables = {"full": layers.data(
+            "gen_block_tables", [geom.max_pages_per_seq], dtype="int32")}
+        pools = {}
+        for kind in ("full", "window"):
+            held = cfg.layers_of(kind)
+            if kind == "window" and held:
+                tables[kind] = layers.data(
+                    "gen_block_tables_window", [geom.window_pages_per_seq],
+                    dtype="int32")
+            kps, vps, _, _ = _page_pools(
+                main, len(held), cfg.kv_heads_of(kind), cfg.v_dim, geom,
+                kv_dtype, k_dim=cfg.k_dim, window=kind == "window")
+            pools.update(zip(held, zip(kps, vps)))
+        state = {name: layers.data(name, list(shp), dtype=dt,
+                                   append_batch_size=False)
+                 for name, (shp, dt) in cfg.state_shapes(-1).items()}
+        from ..kernels import (ragged_paged_attention_layer,
+                               split_kv_cache_write_layer)
+
+        def attention(i, kind, q, k, v, sink):
+            kp, vp = pools[i]
+            windowed = kind == "window"
+            split_kv_cache_write_layer(
+                kp, vp, k, v, tables[kind], positions, num_valid,
+                cfg.kv_heads_of(kind), ring=windowed)
+            return ragged_paged_attention_layer(
+                q, kp, vp, tables[kind], positions, num_valid,
+                cfg.num_heads, window=cfg.window if windowed else None,
+                sink_var=sink, stored_products=kv_dtype != "float32",
+                kernel_name=("ragged_paged_attention_window" if windowed
+                             else None), lean_decode=True)
+
+        # the head on each row's last valid position alone, as the
+        # hybrid step's
+        last = layers.slice(
+            layers.one_hot(layers.unsqueeze(num_valid, [1]), chunk + 1),
+            axes=[1], starts=[1], ends=[chunk + 1])     # hot at nv - 1
+        logits, state_out = mimo_decoder(cfg, tokens, pos_ids, attention,
+                                         num_valid, state, head_at=last)
         next_tok = layers.reshape(layers.expand(
             layers.argmax(logits, axis=-1), [1, chunk]), [-1])      # [R*C]
     return main, [next_tok] + [state_out[name] for name in state]

@@ -63,5 +63,7 @@ from .paged_attention import (kv_cache_write, kv_cache_write_layer,
 from .ragged_paged_attention import (quantized_kv_cache_write,
                                      quantized_kv_cache_write_layer,
                                      ragged_paged_attention,
-                                     ragged_paged_attention_layer)
+                                     ragged_paged_attention_layer,
+                                     split_kv_cache_write,
+                                     split_kv_cache_write_layer)
 from .softmax_xent import fused_softmax_xent

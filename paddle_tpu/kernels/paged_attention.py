@@ -140,7 +140,8 @@ def paged_attention(q, k_pages, v_pages, lengths, page_indices, *,
                                       page_indices, scale)
 
 
-def window_pages(page_indices, positions, num_valid, S: int, ps: int):
+def window_pages(page_indices, positions, num_valid, S: int, ps: int,
+                 ring: bool = False):
     """Which pages the windows ``positions[b] .. positions[b] + S - 1``
     fall into, and what each slot of those pages takes:
 
@@ -151,7 +152,9 @@ def window_pages(page_indices, positions, num_valid, S: int, ps: int):
       ok   [B, T, ps]  whether that row is real; elsewhere the slot
                        keeps what it holds
 
-    T is the most pages a window of S rows can touch. No two windows
+    T is the most pages a window of S rows can touch. ``ring``: the
+    tables are rings (a window layer's: the page of positions p * ps ..
+    lies at entry p % width). No two windows
     share a page that takes rows (the engine writes at positions >= a
     sequence's length, into pages that sequence alone holds)."""
     T = (S + ps - 2) // ps + 1
@@ -161,7 +164,9 @@ def window_pages(page_indices, positions, num_valid, S: int, ps: int):
     col0, slot0 = positions // ps, positions % ps
     t = jnp.arange(T, dtype=jnp.int32)[None, :]
     touched = (num_valid[:, None] > 0) & (t * ps < (slot0 + num_valid)[:, None])
-    col = jnp.clip(col0[:, None] + t, 0, page_indices.shape[1] - 1)
+    width = page_indices.shape[1]
+    col = ((col0[:, None] + t) % width if ring
+           else jnp.clip(col0[:, None] + t, 0, width - 1))
     page = jnp.where(touched,
                      jnp.take_along_axis(page_indices, col, axis=1), 0)
     row = (t[:, :, None] * ps + jnp.arange(ps, dtype=jnp.int32)[None, None, :]

@@ -11,7 +11,8 @@ from ..layer_helper import LayerHelper
 from ..param_attr import ParamAttr
 from .nn import _out
 
-__all__ = ["rms_norm", "linear", "gated_ffn", "topk_moe", "mamba2_mixer"]
+__all__ = ["rms_norm", "linear", "gated_ffn", "topk_moe", "mamba2_mixer",
+           "rotary_embedding", "causal_attention"]
 
 
 def _slot(attr, suffix, initializer=None):
@@ -79,17 +80,62 @@ def gated_ffn(input, size, param_attr=None, dtype=None, name=None):
     return out
 
 
+def rotary_embedding(input, positions, num_heads, rotary_dim, base=10000.0):
+    """Rotary position embedding over the first ``rotary_dim`` values of
+    each of ``num_heads`` heads of ``input`` [rows, chunk, heads * D], at
+    ``positions`` [rows, chunk]; the rest of a head passes. float32."""
+    helper = LayerHelper("rotary_embedding")
+    if rotary_dim % 2 or rotary_dim > int(input.shape[-1]) // num_heads:
+        raise ValueError(f"rotary_dim {rotary_dim}: even, at most the head")
+    out = _out(helper, input, dtype="float32")
+    helper.append_op(
+        type="rotary_embedding",
+        inputs={"X": [input], "Positions": [positions]},
+        outputs={"Out": [out]},
+        attrs={"num_heads": int(num_heads), "rotary_dim": int(rotary_dim),
+               "base": float(base)})
+    return out
+
+
+def causal_attention(q, k, v, num_heads, num_kv_heads, window=None,
+                     sink=None):
+    """Causal attention of whole sequences, q [B, T, H * D] on k [B, T,
+    KVH * D] and v [B, T, KVH * Dv] -> [B, T, H * Dv] (ops/decoder.py):
+    grouped queries, values that may be narrower than keys, ``window``
+    (keys i - window < j <= i) and ``sink`` [H] (a logit a head in the
+    denominator). What a full forward pass uses where the serving step
+    uses ragged paged attention."""
+    helper = LayerHelper("causal_attention")
+    dv = int(v.shape[-1]) // num_kv_heads
+    out = _out(helper, q, shape=tuple(q.shape[:-1]) + (num_heads * dv,),
+               dtype="float32")
+    inputs = {"Q": [q], "K": [k], "V": [v]}
+    attrs = {"num_heads": int(num_heads), "num_kv_heads": int(num_kv_heads)}
+    if sink is not None:
+        inputs["Sink"] = [sink]
+    if window is not None:
+        attrs["window"] = int(window)
+    helper.append_op(type="causal_attention", inputs=inputs,
+                     outputs={"Out": [out]}, attrs=attrs)
+    return out
+
+
 def topk_moe(input, num_experts, top_k, expert_size, held_experts=None,
              first_expert=0, num_valid=None, loads=None,
-             param_attr=None, dtype=None, name=None):
+             param_attr=None, dtype=None, name=None, score_func="softmax",
+             select_bias=False):
     """Dropless top-k mixture of gated-SiLU experts over ``[rows, chunk,
     d]`` (ops/moe.py ``topk_moe``): the router is ``num_experts`` wide,
     this program holds experts ``first_expert .. first_expert +
     held_experts`` (default: all) and computes their part of the sum.
     ``num_valid`` [rows]: tokens of each row that are real; ``loads``
-    [held] int32: running per-expert assignment counts. Returns (out,
-    loads_out). Parameters ``<name>router.w``, ``<name>experts_in.w``
-    [held, d, 2 expert_size], ``<name>experts_out.w``."""
+    [held] int32: running per-expert assignment counts. ``score_func``
+    "softmax" (gates: the softmax over the chosen logits) or "sigmoid"
+    (gates: the chosen experts' sigmoid scores over their sum);
+    ``select_bias``: a per-expert bias ``<name>router.bias`` that ranks
+    the experts and weighs nothing. Returns (out, loads_out). Parameters
+    ``<name>router.w``, ``<name>experts_in.w`` [held, d, 2 expert_size],
+    ``<name>experts_out.w``."""
     helper = LayerHelper("topk_moe", param_attr=param_attr, name=name)
     d, dt = int(input.shape[-1]), dtype or input.dtype
     held = int(held_experts if held_experts is not None else num_experts)
@@ -111,11 +157,17 @@ def topk_moe(input, num_experts, top_k, expert_size, held_experts=None,
         inputs["NumValid"] = [num_valid]
     if loads is not None:
         inputs["Loads"] = [loads]
+    attrs = {"top_k": int(top_k), "num_experts": int(num_experts),
+             "first_expert": int(first_expert)}
+    if select_bias:
+        inputs["SelectBias"] = [helper.create_parameter(
+            _slot(helper.param_attr, "router.bias"), [num_experts], dt,
+            default_initializer=ConstantInitializer(0.0))]
+    if score_func != "softmax":
+        attrs["score_func"] = str(score_func)
     helper.append_op(
         type="topk_moe", inputs=inputs,
-        outputs={"Out": [out], "LoadsOut": [loads_out]},
-        attrs={"top_k": int(top_k), "num_experts": int(num_experts),
-               "first_expert": int(first_expert)})
+        outputs={"Out": [out], "LoadsOut": [loads_out]}, attrs=attrs)
     return out, loads_out
 
 

@@ -229,9 +229,10 @@ _HI = jax.lax.Precision.HIGHEST
 
 # jitted so that a program's expert layers share one trace and lowering
 @functools.partial(jax.jit, static_argnames=(
-    "top_k", "num_experts", "first_expert", "block_rows"))
+    "top_k", "num_experts", "first_expert", "block_rows", "score_func"))
 def topk_moe(x, valid, router_w, w_in, w_out, loads=None, *, top_k: int,
-             num_experts: int, first_expert: int, block_rows: int = 128):
+             num_experts: int, first_expert: int, block_rows: int = 128,
+             score_func: str = "softmax", select_bias=None):
     """x [T, d]; valid [T] bool (padding rows of a ragged window take no
     expert); router_w [d, num_experts]; w_in [held, d, 2f] and w_out
     [held, f, d]: experts first_expert .. first_expert + held, each a
@@ -241,7 +242,10 @@ def topk_moe(x, valid, router_w, w_in, w_out, loads=None, *, top_k: int,
 
     Every token routes over all ``num_experts`` (the router runs in
     float32 at HIGHEST: a rounded logit would flip the tenth expert);
-    gates are the softmax over its top_k logits. The (token, expert)
+    gates are the softmax over its top_k logits, or with ``score_func``
+    "sigmoid" the chosen experts' sigmoid scores over their sum.
+    ``select_bias`` [num_experts] is added to the scores for the ranking
+    alone: it chooses and does not weigh. The (token, expert)
     pairs that landed on held experts are sorted by expert and go
     through two grouped matrix products (``jax.lax.ragged_dot``: on a
     TPU, XLA's own grouped-matmul kernel, which visits only tiles that
@@ -259,8 +263,17 @@ def topk_moe(x, valid, router_w, w_in, w_out, loads=None, *, top_k: int,
             f"{first_expert + held} of {num_experts}")
     logits = jnp.dot(x.astype(jnp.float32), router_w.astype(jnp.float32),
                      precision=_HI)
-    vals, idx = jax.lax.top_k(logits, top_k)                # [T, k]
-    gates = jax.nn.softmax(vals, axis=-1)
+    if score_func not in ("softmax", "sigmoid"):
+        raise ValueError(f"topk_moe: score_func {score_func!r}")
+    scores = jax.nn.sigmoid(logits) if score_func == "sigmoid" else logits
+    if select_bias is None:
+        vals, idx = jax.lax.top_k(scores, top_k)            # [T, k]
+    else:
+        _, idx = jax.lax.top_k(
+            scores + select_bias.astype(jnp.float32), top_k)
+        vals = jnp.take_along_axis(scores, idx, axis=-1)
+    gates = (vals / jnp.sum(vals, axis=-1, keepdims=True)
+             if score_func == "sigmoid" else jax.nn.softmax(vals, axis=-1))
     local = idx.astype(jnp.int32) - first_expert
     mine = valid[:, None] & (local >= 0) & (local < held)
     key = jnp.where(mine, local, held).reshape(-1)          # dead pairs last
@@ -306,9 +319,9 @@ def topk_moe(x, valid, router_w, w_in, w_out, loads=None, *, top_k: int,
 
 @register_op("topk_moe",
              inputs=("X", "NumValid", "RouterW", "ExpertWIn", "ExpertWOut",
-                     "Loads"),
+                     "Loads", "SelectBias"),
              outputs=("Out", "LoadsOut"),
-             no_grad=("NumValid", "Loads"), stop_gradient=True)
+             no_grad=("NumValid", "Loads", "SelectBias"), stop_gradient=True)
 def _topk_moe_op(ctx, op, ins):
     x = ins["X"][0]                                         # [R, C, d]
     R, C, d = x.shape
@@ -317,10 +330,13 @@ def _topk_moe_op(ctx, op, ins):
              jnp.arange(C, dtype=jnp.int32)[None, :]
              < nv[0].astype(jnp.int32)[:, None])
     loads = ins.get("Loads")
+    bias = ins.get("SelectBias")
     out, loads = topk_moe(
         x.reshape(R * C, d), valid.reshape(-1), ins["RouterW"][0],
         ins["ExpertWIn"][0], ins["ExpertWOut"][0],
         loads[0] if loads else None, top_k=int(op.attrs["top_k"]),
         num_experts=int(op.attrs["num_experts"]),
-        first_expert=int(op.attrs["first_expert"]))
+        first_expert=int(op.attrs["first_expert"]),
+        score_func=str(op.attrs.get("score_func", "softmax")),
+        select_bias=bias[0] if bias else None)
     return {"Out": [out.reshape(R, C, d)], "LoadsOut": [loads]}

@@ -62,6 +62,16 @@ def _ragged():
                               0.35, None, None, True)
 
 
+def _ragged_window():
+    q = _z(2, 4, 2, 8)
+    pages = _z(2, 6, 4, 8)
+    i32 = jnp.int32
+    return rpa._ragged_pallas(q, pages, pages, _z(2, dtype=i32),
+                              jnp.ones(2, i32), _z(2, 3, dtype=i32),
+                              0.35, None, None, True, window=4,
+                              sink=_z(2), name="ragged_paged_attention_window")
+
+
 def _quant():
     qw, sc = quant_matmul.quantize_weight(np.ones((256, 128), "float32"),
                                           "int8")
@@ -123,6 +133,11 @@ SITES = [
     ("fused_optim.py", _adam, ["fused_adam"]),
     ("fused_optim.py", _momentum, ["fused_momentum"]),
     ("ragged_paged_attention.py", _ragged, ["ragged_paged_attention"]),
+    # a window layer's call site carries a name of its own (the op's
+    # `kernel_name` attribute), so that a trace tells it from the full
+    # layers' calls
+    ("ragged_paged_attention.py", _ragged_window,
+     ["ragged_paged_attention_window"]),
     ("quant_matmul.py", _quant, ["quant_matmul"]),
     ("lora.py", _lora, ["lora_delta"]),
     ("mamba2_state.py", _state_step, ["mamba2_state_step"]),
@@ -177,8 +192,11 @@ def test_every_call_site_is_named_and_no_two_share_a_name():
         if isinstance(node, ast.Constant):
             names.append(node.value)
         else:
-            # one shared driver: the name is its caller's (fused_optim)
-            assert (f, ast.unparse(node)) == ("fused_optim.py", "name")
+            # one shared driver: the name is its caller's (fused_optim;
+            # the ragged kernel's by kind of attention layer)
+            assert (f, ast.unparse(node)) in (
+                ("fused_optim.py", "name"),
+                ("ragged_paged_attention.py", "name"))
     assert len(set(names)) == len(names), names
     # every name the source gives is one the traced sites above carry,
     # and every file with a call site is traced

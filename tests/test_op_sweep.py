@@ -541,6 +541,13 @@ COVERED_ELSEWHERE = {
     # program == one full forward and the engine == greedy reference)
     'mamba2_mixer', 'topk_moe', 'rms_norm', 'gated_silu_ffn',
     'linear_stored',
+    # PR-35 MiMo decoder ops (tests/test_mimo.py, at the logits against
+    # benchmark/models/mimo_reference.py: rotary_embedding over part of
+    # a head and causal_attention (window, sink, V narrower than K)
+    # through the export program == the reference; kv_cache_write_split
+    # into the split key layout and the ring tables through the kernel
+    # test, the step program == one full forward and the engine)
+    'rotary_embedding', 'causal_attention', 'kv_cache_write_split',
     # PR-6 generation ops (tests/test_generation.py: paged_attention
     # vs dense-softmax oracle incl. length masking + len-0 rows;
     # kv_cache_write scatter vs oracle + junk-page isolation; both

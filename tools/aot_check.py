@@ -272,6 +272,40 @@ def _child():
         lanes=Rl, chunk=Ck, heads=Hh, head_dim=Dd, pages=Pp,
         page_size=psz)
 
+    # -- the MiMo cell's two attention shapes (PT_AOT_ONLY=mimo): 32
+    # lanes x 16 tokens, 64 query heads, keys 192 wide in the split page
+    # layout [128 + 64, 128] over values 128, bfloat16 pages of 128
+    # tokens; full layers 4 KV heads over a 208-entry table, window
+    # layers 8 KV heads, window 128, a sink, a ring of 3 under the
+    # kernel's own name; and the page write into both layouts.
+    from paddle_tpu.kernels.ragged_paged_attention import split_kv_cache_write
+
+    mq = jax.ShapeDtypeStruct((32, 16, 64, 192), jnp.float32)
+    mvec = jax.ShapeDtypeStruct((32,), jnp.int32)
+    for tag, kvh, width, pages, window in (("full", 4, 208, 512, None),
+                                           ("window", 8, 3, 97, 128)):
+        mk = jax.ShapeDtypeStruct((kvh, pages, 192, 128), bf)
+        mv = jax.ShapeDtypeStruct((kvh, pages, 128, 128), bf)
+        mtab = jax.ShapeDtypeStruct((32, width), jnp.int32)
+        msink = jax.ShapeDtypeStruct((64,), bf)
+        aot(f"ragged_attention_mimo_{tag}_bf16",
+            lambda q, k, v, st, nv, pi, sk, window=window: ragged(
+                q, k, v, st, nv, pi, window=window,
+                sink=sk if window else None, stored_products=True,
+                lean_decode=True,
+                name="ragged_paged_attention" + ("_window" if window
+                                                 else "")),
+            (mq, mk, mv, mvec, mvec, mtab, msink), group="mimo", lanes=32,
+            chunk=16, heads=64, kv_heads=kvh, k_dim=192, v_dim=128,
+            page_size=128, table=width, window=window)
+        aot(f"ragged_kv_write_split_mimo_{tag}",
+            lambda kp, vp, k, v, pi, pos, nv, window=window:
+            split_kv_cache_write(kp, vp, k, v, pi, pos, nv,
+                                 ring=bool(window)),
+            (mk, mv, jax.ShapeDtypeStruct((32, 16, kvh, 192), jnp.float32),
+             jax.ShapeDtypeStruct((32, 16, kvh, 128), jnp.float32), mtab,
+             mvec, mvec), group="mimo", kv_heads=kvh, page_size=128)
+
     # -- quantized weight matmul (the inference serving path) ----------
     # paddle_tpu.quantize rewrites every matmul/fc weight onto these
     # kernels at load; the rows compile the custom Pallas lowering
@@ -444,6 +478,64 @@ def _child():
 
     record("hybrid_step_program_granite4_h_small_10layer",
            hybrid_step_program, group="hybrid")
+    # -- the MiMo serving cell's step program at its own shapes
+    # (PT_AOT_ONLY=mimo): mimo_v2_5_serve as benchmark/ runs it: 7
+    # layers, 16 of 256 experts, bfloat16 weights and pages of both kinds
+    # (zeros: only shapes and types reach the compiler), 32 lanes x 16
+    # tokens. A v5e compile failure, a pool that is copied or padded, or
+    # a step that does not fit 16 GB shows here, before a chip call.
+    def mimo_step_program():
+        import ml_dtypes
+
+        import paddle_tpu as fluid
+        from paddle_tpu.generation.kvcache import (key_page_shape,
+                                                   window_ring_pages)
+        from paddle_tpu.generation.model import (CacheGeometry,
+                                                 build_mimo_step_program)
+
+        bench = os.path.join(HERE, "benchmark")
+        with open(os.path.join(bench, "configs",
+                               "mimo_v2_5_serve.json")) as f:
+            cfg = json.load(f)
+        ref = _load(os.path.join(bench, "models", "mimo_reference.py"))
+        mcfg = _load(os.path.join(
+            bench, "models", "mimo_program.py")).mimo_config(cfg)
+        eng = cfg["engine"]
+        lanes, chunk, ps = eng["lanes"], eng["chunk_tokens"], eng["page_size"]
+        maxp = -(-eng["max_position"] // ps)
+        ring = window_ring_pages(mcfg.window, chunk, ps)
+        geom = CacheGeometry(num_pages=eng["num_pages"], page_size=ps,
+                             max_pages_per_seq=maxp,
+                             window_num_pages=lanes * ring + 1,
+                             window_pages_per_seq=ring)
+        prog, fetches = build_mimo_step_program(mcfg, geom, chunk,
+                                                eng["kv_dtype"])
+        feed = {"gen_tokens": np.zeros((lanes, chunk), np.int64),
+                "gen_pos_ids": np.zeros((lanes, chunk), np.int64),
+                "gen_positions": np.zeros(lanes, np.int64),
+                "gen_num_valid": np.zeros(lanes, np.int32),
+                "gen_block_tables": np.zeros((lanes, maxp), np.int32),
+                "gen_block_tables_window": np.zeros((lanes, ring), np.int32)}
+        for name, (shape, dt) in mcfg.state_shapes(lanes).items():
+            feed[name] = np.zeros(shape, dt)
+        scope = fluid.Scope()
+        k_page = key_page_shape(ps, mcfg.k_dim, mcfg.v_dim)
+        for kind, pre, pages in (("full", "", geom.num_pages),
+                                 ("window", "w", geom.window_num_pages)):
+            kvh = mcfg.kv_heads_of(kind)
+            for j in range(len(mcfg.layers_of(kind))):
+                scope.set_var(f"gen_{pre}k_pages_{j}", np.zeros(
+                    (kvh, pages) + k_page, ml_dtypes.bfloat16))
+                scope.set_var(f"gen_{pre}v_pages_{j}", np.zeros(
+                    (kvh, pages, ps, mcfg.v_dim), ml_dtypes.bfloat16))
+        for name, shape, _init in ref.spec(cfg):
+            scope.set_var(name, np.zeros(shape, ml_dtypes.bfloat16))
+        exe = fluid.Executor(fluid.TPUPlace())
+        return exe.aot_compile(prog, feed, fetches, scope=scope,
+                               devices=[dev])
+
+    record("mimo_step_program_mimo_v2_5_7layer", mimo_step_program,
+           group="mimo")
     from paddle_tpu.kernels.mamba2_state import state_step
 
     f32 = jnp.float32
