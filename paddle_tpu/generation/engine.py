@@ -707,6 +707,7 @@ class GenerationEngine:
         watch_generation(self)
 
         self._ragged_bound = None       # resolved on the first step
+        self._moe_kernel_layers = 0     # counted when it is
         self._decode_bound = None       # two_lane: first decode step
         self._prefill_progs: Dict[int, Any] = {}    # seq bucket -> (prog, fetches)
         if self.mode == "ragged":
@@ -1076,6 +1077,7 @@ class GenerationEngine:
             out["moe_expert_load_max"] = int(loads.max())
             out["moe_expert_load_mean"] = float(loads.mean())
             out["recurrent_state_bytes"] = self.cache.state_bytes()
+            out["moe_kernel_layers"] = self._moe_kernel_layers
         # pages by kind of attention layer (window: 0 without any)
         out["kv_pages_resident_full"] = out["cache"]["pages_resident_full"]
         out["kv_pages_resident_window"] = \
@@ -1559,7 +1561,24 @@ class GenerationEngine:
             self._ragged_bound = self._exe.bind(
                 self._ragged_prog, feed, self._ragged_fetches,
                 scope=self._step_scope, tag="generation/ragged_step")
+            self._moe_kernel_layers = self._count_moe_kernel_layers()
         return self._ragged_bound
+
+    def _count_moe_kernel_layers(self) -> int:
+        """The expert layers of the step that run kernels/moe_ffn.py and
+        not the ``ragged_dot`` path: what ``topk_moe`` decides when the
+        step is traced, asked of the same ``fits`` with the window's rows
+        and the weights as the scope holds them."""
+        from ..kernels import moe_ffn
+
+        n = 0
+        for op in self._ragged_prog.global_block().ops:
+            if op.type == "topk_moe":
+                w_out = self._step_scope.find_var(op.input("ExpertWOut")[0])
+                _held, f, d = w_out.shape
+                n += moe_ffn.fits(self.lanes * self.chunk_tokens, d, f,
+                                  w_out.dtype)
+        return n
 
     def _dispatch(self, bound, feed):
         """Dispatch one step that is donated the page pools: under the

@@ -34,6 +34,10 @@ attention/ffn/layer_norm/adam/softmax-ce):
     state [H, P, N] and advance it, one pass over the state
     (kernels/mamba2_state.py): the kernel under ops/ssm.py's
     ``mamba2_mixer`` in the hybrid serving step
+  * grouped expert feed-forward — the held experts' gated feed-forward
+    for pairs sorted by expert, each visited expert's weights streamed
+    once in whole-row tiles of 2-4 MB (kernels/moe_ffn.py): the kernel
+    under ops/moe.py's ``topk_moe`` in the hybrid and MiMo serving steps
   * fused optimizer — one-pass Adam/AdamW/Momentum over donated
     buffers (kernels/fused_optim.py): the whole m/v/param update is a
     single Pallas pass per parameter with the global-norm-clip scale

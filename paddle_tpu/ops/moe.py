@@ -39,6 +39,7 @@ import jax
 import jax.numpy as jnp
 
 from ..core.registry import register_op
+from ..kernels import moe_ffn
 
 
 def _moe_math(x2, wg, w1, b1, w2, b2, cap, act, e_first, e_local,
@@ -227,9 +228,6 @@ def _switch_moe(ctx, op, ins):
 _HI = jax.lax.Precision.HIGHEST
 
 
-# jitted so that a program's expert layers share one trace and lowering
-@functools.partial(jax.jit, static_argnames=(
-    "top_k", "num_experts", "first_expert", "block_rows", "score_func"))
 def topk_moe(x, valid, router_w, w_in, w_out, loads=None, *, top_k: int,
              num_experts: int, first_expert: int, block_rows: int = 128,
              score_func: str = "softmax", select_bias=None):
@@ -247,14 +245,30 @@ def topk_moe(x, valid, router_w, w_in, w_out, loads=None, *, top_k: int,
     ``select_bias`` [num_experts] is added to the scores for the ranking
     alone: it chooses and does not weigh. The (token, expert)
     pairs that landed on held experts are sorted by expert and go
-    through two grouped matrix products (``jax.lax.ragged_dot``: on a
-    TPU, XLA's own grouped-matmul kernel, which visits only tiles that
-    hold rows) in blocks of ``block_rows`` sorted rows, as many blocks
-    as there are live pairs: a decode step of 32 tokens costs 2 blocks,
-    not the 40 a full window of 512 would. Rows are picked and results
-    put back by one-hot products (exact: one term each), so the loop
-    holds no gather and no scatter.
+    through the grouped gated feed-forward: ``kernels/moe_ffn.py`` where
+    it runs (``moe_ffn.fits``: a TPU or the interpreter, shapes its tiles
+    take), one call over the step's live pairs that reads each visited
+    expert's weights once; otherwise two ``jax.lax.ragged_dot`` products
+    in blocks of ``block_rows`` sorted rows, as many blocks as there are
+    live pairs, rows picked and results put back by one-hot products
+    (exact: one term each).
     """
+    return _topk_moe(
+        x, valid, router_w, w_in, w_out, loads, select_bias, top_k=top_k,
+        num_experts=num_experts, first_expert=first_expert,
+        block_rows=block_rows, score_func=score_func,
+        kernel=moe_ffn.fits(x.shape[0], x.shape[1], w_out.shape[1],
+                            w_in.dtype))
+
+
+# jitted so that a program's expert layers share one trace and lowering;
+# ``kernel`` (the routing, decided by the caller from the environment and
+# the shapes) is part of the key
+@functools.partial(jax.jit, static_argnames=(
+    "top_k", "num_experts", "first_expert", "block_rows", "score_func",
+    "kernel"))
+def _topk_moe(x, valid, router_w, w_in, w_out, loads, select_bias, *, top_k,
+              num_experts, first_expert, block_rows, score_func, kernel):
     T, d = x.shape
     held = w_in.shape[0]
     if router_w.shape[1] != num_experts or first_expert + held > num_experts:
@@ -278,14 +292,19 @@ def topk_moe(x, valid, router_w, w_in, w_out, loads=None, *, top_k: int,
     mine = valid[:, None] & (local >= 0) & (local < held)
     key = jnp.where(mine, local, held).reshape(-1)          # dead pairs last
     pairs = T * top_k
-    rows = -(-pairs // block_rows) * block_rows
     order = jnp.argsort(key, stable=True)
-    pad = (0, rows - pairs)
-    e_sorted = jnp.pad(key[order], pad, constant_values=held)
-    tok_sorted = jnp.pad((order // top_k).astype(jnp.int32), pad)
-    gate_sorted = jnp.pad(jnp.where(mine, gates, 0.0).reshape(-1)[order], pad)
+    tok_sorted = (order // top_k).astype(jnp.int32)
+    gate_sorted = jnp.where(mine, gates, 0.0).reshape(-1)[order]
     counts = jnp.sum(key[:, None] == jnp.arange(held, dtype=jnp.int32),
                      axis=0, dtype=jnp.int32)               # [held]
+    loads = counts if loads is None else loads + counts
+    if kernel:
+        return moe_ffn.grouped_ffn(x, w_in, w_out, tok_sorted, gate_sorted,
+                                   counts), loads
+    pad = (0, -pairs % block_rows)
+    e_sorted = jnp.pad(key[order], pad, constant_values=held)
+    tok_sorted = jnp.pad(tok_sorted, pad)
+    gate_sorted = jnp.pad(gate_sorted, pad)
     live = jnp.sum(counts)
     xs = x.astype(w_in.dtype)
     tokens = jnp.arange(T, dtype=jnp.int32)
@@ -314,7 +333,7 @@ def topk_moe(x, valid, router_w, w_in, w_out, loads=None, *, top_k: int,
 
     out = jax.lax.fori_loop(0, -(-live // block_rows), block,
                             jnp.zeros((T, d), jnp.float32))
-    return out, (counts if loads is None else loads + counts)
+    return out, loads
 
 
 @register_op("topk_moe",

@@ -70,6 +70,36 @@ def test_headline_is_the_train_cell_and_the_archive_is_current():
     assert "stage_headline_bert_base_s512_flash" in {r["name"] for r in rows}
 
 
+def test_archive_counts_the_expert_kernel_in_both_moe_steps():
+    """``mosaic_kernels`` names a row's custom calls by ``pallas_call``
+    name: the two MoE cells' step programs, compiled for a v5e at the
+    cells' shapes, run every expert layer on ``moe_grouped_ffn`` (10 and
+    6) and none on XLA's ``ragged-dot`` kernels."""
+    sys.path.insert(0, os.path.dirname(TOOL))
+    import aot_check as tool
+    for line, name in (
+            ("  %moe_grouped_ffn.7 = f32[512,4096]{1,0} custom-call(",
+             "moe_grouped_ffn"),
+            ("  ROOT %ragged-dot-none = f32[128,4096]{1,0} custom-call(",
+             "ragged-dot-none"),
+            ("  layer_norm_fwd = (f32[8,128]) custom-call(", "layer_norm_fwd")):
+        assert tool._KERNEL_NAME.match(line).group(1) == name
+    with open(os.path.join(HERE, "AOT_TPU_CHECK.json")) as f:
+        rows = {r["name"]: r for r in json.load(f)["rows"]}
+    for name, layers in (
+            ("hybrid_step_program_granite4_h_small_10layer", 10),
+            ("mimo_step_program_mimo_v2_5_7layer", 6)):
+        kernels = rows[name]["mosaic_kernels"]
+        assert kernels["moe_grouped_ffn"] == layers, kernels
+        assert not [k for k in kernels if k.startswith("ragged-dot")], kernels
+    for name in ("moe_ffn_hybrid_36x4096x768_top10",
+                 "moe_ffn_mimo_16x4096x2048_top8"):
+        assert rows[name]["ok"], rows[name]
+        assert rows[name]["mosaic_kernels"] == {"moe_grouped_ffn": 1}
+        assert 0 <= (rows[name]["arg_bytes"]
+                     - rows[name]["stated_arg_bytes"]) < 4096
+
+
 @pytest.mark.skipif(
     os.environ.get("PT_AOT_CHECK") != "1",
     reason="multi-minute real-TPU-target AOT compile; set PT_AOT_CHECK=1",

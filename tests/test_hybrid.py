@@ -532,3 +532,45 @@ def test_gpt_engine_step_feeds_and_fetches_are_the_parents(tmp_path):
     assert seen and all(names == want for names in seen)
     assert "moe_held_assignments_total" not in st
     assert st["moe_tokens_routed_total"] == st["state_lane_resets_total"] == 0
+
+
+# -- (g) the expert kernel: the same served tokens on both paths -------------------
+
+
+def test_engine_serves_the_same_tokens_through_the_expert_kernel(
+        tmp_path, monkeypatch):
+    """Width 128 with experts of 128 and a window of 4 lanes x 4 tokens,
+    where kernels/moe_ffn.py's tiles fit: the engine under the
+    interpreter (every expert layer through ``moe_grouped_ffn``, gauge
+    ``moe_kernel_layers`` 4) serves the tokens of the ``ragged_dot``
+    path (gauge 0) and counts the same loads."""
+    from paddle_tpu.runtime import dispatch
+
+    cfg = dict(CFG, hidden_size=128, intermediate_size=128, mamba_d_head=32,
+               vocab_size=101)
+    hcfg = prog.hybrid_config(cfg)
+    main, _startup, _feeds, fetches = build_hybrid_lm_program(hcfg, SEQ)
+    with fluid.scope_guard(_scope_with(_weights(cfg, seed=3))):
+        fluid.io.save_inference_model(
+            str(tmp_path), ["tokens"], [fetches["logits"]],
+            fluid.Executor(fluid.TPUPlace()), main)
+    prompts = _prompts(5, seed=6)
+    seen = {}
+    for path in ("kernel", "ragged_dot"):
+        if path == "kernel":
+            monkeypatch.setenv("PADDLE_TPU_KERNEL_INTERPRET", "1")
+        else:
+            monkeypatch.delenv("PADDLE_TPU_KERNEL_INTERPRET")
+        dispatch._SHARED_CACHE.clear()      # compiled by program content
+        with GenerationEngine(
+                create_predictor(Config(str(tmp_path))), hcfg, mode="ragged",
+                page_size=4, num_pages=40, max_decode_batch=4,
+                chunk_tokens=4, prefix_cache=False) as eng:
+            streams = [eng.submit(p, max_new_tokens=5) for p in prompts]
+            tokens = [s.result(timeout=300) for s in streams]
+        st = eng.stats()
+        seen[path] = (tokens, st["moe_held_assignments_total"],
+                      st["moe_kernel_layers"])
+    dispatch._SHARED_CACHE.clear()
+    assert seen["kernel"][:2] == seen["ragged_dot"][:2]
+    assert (seen["kernel"][2], seen["ragged_dot"][2]) == (4, 0)

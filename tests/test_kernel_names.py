@@ -21,11 +21,11 @@ def _mod(name):
     return importlib.import_module("paddle_tpu.kernels." + name)
 
 
-(fa, fused_optim, layer_norm, lora, mamba2_state, quant_matmul, rpa,
+(fa, fused_optim, layer_norm, lora, mamba2_state, moe_ffn, quant_matmul, rpa,
  softmax_xent) = map(
     _mod, ("flash_attention", "fused_optim", "layer_norm", "lora",
-           "mamba2_state", "quant_matmul", "ragged_paged_attention",
-           "softmax_xent"))
+           "mamba2_state", "moe_ffn", "quant_matmul",
+           "ragged_paged_attention", "softmax_xent"))
 
 KERNELS = os.path.dirname(os.path.abspath(fa.__file__))
 F32 = jnp.float32
@@ -90,6 +90,13 @@ def _state_step():
         _z(2, 8, 128), _z(2, 8), True)
 
 
+def _moe_ffn():
+    i32 = jnp.int32
+    return moe_ffn._grouped_ffn_pallas(
+        _z(16, 128), _z(2, 128, 256), _z(2, 128, 128), _z(16, dtype=i32),
+        _z(16), jnp.ones(2, i32), True)
+
+
 def _adam():
     p = _z(8, 128)
     return fused_optim.fused_adam_update(p, p, p, p, 1e-3, 0.9, 0.999)
@@ -141,6 +148,7 @@ SITES = [
     ("quant_matmul.py", _quant, ["quant_matmul"]),
     ("lora.py", _lora, ["lora_delta"]),
     ("mamba2_state.py", _state_step, ["mamba2_state_step"]),
+    ("moe_ffn.py", _moe_ffn, ["moe_grouped_ffn"]),
 ]
 
 
@@ -185,7 +193,7 @@ def _call_sites():
 
 def test_every_call_site_is_named_and_no_two_share_a_name():
     sites = _call_sites()
-    assert len(sites) >= 15
+    assert len(sites) >= 16
     names = []
     for f, line, node in sites:
         assert node is not None, f"{f}:{line}: pallas_call without name="

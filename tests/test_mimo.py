@@ -434,3 +434,52 @@ def test_planted_fault_moves_the_logits(fault):
     toks = np.random.RandomState(4).randint(1, 89, 64)
     assert np.abs(_ref_logits(toks, fault)
                   - _ref_logits(toks)).max() > 100 * TOL
+
+
+# -- (f) the expert kernel: the same served tokens on both paths ----------------------
+
+
+def test_engine_serves_the_same_tokens_through_the_expert_kernel(
+        tmp_path, monkeypatch):
+    """Width 128 with experts of 128 and a window of 4 lanes x 4 tokens,
+    where kernels/moe_ffn.py's tiles fit: the engine under the
+    interpreter (the three expert layers through ``moe_grouped_ffn``,
+    gauge ``moe_kernel_layers`` 3) serves the tokens of the
+    ``ragged_dot`` path (gauge 0) and counts the same loads."""
+    from paddle_tpu.runtime import dispatch
+
+    cfg = dict(CFG, hidden_size=128, moe_intermediate_size=128, vocab_size=97)
+    mcfg = prog.mimo_config(cfg)
+    main, _startup, _feeds, fetches = build_mimo_lm_program(mcfg, 16)
+    fluid.io.save_inference_model(
+        str(tmp_path), ["tokens"], [fetches["logits"]],
+        fluid.Executor(fluid.TPUPlace()), main, program_only=True)
+    np.savez(os.path.join(str(tmp_path), "__params__.npz"))
+    weights = {k: ref.placed(cfg, k, v)
+               for k, v in _weights(cfg, seed=5).items()}
+    rng = np.random.default_rng(1)
+    prompts = [rng.integers(1, 97, n, dtype=np.int64) for n in (23, 9, 30)]
+    seen = {}
+    for path in ("kernel", "ragged_dot"):
+        if path == "kernel":
+            monkeypatch.setenv("PADDLE_TPU_KERNEL_INTERPRET", "1")
+        else:
+            monkeypatch.delenv("PADDLE_TPU_KERNEL_INTERPRET")
+        dispatch._SHARED_CACHE.clear()      # compiled by program content
+        pred = create_predictor(Config(str(tmp_path)))
+        for k, v in weights.items():
+            pred._scope.set_var(k, v)
+        eng = GenerationEngine(
+            pred, mcfg, mode="ragged", page_size=ENG["page_size"],
+            num_pages=ENG["num_pages"], max_decode_batch=4,
+            chunk_tokens=4, prefix_cache=False)
+        streams = [eng.submit(p, max_new_tokens=6, eos_id=None)
+                   for p in prompts]
+        tokens = [s.result(timeout=600) for s in streams]
+        eng.close()
+        st = eng.stats()
+        seen[path] = (tokens, st["moe_held_assignments_total"],
+                      st["moe_kernel_layers"])
+    dispatch._SHARED_CACHE.clear()
+    assert seen["kernel"][:2] == seen["ragged_dot"][:2]
+    assert (seen["kernel"][2], seen["ragged_dot"][2]) == (3, 0)

@@ -19,8 +19,11 @@ this is the TPU analogue — target-arch compilation as a local,
 driver-checkable step.
 """
 
+import collections
+import hashlib
 import json
 import os
+import re
 import subprocess
 import sys
 import time
@@ -38,6 +41,11 @@ _CHILD_ENV = {
     "TPU_SKIP_MDS_QUERY": "1",
     "PADDLE_TPU_FORCE_PALLAS": "1",
 }
+
+
+# a custom call's instruction name in optimized HLO: the pallas_call's
+# ``name=``, less the ".N" XLA appends to a repeat
+_KERNEL_NAME = re.compile(r"\s*(?:ROOT )?%?([A-Za-z_][\w-]*?)(?:\.\d+)? = ")
 
 
 def _load(path):
@@ -122,12 +130,20 @@ def _child():
             ma = compiled.memory_analysis()
             total = int(ma.temp_size_in_bytes + ma.argument_size_in_bytes
                         + ma.output_size_in_bytes)
+            text = compiled.as_text()
+            # the optimized HLO names its source files, and so do the
+            # Mosaic kernels' payloads: the hash compares between trees
+            # unpacked at one path, one after the other (PT_AOT_PARENT)
+            sha = hashlib.sha256(text.encode()).hexdigest()
             row(name, ok=True, compile_s=round(time.time() - t0, 1),
                 temp_bytes=int(ma.temp_size_in_bytes),
                 arg_bytes=int(ma.argument_size_in_bytes),
                 hbm_frac_v5e=round(total / 16e9, 3),
-                mosaic_calls=compiled.as_text().count(MOSAIC_TARGET),
-                **meta)
+                mosaic_calls=text.count(MOSAIC_TARGET),
+                mosaic_kernels=dict(collections.Counter(
+                    _KERNEL_NAME.match(ln).group(1)
+                    for ln in text.splitlines() if MOSAIC_TARGET in ln)),
+                hlo_sha256=sha, **meta)
             return True
         except Exception as e:  # noqa: BLE001 — record the rejection
             row(name, ok=False, compile_s=round(time.time() - t0, 1),
@@ -395,38 +411,64 @@ def _child():
         lanes=Rl, chunk=Ck, heads=Hh, head_dim=Dd, pages=Pp,
         page_size=psz, max_pages=maxp)
 
-    def ragged_step_program():
+    def ragged_step_program(cfg, lanes, chunk, pages, page_size, table):
+        """A GPT's ragged step as the engine builds it (zeros in the
+        scope: only shapes and types reach the compiler)."""
         import paddle_tpu as fluid
         from paddle_tpu.generation.model import (
             CacheGeometry, build_lm_program, build_ragged_step_program)
-        from paddle_tpu.models.gpt import GPTConfig
 
-        cfg = GPTConfig.gpt3_1p3b()
-        cfg.num_layers = 2
-        geom = CacheGeometry(num_pages=Pp, page_size=psz,
-                             max_pages_per_seq=maxp)
-        _main, startup, _f, _o = build_lm_program(cfg, 32)
-        prog, fetches = build_ragged_step_program(cfg, geom, Ck)
-        feed = {"gen_tokens": np.zeros((Rl, Ck), np.int64),
-                "gen_pos_ids": np.zeros((Rl, Ck), np.int64),
-                "gen_positions": np.zeros(Rl, np.int64),
-                "gen_num_valid": np.zeros(Rl, np.int32),
-                "gen_block_tables": np.zeros((Rl, maxp), np.int32)}
+        geom = CacheGeometry(num_pages=pages, page_size=page_size,
+                             max_pages_per_seq=table)
+        main, _startup, _f, _o = build_lm_program(cfg, 32)
+        prog, fetches = build_ragged_step_program(cfg, geom, chunk)
+        feed = {"gen_tokens": np.zeros((lanes, chunk), np.int64),
+                "gen_pos_ids": np.zeros((lanes, chunk), np.int64),
+                "gen_positions": np.zeros(lanes, np.int64),
+                "gen_num_valid": np.zeros(lanes, np.int32),
+                "gen_block_tables": np.zeros((lanes, table), np.int32)}
         scope = fluid.Scope()
+        for v in main.global_block().all_parameters():
+            scope.set_var(v.name, np.zeros(v.shape, np.float32))
         # the page pools are state of the step, donated and rewritten
         # in place: they come from the scope like the weights
         for li in range(cfg.num_layers):
             for kv in "kv":
-                scope.set_var(f"gen_{kv}_pages_{li}",
-                              np.zeros((Hh, Pp, psz, Dd), np.float32))
+                scope.set_var(f"gen_{kv}_pages_{li}", np.zeros(
+                    (cfg.num_heads, pages, page_size,
+                     cfg.hidden_size // cfg.num_heads), np.float32))
         with fluid.scope_guard(scope):
             exe = fluid.Executor(fluid.TPUPlace())
-            exe.run(startup)
             return exe.aot_compile(prog, feed, fetches, scope=scope,
                                    devices=[dev])
 
-    record("chip_smoke_ragged_step_program_gpt3xl_2layer",
-           ragged_step_program, group="chip_smoke")
+    def smoke_step():
+        from paddle_tpu.models.gpt import GPTConfig
+
+        cfg = GPTConfig.gpt3_1p3b()
+        cfg.num_layers = 2
+        return ragged_step_program(cfg, Rl, Ck, Pp, psz, maxp)
+
+    record("chip_smoke_ragged_step_program_gpt3xl_2layer", smoke_step,
+           group="chip_smoke")
+
+    # the two GPT cells' own step (PT_AOT_ONLY=gpt_serve): gpt3_xl_serve
+    # as benchmark/ runs it, all 24 layers. A PR that should not reach the
+    # dense cells shows this row's and the headline's hlo_sha256 equal to
+    # the parent's (PT_AOT_PARENT).
+    def gpt_serve_step():
+        bench = os.path.join(HERE, "benchmark")
+        with open(os.path.join(bench, "configs", "gpt3_xl_serve.json")) as f:
+            cfg = json.load(f)
+        eng = cfg["engine"]
+        return ragged_step_program(
+            _load(os.path.join(bench, "models", "gpt_program.py"))
+            .gpt_config(cfg), eng["lanes"], eng["chunk_tokens"],
+            eng["num_pages"], eng["page_size"],
+            cfg["max_position"] // eng["page_size"])
+
+    record("gpt3_xl_serve_step_program_24layer", gpt_serve_step,
+           group="gpt_serve")
 
     # -- the hybrid serving cell's step program at its own shapes
     # (PT_AOT_ONLY=hybrid): granite4_h_small_serve as benchmark/ runs it:
@@ -547,6 +589,49 @@ def _child():
          jax.ShapeDtypeStruct((32, 128), f32)),
         group="hybrid", lanes=32, heads=128, head_dim=64, state=128,
         chunk=16)
+
+    # -- the expert kernel at both cells' shapes (PT_AOT_ONLY=moe_ffn; it
+    # also answers hybrid / mimo): 512 window rows, the held experts as
+    # stored. The compiled module must hold the weights once: arguments
+    # are the stated bytes, and no copy, transpose or other op yields an
+    # array of a weight's size (the kernel reads them where they are).
+    def moe_ffn_row(name, group, held, d, f, top_k):
+        T = 512
+        pairs = T * top_k
+        args = (jax.ShapeDtypeStruct((T, d), f32),
+                jax.ShapeDtypeStruct((held, d, 2 * f), bf),
+                jax.ShapeDtypeStruct((held, f, d), bf),
+                jax.ShapeDtypeStruct((pairs,), jnp.int32),
+                jax.ShapeDtypeStruct((pairs,), f32),
+                jax.ShapeDtypeStruct((held,), jnp.int32))
+        stated = (T * d * 4 + held * 3 * d * f * 2 + pairs * 8 + held * 4)
+
+        def compile_fn():
+            from paddle_tpu.kernels import moe_ffn
+
+            assert moe_ffn.fits(T, d, f, bf)
+            compiled = jax.jit(moe_ffn.grouped_ffn,
+                               in_shardings=(R,) * 6).lower(*args).compile()
+            text = compiled.as_text()
+            assert "moe_grouped_ffn" in text
+            for shape in (f"bf16[{held},{d},{2 * f}]",
+                          f"bf16[{held},{f},{d}]"):
+                made = [ln for ln in text.splitlines()
+                        if f" = {shape}" in ln and " parameter(" not in ln]
+                assert not made, f"a second {shape}: {made[0][:200]}"
+            ma = compiled.memory_analysis()
+            # (the small arrays are padded to whole tiles: 4 KiB of room)
+            assert 0 <= ma.argument_size_in_bytes - stated < 4096, (
+                ma.argument_size_in_bytes, stated)
+            assert ma.temp_size_in_bytes < 64 * 1024 * 1024, \
+                ma.temp_size_in_bytes
+            return compiled
+        return record(name, compile_fn, group, held=held, d=d, f=f,
+                      top_k=top_k, stated_arg_bytes=stated)
+
+    moe_ffn_row("moe_ffn_hybrid_36x4096x768_top10", "hybrid", 36, 4096, 768,
+                10)
+    moe_ffn_row("moe_ffn_mimo_16x4096x2048_top8", "mimo", 16, 4096, 2048, 8)
 
     # -- the headline: the train cell's own step at its REAL shapes ----
     # params + adam state from the startup program, full fwd+bwd+
@@ -798,6 +883,19 @@ def _child():
            lambda m: fluid.CompiledProgram(m).with_partitioning(
                pt.PartitionConfig(mesh_axes={"tp": 4})),
            (imain, istart, if_["logits"]), ifeed, mesh="tp4")
+
+    # PT_AOT_PARENT=<the AOT_TPU_CHECK.json this tool wrote in the parent's
+    # tree, when that lay at this path>: each row says whether its
+    # optimized HLO is the parent's
+    parent = os.environ.get("PT_AOT_PARENT")
+    if parent:
+        with open(parent) as f:
+            theirs = {r["name"]: r.get("hlo_sha256")
+                      for r in json.load(f)["rows"]}
+        for r in results["rows"]:
+            if r.get("hlo_sha256") and theirs.get(r["name"]):
+                r["hlo_equals_parent"] = \
+                    r["hlo_sha256"] == theirs[r["name"]]
 
     # merge-by-name into the existing archive: different env
     # selections (kernels-only / stages / multichip) must accumulate,
