@@ -421,6 +421,9 @@ class GenerationMetrics:
                  # with window layers: the pages that overlap the window)
                  "attn_live_pages_full_total",
                  "attn_live_pages_window_total",
+                 # of attn_live_pages_total, the pages of lanes that take
+                 # the kernel's lean decode branch (their rows alone)
+                 "attn_lean_pages_total",
                  # recurrent layers and experts in the step (a hybrid
                  # config): valid tokens x expert layers, and lanes that
                  # took a new sequence (its state restarts from zero in
@@ -719,6 +722,7 @@ class GenerationEngine:
                      else build_ragged_step_program)
             self._ragged_prog, self._ragged_fetches = build(
                 config, self.geom, self.chunk_tokens, self.kv_dtype)
+            self._lean_tokens = self._lean_decode_tokens()
         else:
             self._decode_prog, self._decode_fetches = build_decode_program(
                 config, self.geom)
@@ -1566,6 +1570,24 @@ class GenerationEngine:
             self._moe_kernel_layers = self._count_moe_kernel_layers()
         return self._ragged_bound
 
+    def _lean_decode_tokens(self) -> int:
+        """The most valid tokens of a lane that takes the attention
+        kernel's lean branch in the step's full-attention layers (its
+        rows, ``num_valid`` x the query group, fit ``lean_decode_rows``),
+        or 0 where the step's kernel has no such branch."""
+        from ..kernels.ragged_paged_attention import lean_decode_rows
+
+        block = self._ragged_prog.global_block()
+        for op in block.ops:
+            if (op.type == "ragged_paged_attention"
+                    and op.attr("lean_decode") and not op.attr("window")):
+                kv_heads = block.var(op.input("KPages")[0]).shape[0]
+                group = int(op.attr("num_heads")) // int(kv_heads)
+                return lean_decode_rows(
+                    group, self.chunk_tokens,
+                    bool(op.attr("stored_products"))) // group
+        return 0
+
     def _count_moe_kernel_layers(self) -> int:
         """The expert layers of the step that run kernels/moe_ffn.py and
         not the ``ragged_dot`` path: what ``topk_moe`` decides when the
@@ -1829,8 +1851,11 @@ class GenerationEngine:
             num_valid[slot] = 1 + dr.size
             rows.append((slot, req, 1 + int(dr.size)))
         live = positions + num_valid
-        walked = int((-(-live[num_valid > 0] // self.geom.page_size)).sum())
+        npages = -(-live // self.geom.page_size)
+        walked = int(npages[num_valid > 0].sum())
         self.metrics.inc("attn_live_pages_total", walked)
+        self.metrics.inc("attn_lean_pages_total", int(npages[
+            (num_valid > 0) & (num_valid <= self._lean_tokens)].sum()))
         self.metrics.inc("attn_table_pages_total",
                          R * self.geom.max_pages_per_seq)
         feed = {

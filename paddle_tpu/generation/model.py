@@ -326,7 +326,8 @@ def build_hybrid_step_program(cfg: HybridConfig, geom: CacheGeometry,
       ``[num_kv_heads, pages, page_size, cfg.head_dim]``, rewritten in
       place as state like the dense step's: grouped queries, the
       kernel's own 1/sqrt(head_dim) with the attention multiplier folded
-      into q, the keys written rotated and times the key multiplier;
+      into q, the keys written rotated and times the key multiplier; a
+      decode lane multiplies its one token's rows only (``lean_decode``);
     * the per-lane recurrent state of ``cfg.state_shapes(lanes)`` fed as
       ``gen_state_*`` and fetched back, rewritten whole: a row advances
       its Mamba layers by its valid tokens, and a row at position 0
@@ -365,7 +366,8 @@ def build_hybrid_step_program(cfg: HybridConfig, geom: CacheGeometry,
             kv_cache_write_layer(kp, vp, k, v, tables, positions,
                                  num_valid, cfg.num_kv_heads)
             return ragged_paged_attention_layer(
-                q, kp, vp, tables, positions, num_valid, cfg.num_heads)
+                q, kp, vp, tables, positions, num_valid, cfg.num_heads,
+                lean_decode=True)
 
         # no speculative rows here, so the engine reads one token a row,
         # the one after its last valid position: the head runs on that

@@ -71,7 +71,13 @@ the TPU default, which rounds float32 operands to bf16 for one pass.
 The first chip run measured that default at 2.5e-3 relative against a
 "highest"-precision reference; chip_smoke.py's kernel phase holds this
 kernel to the float32 bound (2e-5), so a bf16 rounding anywhere in the
-attend path fails there.
+attend path fails there. Over bfloat16 pages the keys and values are
+exact bfloat16, so three of HIGHEST's six passes (those with their
+lower parts) are zero: the kernel cuts q and the probabilities into
+three bfloat16 parts (hi + mid + lo = the float32 value), stacks them
+as rows and multiplies them in ONE pass over the stored keys / values
+with float32 accumulation, then sums the three row blocks: the same
+terms as HIGHEST, a sixth of its weight loads into the MXU.
 """
 
 from __future__ import annotations
@@ -225,11 +231,21 @@ def _block_scales(scales, page_indices, npages, bp: int):
         scales[:, pages].reshape(KVH, B, blocks, bp * ps), (1, 2, 0, 3))
 
 
+def lean_decode_rows(group: int, chunk: int, stored_products: bool) -> int:
+    """The rows a lane multiplies on the ``lean_decode`` branch: its
+    first token's ``group`` rows, to whole sublane tiles of the rows as
+    the kernel cuts them (8 float32 rows; 16 packed bfloat16 ones where
+    the products round to bfloat16), at most the chunk's. A lane takes
+    the branch when ``num_valid * group`` fits."""
+    tile = 16 if stored_products else 8
+    return min(-(-group // tile) * tile, group * -(-chunk // 8) * 8)
+
+
 def _make_ragged_kernel(C: int, group: int, ps: int, bp: int,
                         sm_scale: float, quantized: bool, window=None,
                         ring: int = 0, sinks: bool = False, d_lo: int = 0,
                         d_hi: int = 0, stored_products: bool = False,
-                        lean_rows: int = 0):
+                        lean_rows: int = 0, parts: bool = False):
     """One grid step = one lane, every head: walk the lane's live keys
     in blocks of ``bp`` pages. ``C`` is the padded chunk; a KV head's
     ``group`` query heads ride as group * C rows of one matmul.
@@ -241,7 +257,10 @@ def _make_ragged_kernel(C: int, group: int, ps: int, bp: int,
     default is a float32 contraction. ``lean_rows``: the
     rows are chunk-major (row j * group + g), so a lane's valid rows
     come first, and a lane whose valid rows fit the first ``lean_rows``
-    (a decode row: one token of ``group`` heads) multiplies only those."""
+    (a decode row: one token of ``group`` heads) multiplies only those.
+    ``parts``: the pages are bfloat16 and the products float32: q and
+    the probabilities go in as three bfloat16 parts stacked as rows,
+    one MXU pass over the stored keys and values (module docstring)."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -330,9 +349,10 @@ def _make_ragged_kernel(C: int, group: int, ps: int, bp: int,
         def positions(n):
             """(key index, query position) [n, bk] of the first n rows."""
             if lean_rows:
-                # row j * group + g is query j of the g-th query head
-                qpos = start + jax.lax.broadcasted_iota(
-                    jnp.int32, (n // group, group, bk), 0).reshape(n, bk)
+                # row j * group + g is query j of the g-th query head,
+                # for any group (a lean tile may end inside a query)
+                qpos = start + jax.lax.div(jax.lax.broadcasted_iota(
+                    jnp.int32, (n, bk), 0), group)
             else:
                 # row g * C + j of a KV head is query j of its g-th head
                 qpos = start + jax.lax.broadcasted_iota(
@@ -345,6 +365,20 @@ def _make_ragged_kernel(C: int, group: int, ps: int, bp: int,
         # once a grid step, outside the walk's loop
         where = {n: positions(n)
                  for n in dict.fromkeys((group * C, lean_rows)) if n}
+
+        def split3(x):
+            """float32 [KVH, n, k] -> [KVH, 3n, k] bfloat16: three parts,
+            stacked as rows, whose sum is x"""
+            hi = x.astype(jnp.bfloat16).astype(jnp.float32)
+            mid = (x - hi).astype(jnp.bfloat16).astype(jnp.float32)
+            return jnp.concatenate([hi, mid, x - hi - mid], axis=1).astype(
+                jnp.bfloat16)
+
+        def fold(y, n):
+            """[KVH, 3n, k] products of the three parts -> their sum"""
+            return y[:, :n] + y[:, n:2 * n] + y[:, 2 * n:]
+
+        q_parts = {n: split3(q[:, :n]) for n in where} if parts else {}
 
         def scores(q, kb):
             """q . k^T over one block's K pages [KVH, bp, rows, width]."""
@@ -372,8 +406,15 @@ def _make_ragged_kernel(C: int, group: int, ps: int, bp: int,
             (all of them, or a decode lane's ``lean_rows``)."""
             top = slice(None) if n == group * C else slice(0, n)
             # (rows are cut in float32: whole 8-row tiles)
-            s = scores(q[:, top].astype(operand), bufs[0][slot])
-            v = bufs[1][slot].astype(operand).reshape(kvh, bk, -1)
+            if parts:
+                s = fold(jax.lax.dot_general(
+                    q_parts[n], bufs[0][slot].reshape(kvh, bk, -1),
+                    (((2,), (2,)), ((0,), (0,))),
+                    preferred_element_type=jnp.float32), n)
+                v = bufs[1][slot].reshape(kvh, bk, -1)
+            else:
+                s = scores(q[:, top].astype(operand), bufs[0][slot])
+                v = bufs[1][slot].astype(operand).reshape(kvh, bk, -1)
             if quantized:
                 # int8 pages: q.(k * scale) = (q.k) * scale, a key's
                 # scale applied where keys lie on the lane axis
@@ -393,9 +434,17 @@ def _make_ragged_kernel(C: int, group: int, ps: int, bp: int,
             m_ref[:, top] = jnp.broadcast_to(m_next, (kvh, n, LANES))
             if quantized:
                 pexp = pexp * vs_ref[0, i][:, None, :]
-            acc_ref[:, top] = acc_ref[:, top] * alpha + jax.lax.dot_general(
-                pexp.astype(operand), v, (((2,), (1,)), ((0,), (0,))),
-                precision=precision, preferred_element_type=jnp.float32)
+            if parts:
+                acc_ref[:, top] = acc_ref[:, top] * alpha + fold(
+                    jax.lax.dot_general(
+                        split3(pexp), v, (((2,), (1,)), ((0,), (0,))),
+                        preferred_element_type=jnp.float32), n)
+            else:
+                acc_ref[:, top] = acc_ref[:, top] * alpha + (
+                    jax.lax.dot_general(
+                        pexp.astype(operand), v, (((2,), (1,)), ((0,), (0,))),
+                        precision=precision,
+                        preferred_element_type=jnp.float32))
 
         def block(i, carry):
             slot = i % 2
@@ -454,8 +503,8 @@ def _ragged_pallas(q, k_pages, v_pages, start_pos, num_valid, page_indices,
     D = -(-D0 // LANES) * LANES
     group = H // KVH
     rows = group * Cp          # query head h = kv head h // group
-    # a decode lane's rows, to whole packed sublane tiles of 16
-    lean_rows = min(-(-group // 16) * 16, rows) if lean_decode else 0
+    lean_rows = (lean_decode_rows(group, C, stored_products) if lean_decode
+                 else 0)
     if lean_rows:
         # chunk-major rows: row j * group + g of a KV head
         qt = jnp.transpose(
@@ -507,7 +556,9 @@ def _ragged_pallas(q, k_pages, v_pages, start_pos, num_valid, page_indices,
     kernel = _make_ragged_kernel(
         Cp, group, ps, bp, sm_scale, quantized, window=window,
         ring=page_indices.shape[1], sinks=sink is not None, d_lo=d_lo,
-        d_hi=d_hi, stored_products=stored_products, lean_rows=lean_rows)
+        d_hi=d_hi, stored_products=stored_products, lean_rows=lean_rows,
+        parts=(not (stored_products or quantized or d_hi)
+               and k_pages.dtype == v_pages.dtype == jnp.bfloat16))
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
@@ -559,7 +610,8 @@ def ragged_paged_attention(q, k_pages, v_pages, start_pos, num_valid,
                   (one MXU pass; exact for bfloat16 pages) instead of a
                   float32 contraction
     name:         the kernel's name in a device trace
-    lean_decode:  a lane with one valid token multiplies that token's
+    lean_decode:  a lane whose valid rows fit ``lean_decode_rows`` (a
+                  decode lane: one token's query heads) multiplies those
                   rows only, not the whole chunk's (the kernel orders
                   its rows chunk-major for it)
 

@@ -232,6 +232,14 @@ def test_running_ahead_serves_the_serial_orders_tokens(case, predictor,
     assert ahead["cache"]["pages_in_use"] == ahead["radix"]["trie_pages"]
 
 
+def test_the_dense_step_counts_no_lean_pages(predictor):
+    """The GPT step's attention has no lean decode branch (one query
+    head a KV head: a lane's 16 rows are its whole chunk)."""
+    _got, st = _plain(predictor, True)
+    assert st["attn_live_pages_total"] > 0
+    assert st["attn_lean_pages_total"] == 0
+
+
 def _recurrent_state(hybrid, pred, ahead):
     """7 requests over 3 lanes: a lane is reused two steps after its
     predecessor's last token was dispatched, and starts from a reset

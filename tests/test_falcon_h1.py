@@ -356,6 +356,39 @@ def test_engine_pools_take_the_configs_head_dim(predictor):
             _greedy_by_reference(np.arange(1, 9), 2)
 
 
+def test_engine_counts_the_pages_of_lean_lanes(predictor):
+    """``attn_lean_pages_total`` is the pages walked by the lanes whose
+    rows (valid tokens x the query group, 8 / 2 = 4) fit the kernel's
+    lean rows, counted over every step the engine assembled: decode
+    lanes and the short last chunks of prompts, not the full chunks."""
+    from paddle_tpu.kernels.ragged_paged_attention import lean_decode_rows
+
+    group = HCFG.num_heads // HCFG.num_kv_heads
+    rows = lean_decode_rows(group, 4, stored_products=False)
+    feeds = []
+    eng = _engine(predictor, start=False)
+    assemble = eng._assemble
+
+    def seen(lanes):
+        feed, rows_, carry = assemble(lanes)
+        feeds.append((feed["gen_positions"].copy(),
+                      feed["gen_num_valid"].copy()))
+        return feed, rows_, carry
+
+    eng._assemble = seen
+    rng = np.random.RandomState(5)
+    with eng.start():
+        for s in [eng.submit(rng.randint(1, V, n).astype(np.int64),
+                             max_new_tokens=5) for n in (6, 13, 9, 17)]:
+            s.result(timeout=300)
+    st = eng.stats()
+    want = sum(int(-(-(p + n) // 8)) for pos, nv in feeds
+               for p, n in zip(pos, nv) if 0 < n * group <= rows)
+    assert eng._lean_tokens == 2
+    assert st["attn_lean_pages_total"] == want
+    assert 0 < want < st["attn_live_pages_total"]
+
+
 @pytest.mark.parametrize("kwargs,needs", [
     (dict(prefix_cache=True), "snapshots"),
     (dict(spec_tokens=2, draft=object()), "rollback"),
