@@ -100,7 +100,9 @@ does what modern LLM serving does instead:
   positions, tables, and a hybrid model's recurrent state), ``bind``,
   ``step`` (the token merge and the dispatch of the new step with the
   pools donated, under the cache's pool lock, then the wait for the
-  tokens of the step before it — ``generation/fetch``) and ``emit``
+  tokens of the step before it — ``generation/fetch``, which holds
+  ``generation/fetch_ready``, the wait for that step to be reported
+  done, before the copy) and ``emit``
   (of that earlier step: advance, stop conditions, the clients'
   ``on_token`` callbacks, trie publish). What a step was fed is
   released at its dispatch. The last step of a burst is read by an
@@ -1944,11 +1946,16 @@ class GenerationEngine:
         self._recover_pools()
 
     def _fetch(self, step: _StepInFlight):
-        """Wait for a dispatched step's tokens ``[lanes, chunk]``; None
-        when the step failed on the device (its sequences are failed
-        here)."""
+        """Wait for a dispatched step's tokens ``[lanes, chunk]``: for
+        the step to be reported done (``generation/fetch_ready``), then
+        for the copy; None when the step failed on the device (its
+        sequences are failed here)."""
+        import jax
+
         try:
             with tracing.annotation("generation/fetch"):
+                with tracing.annotation("generation/fetch_ready"):
+                    jax.block_until_ready(step.tokens)
                 return np.asarray(step.tokens).reshape(
                     self.lanes, self.chunk_tokens)
         except Exception as e:  # noqa: BLE001 — a bad batch must not kill the loop

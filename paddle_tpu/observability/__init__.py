@@ -19,7 +19,23 @@ needs from one place:
   across threads (serving request -> micro-batch -> worker -> jit
   step; supervisor step -> retry/rollback), ``flow_from`` arrows
   rendered by ``tools_timeline``, and the flight-ring copy of every
-  span.
+  span. The host's stalls are ranges too: ``executor/fetch_ready`` and
+  ``generation/fetch_ready`` (the wait for a finished step inside the
+  fetch), ``executor/writeback`` (the written state stored into the
+  scope), ``executor/compile`` (a first call, its tag in ``args``) and
+  ``executor/gc`` (below).
+* **the cyclic collector** — importing this package installs one
+  ``gc.callbacks`` hook a process (``tracing.install_gc_hook``,
+  idempotent). A collection of generation 1 or 2 is the range
+  ``executor/gc`` on the thread that ran it, with the generation in
+  ``args``; every collection, generation 0 too, counts into
+  ``paddle_gc_collections_total{generation}`` and
+  ``paddle_gc_pause_seconds_total{generation}`` (``snapshot()``,
+  ``/metrics``). No threshold is changed and nothing is frozen. The
+  benchmark's trace reduction keeps host ranges whose names start
+  ``bench/``, ``executor/`` or ``generation/``, so the collector's range
+  carries the runtime's prefix, and an idle gap of the device under it
+  is read as the collector's.
 * ``flight`` — an always-on constant-memory flight recorder dumped to
   JSON on NaN rollback, watchdog hang, uncaught loop exception,
   SIGTERM and SIGUSR2.
@@ -57,6 +73,8 @@ from .registry import (Counter, Gauge, Histogram, MetricsRegistry,
                        watch_supervisor, watch_traffic)
 from .registry import registry as get_registry
 from .tracing import SpanContext, attach, current, span, traced
+
+tracing.install_gc_hook()
 
 __all__ = [
     "registry", "tracing", "flight", "propagate", "fleet",

@@ -35,11 +35,27 @@ assembled across threads and processes) — ``executor/step`` and
 ``generation/step`` do, as before; the other phases of the two loops are
 ``annotation``s, which stay on the off path, so the flag still costs one
 ring entry a step.
+
+The host's stalls have ranges of their own on the same off path:
+``executor/fetch_ready`` and ``generation/fetch_ready`` (the wait for a
+finished step inside ``executor/fetch`` / ``generation/fetch``; the
+rest of the outer range is the copy to the host),
+``executor/writeback`` (the written state stored into the scope) and
+``executor/compile`` (a first call; the tag in ``args``). The cyclic
+collector gets one ``gc.callbacks`` hook a process (``install_gc_hook``,
+called by the package): a collection of generation 1 or 2 is the range
+``executor/gc`` on the thread that ran it (the generation in ``args``),
+and every collection, generation 0 too, adds to the registry's
+``paddle_gc_collections_total{generation}`` and
+``paddle_gc_pause_seconds_total{generation}``. The names carry the
+runtime's ``executor/`` prefix because a profiler reader keeps host
+ranges by prefix (``bench/``, ``executor/``, ``generation/``).
 """
 
 from __future__ import annotations
 
 import functools
+import gc
 import os
 import threading
 import time
@@ -49,10 +65,10 @@ from jax.profiler import TraceAnnotation as _TraceAnnotation
 
 from .. import profiler
 from ..flags import _flags  # hot path: direct flag-store reads
-from . import flight
+from . import flight, registry
 
 __all__ = ["SpanContext", "span", "annotation", "traced", "attach", "current",
-           "enabled"]
+           "enabled", "install_gc_hook"]
 
 
 class SpanContext(NamedTuple):
@@ -201,13 +217,14 @@ def span(name: str,
     return _Span(name, args() if callable(args) else args, parent)
 
 
-def annotation(name: str) -> _Annotation:
+def annotation(name: str, args=None) -> _Annotation:
     """The off path of ``span`` whatever the flag says: for the phases
     of a hot loop, whose place is the timeline beside the device trace,
     not a request's trace. Were they full spans, ``observability_tracing``
     would put a dozen flight-ring entries a step where one was, and a
-    request's submit span would leave the ring within seconds."""
-    return _Annotation(name, None)
+    request's submit span would leave the ring within seconds. ``args``
+    as for ``span``: kept only inside a ``paddle_tpu.profiler`` session."""
+    return _Annotation(name, args)
 
 
 class _Attach:
@@ -249,3 +266,54 @@ def traced(name: Optional[str] = None, args: Optional[Dict[str, Any]] = None):
         return wrapper
 
     return deco
+
+
+# -- the cyclic collector ----------------------------------------------------
+
+# the hook's state in preallocated slots: a generation-0 callback runs
+# every ~700 allocations, so it reads the clock, adds to two lists and
+# allocates no container (a collection is never nested, and start and
+# stop come on the thread that collects)
+_gc_t0 = [0.0]
+_gc_open = [None]
+_gc_collections = [0, 0, 0]
+_gc_pause_s = [0.0, 0.0, 0.0]
+_perf_counter = time.perf_counter
+
+
+def _on_gc(phase: str, info) -> None:
+    gen = info["generation"]
+    if phase == "start":
+        if gen:
+            ann = annotation("executor/gc", {"generation": gen})
+            ann.__enter__()
+            _gc_open[0] = ann
+        _gc_t0[0] = _perf_counter()
+        return
+    t0 = _gc_t0[0]
+    if t0:
+        _gc_pause_s[gen] += _perf_counter() - t0
+        _gc_collections[gen] += 1
+        _gc_t0[0] = 0.0
+    ann = _gc_open[0]
+    if ann is not None:
+        _gc_open[0] = None
+        ann.__exit__(None, None, None)
+
+
+def _collect_gc():
+    return {
+        "paddle_gc_collections_total": [
+            ({"generation": str(g)}, n) for g, n in enumerate(_gc_collections)],
+        "paddle_gc_pause_seconds_total": [
+            ({"generation": str(g)}, s) for g, s in enumerate(_gc_pause_s)],
+    }
+
+
+def install_gc_hook() -> None:
+    """Put the cyclic collector on the profiler's clock and in the
+    registry; idempotent (one callback a process however often it is
+    called). Changes no threshold and freezes nothing."""
+    if _on_gc not in gc.callbacks:
+        gc.callbacks.append(_on_gc)
+    registry.registry().register_collector("gc", _collect_gc)

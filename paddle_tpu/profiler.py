@@ -59,7 +59,10 @@ def profiler(state="All", sorted_key=None, profile_path="/tmp/profile"):
 # mutation and snapshot goes through one module lock. The lock guards
 # the LISTS only; `_recording` stays a plain bool (a racy read at worst
 # drops the first/last event of a session, never corrupts state).
-_events_lock = threading.Lock()
+# Re-entrant: the cyclic collector's hook (observability.tracing) logs
+# its range from inside a collection, which an allocation made under
+# this lock can start on the same thread.
+_events_lock = threading.RLock()
 _host_events: list = []
 _recording = False
 # a session left recording for hours must stay constant-memory (the
@@ -179,10 +182,10 @@ def host_events():
 
 # compile-event history (runtime/dispatch._first_call): kept
 # unconditionally — knowing WHEN each executable was built matters for
-# post-hoc TPU-window accounting — and mirrored into the host-event
-# log when a profiling session is active so compiles show as named
-# ranges in tools/timeline.py traces. Ring-capped: use_program_cache=
-# False loops compile every step, which must not grow memory forever.
+# post-hoc TPU-window accounting. The compile's range in a profiling
+# session is the caller's span (``executor/compile``), not an entry
+# here. Ring-capped: use_program_cache=False loops compile every step,
+# which must not grow memory forever.
 _compile_events: list = []
 _COMPILE_EVENTS_CAP = 1000
 
@@ -198,8 +201,6 @@ def record_compile(name: str, dur: float):
         _compile_events.append(ev)
         if len(_compile_events) > _COMPILE_EVENTS_CAP:
             del _compile_events[:_COMPILE_EVENTS_CAP // 2]
-        if _recording:
-            _append_host_event(ev)
     # observability: compiles count in the unified registry and land in
     # the crash-time flight ring (lazy import: observability imports us)
     from .observability import flight, registry

@@ -218,9 +218,17 @@ def test_executor_run_spans_without_the_flag():
         with profiler.host_trace():
             exe.run(main, feed=feed, fetch_list=[out])
             seen = [e for e in profiler.host_events()
-                    if e["name"].startswith("executor/")]
+                    if e["name"].startswith("executor/")
+                    and e["name"] != "executor/gc"]
     assert threading.get_ident() == tid
+    # a range is logged when it closes: the wait for the finished step
+    # inside the fetch closes first
     assert [e["name"] for e in seen] == [
-        "executor/bind", "executor/feed", "executor/step", "executor/fetch"]
+        "executor/bind", "executor/feed", "executor/step",
+        "executor/fetch_ready", "executor/fetch"]
+    ready = seen.pop(3)
     for a, b in zip(seen, seen[1:]):
         assert a["ts"] + a["dur"] <= b["ts"] + 1e-6
+    fetch = seen[-1]
+    assert fetch["ts"] - 1e-6 <= ready["ts"]
+    assert ready["ts"] + ready["dur"] <= fetch["ts"] + fetch["dur"] + 1e-6
