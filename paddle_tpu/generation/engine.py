@@ -433,6 +433,11 @@ class GenerationMetrics:
                  # counted on the device (stats(): moe_held_assignments_
                  # total, moe_expert_load_max / _mean)
                  "moe_tokens_routed_total", "state_lane_resets_total",
+                 # the dense feed-forwards (ops/decoder.py gated_silu_ffn):
+                 # valid tokens a step, and the steps that had few enough
+                 # of them for the bound step's layers that multiply the
+                 # live rows alone (stats(): ffn_narrow_layers)
+                 "ffn_live_rows_total", "ffn_narrow_steps_total",
                  # ragged mode, one step ahead: steps dispatched before
                  # their predecessor's tokens were read (over
                  # ragged_steps_total: the share the overlap engaged
@@ -714,6 +719,7 @@ class GenerationEngine:
 
         self._ragged_bound = None       # resolved on the first step
         self._moe_kernel_layers = 0     # counted when it is
+        self._ffn_narrow = (0, 0)       # (layers, live rows), likewise
         self._decode_bound = None       # two_lane: first decode step
         self._prefill_progs: Dict[int, Any] = {}    # seq bucket -> (prog, fetches)
         if self.mode == "ragged":
@@ -1086,6 +1092,7 @@ class GenerationEngine:
             out["moe_expert_load_max"] = int(loads.max())
             out["moe_expert_load_mean"] = float(loads.mean())
             out["moe_kernel_layers"] = self._moe_kernel_layers
+        out["ffn_narrow_layers"] = self._ffn_narrow[0]
         # pages by kind of attention layer (window: 0 without any)
         out["kv_pages_resident_full"] = out["cache"]["pages_resident_full"]
         out["kv_pages_resident_window"] = \
@@ -1570,6 +1577,7 @@ class GenerationEngine:
                 self._ragged_prog, feed, self._ragged_fetches,
                 scope=self._step_scope, tag="generation/ragged_step")
             self._moe_kernel_layers = self._count_moe_kernel_layers()
+            self._ffn_narrow = self._count_ffn_narrow_layers()
         return self._ragged_bound
 
     def _lean_decode_tokens(self) -> int:
@@ -1605,6 +1613,24 @@ class GenerationEngine:
                 n += moe_ffn.fits(self.lanes * self.chunk_tokens, d, f,
                                   w_out.dtype)
         return n
+
+    def _count_ffn_narrow_layers(self):
+        """(layers, R): the dense feed-forwards of the step that multiply
+        a window's live rows alone when at most R are valid, asked of the
+        same ``live_ffn_rows`` that ``gated_silu_ffn`` asks when the step
+        is traced, with the weights as the scope holds them."""
+        from ..ops import decoder
+
+        n = live = 0
+        for op in self._ragged_prog.global_block().ops:
+            if op.type == "gated_silu_ffn" and op.input("NumValid"):
+                w_out = self._step_scope.find_var(op.input("WOut")[0])
+                f, d = w_out.shape
+                r = decoder.live_ffn_rows(self.lanes * self.chunk_tokens,
+                                          d, f, np.dtype(w_out.dtype).itemsize)
+                n += r > 0
+                live = max(live, r)
+        return n, live
 
     def _dispatch(self, bound, feed):
         """Dispatch one step that is donated the page pools: under the
@@ -1982,6 +2008,10 @@ class GenerationEngine:
             return
         now = time.monotonic()
         self.metrics.inc("ragged_steps_total")
+        live = sum(nv for _slot, _req, nv in step.rows)
+        self.metrics.inc("ffn_live_rows_total", live)
+        if self._ffn_narrow[0] and live <= self._ffn_narrow[1]:
+            self.metrics.inc("ffn_narrow_steps_total")
         emitted_total = n_active = 0
         for slot, req, nv in step.rows:
             n_active += 1

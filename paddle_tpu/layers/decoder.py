@@ -62,11 +62,15 @@ def linear(input, size=None, param_attr=None, dtype=None, transpose_w=False,
 
 
 def gated_ffn(input, size, param_attr=None, dtype=None, name=None,
-              multipliers=(1.0, 1.0)):
+              multipliers=(1.0, 1.0), num_valid=None):
     """Gated-SiLU feed-forward of width ``size``: ``(silu(a1 * m_gate) *
     a2) W_out * m_down`` with ``(a1, a2) = split(x W_in)`` and
     ``multipliers = (m_gate, m_down)``, both applied in float32;
-    parameters ``<name>_in.w`` [d, 2 size] and ``<name>_out.w`` [size, d]."""
+    parameters ``<name>_in.w`` [d, 2 size] and ``<name>_out.w`` [size, d].
+    ``num_valid`` [rows] over ``input`` [rows, chunk, d]: the tokens of
+    each row that are real; where the weights outweigh the rows
+    (ops/decoder.py ``live_ffn_rows``), a window with few enough of them
+    multiplies those alone and its padding rows read 0."""
     helper = LayerHelper("gated_ffn", param_attr=param_attr, name=name)
     d, dt = int(input.shape[-1]), dtype or input.dtype
     w_in = helper.create_parameter(
@@ -77,8 +81,10 @@ def gated_ffn(input, size, param_attr=None, dtype=None, name=None,
         default_initializer=XavierInitializer())
     out = _out(helper, input, dtype="float32")
     gate, down = (float(m) for m in multipliers)
-    helper.append_op(type="gated_silu_ffn",
-                     inputs={"X": [input], "WIn": [w_in], "WOut": [w_out]},
+    inputs = {"X": [input], "WIn": [w_in], "WOut": [w_out]}
+    if num_valid is not None:
+        inputs["NumValid"] = [num_valid]
+    helper.append_op(type="gated_silu_ffn", inputs=inputs,
                      outputs={"Out": [out]},
                      attrs={} if (gate, down) == (1.0, 1.0) else
                      {"gate_multiplier": gate, "down_multiplier": down})

@@ -238,7 +238,7 @@ def hybrid_decoder(cfg: HybridConfig, tokens, attention: Callable,
         if ffn == "dense":
             h = residual(h, layers.gated_ffn(
                 u, cfg.dense_size, dtype=dt, multipliers=cfg.mlp_multipliers,
-                param_attr=_attr(pre + "ffn", std)))
+                num_valid=num_valid, param_attr=_attr(pre + "ffn", std)))
             continue
         loads = None
         if state is not None:
@@ -253,6 +253,7 @@ def hybrid_decoder(cfg: HybridConfig, tokens, attention: Callable,
             param_attr=_attr(pre, std), dtype=dt)
         loads_out.append(loads)
         shared = layers.gated_ffn(u, cfg.shared_size, dtype=dt,
+                                  num_valid=num_valid,
                                   param_attr=_attr(pre + "shared", std))
         h = residual(h, layers.elementwise_add(routed, shared))
     if loads_out:

@@ -149,6 +149,7 @@ def mimo_decoder(cfg: MiMoConfig, tokens, pos_ids, attention: Callable,
         u = layers.rms_norm(h, eps, param_attr=pre + "ln2.scale", dtype=dt)
         if ffn == "dense":
             y = layers.gated_ffn(u, cfg.dense_size, dtype=dt,
+                                 num_valid=num_valid,
                                  param_attr=_attr(pre + "ffn", std))
         else:
             j = len(loads_out)

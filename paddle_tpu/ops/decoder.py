@@ -32,16 +32,52 @@ def rms_norm(x, scale, eps):
     return x * jax.lax.rsqrt(var + eps) * scale.astype(jnp.float32)
 
 
+def live_ffn_rows(rows: int, d: int, f: int, itemsize: int) -> int:
+    """How many of a window's ``rows`` a gated feed-forward of width
+    ``f`` on ``d`` multiplies alone when no more are valid: half the
+    window, or 0 (every row, always) where the weights, 3 d f x
+    ``itemsize`` bytes, are under ten times the ~12 rows d bytes that
+    gathering the rows and putting them back moves in float32."""
+    return rows // 2 if 3 * f * itemsize >= 120 * rows else 0
+
+
 def gated_silu_ffn(x, w_in, w_out, gate_multiplier=None,
-                   down_multiplier=None):
+                   down_multiplier=None, num_valid=None, live_rows=0):
     """``(silu(a1) * a2) W_out`` with ``(a1, a2) = split(x W_in)``; the
     muP multipliers (falcon_h1), where given, scale a1 inside the SiLU
-    and the output, in float32."""
-    a1, a2 = jnp.split(dot_stored(x, w_in), 2, axis=-1)
-    if gate_multiplier is not None:
-        a1 = a1 * gate_multiplier
-    out = dot_stored(jax.nn.silu(a1) * a2, w_out)
-    return out if down_multiplier is None else out * down_multiplier
+    and the output, in float32.
+
+    With ``num_valid`` [rows] over x [rows, chunk, d] (the tokens of
+    each row that are real) and ``live_rows`` R > 0: a window whose
+    valid tokens number R or fewer gathers them into R rows, multiplies
+    those and puts them back, a padding row reading 0; a fuller window
+    multiplies every row. Each row's result is its own either way."""
+
+    def ffn(x):
+        a1, a2 = jnp.split(dot_stored(x, w_in), 2, axis=-1)
+        if gate_multiplier is not None:
+            a1 = a1 * gate_multiplier
+        out = dot_stored(jax.nn.silu(a1) * a2, w_out)
+        return out if down_multiplier is None else out * down_multiplier
+
+    if num_valid is None or not live_rows:
+        return ffn(x)
+    rows, chunk, d = x.shape
+    n = rows * chunk
+    valid = (jnp.arange(chunk, dtype=jnp.int32)[None, :]
+             < num_valid.astype(jnp.int32)[:, None]).reshape(n)
+    (idx,) = jnp.nonzero(valid, size=live_rows, fill_value=n)
+    # each valid row's place among the R, and past them (a zero) for
+    # padding
+    at = jnp.where(valid, jnp.cumsum(valid) - 1, live_rows)
+
+    def narrow(flat):
+        y = ffn(jnp.take(flat, idx, axis=0, mode="fill", fill_value=0))
+        return jnp.take(y, at, axis=0, mode="fill", fill_value=0)
+
+    out = jax.lax.cond(valid.sum() <= live_rows, narrow, ffn,
+                       x.reshape(n, d))
+    return out.reshape(rows, chunk, -1)
 
 
 def rotary_embedding(x, positions, rotary_dim: int, base: float):
@@ -89,12 +125,20 @@ def _rms_norm_op(ctx, op, ins):
                              float(op.attrs["epsilon"]))]}
 
 
-@register_op("gated_silu_ffn", inputs=("X", "WIn", "WOut"), outputs=("Out",))
+@register_op("gated_silu_ffn", inputs=("X", "WIn", "WOut", "NumValid"),
+             outputs=("Out",), no_grad=("NumValid",))
 def _gated_silu_ffn_op(ctx, op, ins):
-    return {"Out": [gated_silu_ffn(ins["X"][0], ins["WIn"][0],
-                                   ins["WOut"][0],
+    x, w_out = ins["X"][0], ins["WOut"][0]
+    nv = ins.get("NumValid")
+    live = 0
+    if nv:
+        f, d = w_out.shape
+        live = live_ffn_rows(x.shape[0] * x.shape[1], d, f,
+                             w_out.dtype.itemsize)
+    return {"Out": [gated_silu_ffn(x, ins["WIn"][0], w_out,
                                    op.attrs.get("gate_multiplier"),
-                                   op.attrs.get("down_multiplier"))]}
+                                   op.attrs.get("down_multiplier"),
+                                   nv[0] if nv else None, live)]}
 
 
 @register_op("linear_stored", inputs=("X", "W"), outputs=("Out",))

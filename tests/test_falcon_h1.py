@@ -389,6 +389,58 @@ def test_engine_counts_the_pages_of_lean_lanes(predictor):
     assert 0 < want < st["attn_live_pages_total"]
 
 
+def _serve_counting_live_rows(predictor, prompts, n):
+    """Serve ``prompts`` greedily; returns (tokens, stats after the
+    close, the valid tokens of every step assembled)."""
+    live = []
+    eng = _engine(predictor, start=False)
+    assemble = eng._assemble
+
+    def seen(lanes):
+        feed, rows, carry = assemble(lanes)
+        live.append(int(feed["gen_num_valid"].sum()))
+        return feed, rows, carry
+
+    eng._assemble = seen
+    with eng.start():
+        streams = [eng.submit(p, max_new_tokens=n) for p in prompts]
+        tokens = [s.result(timeout=300) for s in streams]
+    return tokens, eng.stats(), live
+
+
+def test_engine_serves_the_same_tokens_on_the_live_rows(predictor,
+                                                        monkeypatch):
+    """The dense SwiGLU on the live rows alone: the tiny widths are under
+    ``live_ffn_rows``'s rule, so the rule is made to say R = half the
+    window (6 of 3 lanes x 4 tokens) for every layer. The engine then
+    serves the tokens of the whole-window path and of the reference
+    (padding rows now read 0 where they read noise: nothing reads them),
+    and counts a narrow step for each step with at most 6 valid tokens,
+    over both branches."""
+    from paddle_tpu.ops import decoder
+    from paddle_tpu.runtime import dispatch
+
+    prompts = [np.random.RandomState(s).randint(1, V, n).astype(np.int64)
+               for s, n in ((11, 6), (12, 13), (13, 9), (14, 17), (15, 5))]
+    dispatch._SHARED_CACHE.clear()      # compiled by program content
+    whole, st_whole, _ = _serve_counting_live_rows(predictor, prompts, 6)
+    monkeypatch.setattr(decoder, "live_ffn_rows",
+                        lambda rows, d, f, itemsize: rows // 2)
+    dispatch._SHARED_CACHE.clear()
+    try:
+        got, st, live = _serve_counting_live_rows(predictor, prompts, 6)
+    finally:
+        monkeypatch.undo()
+        dispatch._SHARED_CACHE.clear()
+    assert got == whole == [_greedy_by_reference(p, 6) for p in prompts]
+    assert (st["ffn_narrow_layers"], st_whole["ffn_narrow_layers"]) == (3, 0)
+    assert st_whole["ffn_narrow_steps_total"] == 0
+    assert st["ragged_steps_total"] == len(live)
+    assert st["ffn_live_rows_total"] == sum(live)
+    assert st["ffn_narrow_steps_total"] == sum(n <= 6 for n in live)
+    assert 0 < st["ffn_narrow_steps_total"] < st["ragged_steps_total"]
+
+
 @pytest.mark.parametrize("kwargs,needs", [
     (dict(prefix_cache=True), "snapshots"),
     (dict(spec_tokens=2, draft=object()), "rollback"),

@@ -413,6 +413,37 @@ def test_engine_evicted_sequence_resumes_identically(predictor):
     assert eng.stats()["evicted_total"] >= 1
 
 
+def test_engine_serves_the_same_tokens_with_the_dense_layer_on_live_rows(
+        predictor, monkeypatch):
+    """Layer 0's dense feed-forward on the live rows alone (the rule made
+    to say half the window: the tiny width is under it): the same served
+    tokens as the whole window, gauge ``ffn_narrow_layers`` 1 against 0."""
+    from paddle_tpu.ops import decoder
+    from paddle_tpu.runtime import dispatch
+
+    rng = np.random.default_rng(7)
+    prompts = [rng.integers(1, 89, n, dtype=np.int64) for n in (30, 9, 21)]
+    seen = []
+    for narrow in (False, True):
+        if narrow:
+            monkeypatch.setattr(decoder, "live_ffn_rows",
+                                lambda rows, d, f, itemsize: rows // 2)
+        dispatch._SHARED_CACHE.clear()      # compiled by program content
+        try:
+            with _engine(predictor) as eng:
+                streams = [eng.submit(p, max_new_tokens=12, eos_id=None)
+                           for p in prompts]
+                tokens = [s.result(timeout=600) for s in streams]
+        finally:
+            monkeypatch.undo()
+            dispatch._SHARED_CACHE.clear()
+        st = eng.stats()
+        seen.append((tokens, st["ffn_narrow_layers"],
+                     st["ffn_narrow_steps_total"] > 0))
+    assert seen[0][0] == seen[1][0]
+    assert seen[0][1:] == (0, False) and seen[1][1:] == (1, True)
+
+
 @pytest.mark.parametrize("kwargs,needs", [
     (dict(prefix_cache=True), "window's last pages"),
     (dict(spec_tokens=2, draft=object()), "recycling held back"),
