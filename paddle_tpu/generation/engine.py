@@ -426,6 +426,11 @@ class GenerationMetrics:
                  # of attn_live_pages_total, the pages of lanes that take
                  # the kernel's lean decode branch (their rows alone)
                  "attn_lean_pages_total",
+                 # latent layers (stats(): latent_layers): the pages the
+                 # latent kernel walks, and the keys its valid query
+                 # tokens attend (what its FLOPs are counted from), both
+                 # summed over the layers
+                 "attn_latent_pages_total", "attn_latent_key_rows_total",
                  # recurrent layers and experts in the step (a hybrid
                  # config): valid tokens x expert layers, and lanes that
                  # took a new sequence (its state restarts from zero in
@@ -653,13 +658,16 @@ class GenerationEngine:
         # attention layers only and carries a per-lane recurrent state
         # beside the pools; a MiMo config (models/mimo.py) pages two
         # kinds of attention layer, full and window, in pools of their
-        # own; the step loop only ever sees how many pooled layers there
-        # are, which extra arrays ride the step, and whether the cache
-        # has window pages to turn over
+        # own, or latent layers in one pool a layer and no V pool (the
+        # config's pool_geometry); the step loop only ever sees how many
+        # pooled layers there are, which extra arrays ride the step, and
+        # whether the cache has window pages to turn over
         hybrid = isinstance(config, HybridConfig)
         mimo = isinstance(config, MiMoConfig)
+        latent = mimo and config.latent
+        pooled = "latent" if latent else "full"
         self._kv_layers = (len(config.attention_layers) if hybrid
-                           else len(config.layers_of("full")) if mimo
+                           else len(config.layers_of(pooled)) if mimo
                            else config.num_layers)
         self._expert_layers = (len(config.expert_layers) if hybrid or mimo
                                else 0)
@@ -682,11 +690,13 @@ class GenerationEngine:
             window_num_pages=(self.lanes * window.pages_per_seq + 1
                               if window else 0),
             window_pages_per_seq=window.pages_per_seq if window else 0)
+        kv_heads, head_dim, k_dim = (
+            config.pool_geometry(pooled) if mimo
+            else (config.num_kv_heads, config.head_dim, None) if hybrid
+            else (config.num_heads, config.head_dim, None))
         self.cache = PagedKVCache(
-            self._kv_layers,
-            config.num_heads if not (hybrid or mimo) else config.num_kv_heads,
-            config.v_dim if mimo else config.head_dim,
-            k_dim=config.k_dim if mimo else None, window=window,
+            self._kv_layers, kv_heads, head_dim, k_dim=k_dim, window=window,
+            latent=latent,
             num_pages=self.num_pages, page_size=self.page_size,
             max_seqs=self.lanes, max_pages_per_seq=maxp,
             dtype=self.kv_dtype, state=state, scope=self._step_scope,
@@ -722,6 +732,7 @@ class GenerationEngine:
         self._ffn_narrow = (0, 0)       # (layers, live rows), likewise
         self._decode_bound = None       # two_lane: first decode step
         self._prefill_progs: Dict[int, Any] = {}    # seq bucket -> (prog, fetches)
+        self._latent_layers = 0         # the step's latent layers
         if self.mode == "ragged":
             # THE executable: one mixed prefill+decode program for the
             # engine's whole life, one BoundStep per step
@@ -731,6 +742,9 @@ class GenerationEngine:
             self._ragged_prog, self._ragged_fetches = build(
                 config, self.geom, self.chunk_tokens, self.kv_dtype)
             self._lean_tokens = self._lean_decode_tokens()
+            self._latent_layers = sum(
+                op.type == "latent_paged_attention"
+                for op in self._ragged_prog.global_block().ops)
         else:
             self._decode_prog, self._decode_fetches = build_decode_program(
                 config, self.geom)
@@ -873,33 +887,44 @@ class GenerationEngine:
 
     def _refuse_for_window_layers(self, page_store):
         """A model with window layers keeps, of those layers, only the
-        pages a query can still reach: what counts on every page of a
-        sequence being there is refused at construction, by what it
-        would need."""
+        pages a query can still reach; one with latent layers keeps one
+        entry a token and no values: what counts on every page of a
+        sequence being there, or on pages of keys and values, is refused
+        at construction, by what it would need."""
+        latent = self.config.latent
         if self.mode != "ragged":
             raise ValueError(
-                "a config with window layers needs the ragged engine: the "
-                "two_lane programs know one kind of page pool")
+                "a config with window or latent layers needs the ragged "
+                "engine: the two_lane programs know one kind of page pool")
         if self.prefix_cache:
             raise ValueError(
-                "prefix_cache with window layers: a shared prefix's full "
-                "pages are in the trie, its window pages were recycled; it "
-                "needs the window's last pages kept at page boundaries")
+                "prefix_cache with latent layers: the trie publishes, "
+                "exports and shares pages of keys and values; it needs "
+                "the latent pool's one page kind in the trie" if latent
+                else "prefix_cache with window layers: a shared prefix's "
+                "full pages are in the trie, its window pages were "
+                "recycled; it needs the window's last pages kept at page "
+                "boundaries")
         if self._draft is not None or self.spec_tokens > 0:
             raise ValueError(
-                "speculative decoding with window layers: a rejected "
+                "speculative decoding with latent layers: a draft model "
+                "keeps pages of keys and values of its own; it needs a "
+                "self-draft over the latent pages (the MTP module)" if latent
+                else "speculative decoding with window layers: a rejected "
                 "draft's rows have recycled pages the accepted prefix "
                 "still needs; it needs recycling held back by the draft")
         if page_store is not None:
             raise ValueError(
-                "page_store with window layers: exported runs carry the "
-                "full layers' pages only; it needs the window pages on "
-                "the wire")
+                "page_store with latent layers: exported runs carry pages "
+                "of keys and values; it needs the latent pages on the wire"
+                if latent else "page_store with window layers: exported "
+                "runs carry the full layers' pages only; it needs the "
+                "window pages on the wire")
         if self.quantize_weights != "off" or self.kv_dtype == "int8":
             raise ValueError(
                 "quantize_weights or int8 pages with a MiMo config: the "
                 "rewrite knows mul ops only, and int8 scale planes know "
-                "no window and no split keys")
+                "no window, no split keys and no latent pages")
 
     # -- lifecycle -----------------------------------------------------------
     def start(self) -> "GenerationEngine":
@@ -1093,6 +1118,7 @@ class GenerationEngine:
             out["moe_expert_load_mean"] = float(loads.mean())
             out["moe_kernel_layers"] = self._moe_kernel_layers
         out["ffn_narrow_layers"] = self._ffn_narrow[0]
+        out["latent_layers"] = self._latent_layers
         # pages by kind of attention layer (window: 0 without any)
         out["kv_pages_resident_full"] = out["cache"]["pages_resident_full"]
         out["kv_pages_resident_window"] = \
@@ -1886,6 +1912,14 @@ class GenerationEngine:
             (num_valid > 0) & (num_valid <= self._lean_tokens)].sum()))
         self.metrics.inc("attn_table_pages_total",
                          R * self.geom.max_pages_per_seq)
+        if self._latent_layers:
+            # the latent kernel's own rule: a lane walks its live pages
+            # once, and its valid token j attends positions + j + 1 keys
+            keys = num_valid * positions + num_valid * (num_valid + 1) // 2
+            self.metrics.inc("attn_latent_pages_total",
+                             walked * self._latent_layers)
+            self.metrics.inc("attn_latent_key_rows_total",
+                             int(keys.sum()) * self._latent_layers)
         feed = {
             "gen_tokens": tokens,
             "gen_pos_ids": pos_ids,

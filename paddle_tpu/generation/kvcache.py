@@ -57,6 +57,18 @@ the split page layout of kernels/ragged_paged_attention.py. Prefix
 sharing, page export and ingest see full pages only, so the engine
 refuses them for a model with window layers.
 
+**Latent pages** (``latent=True``, a model whose layers are latent
+attention, MLA): one entry a token and layer, the normed latent
+(``head_dim`` = kv_lora_rank wide) and the rotated rope key, is the
+whole of what a sequence keeps, and it is a key ``k_dim`` = kv_lora_rank
++ rope wide whose first ``head_dim`` values are also its value. So a
+layer has ONE pool, the K pool in the split layout ``[1, pages,
+page_size + k_dim - head_dim, head_dim]`` with ``page_size ==
+head_dim`` (kernels/latent_attention.py), and no V pool: ``v_pages`` is
+empty and a page costs ``k_dim`` values a token. Tables, lengths and
+the free list are the full layers' own; prefix sharing, page export
+and ingest know K and V pools, so the engine refuses them here too.
+
 Page 0 is permanently reserved as the JUNK page: idle decode lanes and
 batch-padding rows point their tables at it, so their (discarded)
 writes can never corrupt a live sequence.
@@ -110,15 +122,19 @@ class PagePoolExhausted(RuntimeError):
     (or eviction) must resolve it; never an allocation."""
 
 
-def pool_names(num_layers: int, quantized: bool = False, prefix: str = ""):
+def pool_names(num_layers: int, quantized: bool = False, prefix: str = "",
+               values: bool = True):
     """The scope names of the page pools, ``(k, v, k_scales, v_scales)``
-    as lists by layer (the scale lists empty for float pools): what the
-    step programs declare (generation/model.py) and what a cache keeps
-    in its scope. ``prefix`` "w": the window layers' pools."""
+    as lists by layer (the scale lists empty for float pools, the V list
+    without ``values``: latent pages): what the step programs declare
+    (generation/model.py) and what a cache keeps in its scope. ``prefix``
+    "w": the window layers' pools."""
     kinds = ("k_pages", "v_pages") + (("k_scales", "v_scales")
                                       if quantized else ())
     names = [[f"gen_{prefix}{kind}_{i}" for i in range(num_layers)]
              for kind in kinds]
+    if not values:
+        names[1] = []
     return tuple(names) + ([],) * (4 - len(names))
 
 
@@ -202,7 +218,7 @@ class PagedKVCache:
                  trie_max_pages: int = 0, tenant_quota_pages: int = 0,
                  state: Optional[Dict[str, tuple]] = None, scope=None,
                  k_dim: Optional[int] = None,
-                 window: Optional[WindowKind] = None):
+                 window: Optional[WindowKind] = None, latent: bool = False):
         if num_pages < 2:
             raise ValueError("num_pages must be >= 2 (page 0 is reserved)")
         if page_size < 1 or max_seqs < 1 or max_pages_per_seq < 1:
@@ -219,6 +235,8 @@ class PagedKVCache:
         # keys as wide as values unless told; a second kind of layer
         self.k_dim = int(k_dim or head_dim)
         self.window = window
+        # one pool a layer: the K pool's first head_dim values are the V
+        self.latent = bool(latent)
         if (window is not None or self.k_dim != self.head_dim) and (
                 self.quantized or prefix_cache):
             raise ValueError("window layers and keys wider than values "
@@ -239,7 +257,8 @@ class PagedKVCache:
 
             scope = Scope()
         self.scope = scope
-        self._pool_names = pool_names(self.num_layers, self.quantized)
+        self._pool_names = pool_names(self.num_layers, self.quantized,
+                                      values=not self.latent)
         self._wpool_names = pool_names(
             window.num_layers if window else 0, prefix="w")[:2]
         self._pool_lock = threading.Lock()
@@ -326,7 +345,7 @@ class PagedKVCache:
         scales = ([[jnp.ones(shape[:3], "float32") for _ in range(n)]
                    for _ in "kv"] if self.quantized else [])
         k_page = key_page_shape(self.page_size, self.k_dim, self.head_dim)
-        shapes = (shape[:2] + k_page, shape)
+        shapes = (shape[:2] + k_page,) + (shape,) * (not self.latent)
         if self.window is not None:
             w = self.window
             for names, page in zip(self._wpool_names, (k_page, shape[2:])):
@@ -334,8 +353,11 @@ class PagedKVCache:
                     self.scope.vars[name] = jnp.zeros(
                         (w.num_kv_heads, self.window_num_pages) + page,
                         self.dtype)
-        self.set_buffers(*([jnp.zeros(shp, self.dtype) for _ in range(n)]
-                           for shp in shapes), *scales)
+        pools = [[jnp.zeros(shp, self.dtype) for _ in range(n)]
+                 for shp in shapes]
+        if self.latent:
+            pools.append([])            # no V pool
+        self.set_buffers(*pools, *scales)
 
     def pools_locked(self):
         """``with cache.pools_locked():`` around the DISPATCH of whatever
@@ -396,7 +418,8 @@ class PagedKVCache:
         step programs declare. The steps themselves never call this:
         they rewrite the pools in place and the executor stores what
         they return."""
-        if len(k_pages) != self.num_layers or len(v_pages) != self.num_layers:
+        if len(k_pages) != self.num_layers or len(v_pages) != len(
+                self._pool_names[1]):
             raise ValueError("set_buffers: wrong layer count")
         if self.quantized and (k_scales is None or v_scales is None):
             raise ValueError("set_buffers: int8 pool needs scale planes")
@@ -435,17 +458,19 @@ class PagedKVCache:
 
     @staticmethod
     def page_bytes(num_kv_heads: int, head_dim: int, page_size: int,
-                   dtype: str, k_dim: Optional[int] = None) -> int:
+                   dtype: str, k_dim: Optional[int] = None,
+                   values: bool = True) -> int:
         """HBM bytes ONE page costs per layer (K + V, scale planes
         included for int8) — the capacity arithmetic the int8 bench
         gates its ~2x-resident-sequences claim on. ``k_dim``: the keys'
-        width where it is not the values' ``head_dim``."""
+        width where it is not the values' ``head_dim``; without
+        ``values`` (latent pages) the K page alone."""
         slots = num_kv_heads * page_size
         if dtype == "int8":
             return 2 * (slots * head_dim + 4 * slots)   # int8 body + scales
         import jax.numpy as jnp
 
-        return (slots * (head_dim + (k_dim or head_dim))
+        return (slots * (head_dim * values + (k_dim or head_dim))
                 * jnp.dtype(dtype).itemsize)
 
     def pool_bytes(self) -> int:
@@ -453,7 +478,8 @@ class PagedKVCache:
         kinds."""
         total = (self.num_layers * self.num_pages
                  * self.page_bytes(self.num_kv_heads, self.head_dim,
-                                   self.page_size, self.dtype, self.k_dim))
+                                   self.page_size, self.dtype, self.k_dim,
+                                   not self.latent))
         if self.window is not None:
             total += (self.window.num_layers * self.window_num_pages
                       * self.page_bytes(self.window.num_kv_heads,

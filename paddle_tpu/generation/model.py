@@ -98,20 +98,22 @@ class CacheGeometry:
 
 def _page_pools(main: Program, num_layers: int, num_kv_heads: int,
                 head_dim: int, geom: CacheGeometry, dtype: str = "float32",
-                k_dim=None, window: bool = False):
+                k_dim=None, window: bool = False, latent: bool = False):
     """Declare the page pools of ``num_layers`` attention layers as
     persistable variables of ``main``; returns ``(k, v, k_scales,
     v_scales)`` lists by layer, the scale lists ``[None] * num_layers``
     unless ``dtype`` is int8. ``k_dim``: keys wider than the values'
     ``head_dim`` (``kvcache.key_page_shape``); ``window``: the window
-    layers' pools, with their own names and page count. No startup op:
+    layers' pools, with their own names and page count; ``latent``: the
+    K pools alone (the V list ``[None] * num_layers``). No startup op:
     the ``PagedKVCache`` makes the arrays."""
     block = main.global_block()
     pages = geom.window_num_pages if window else geom.num_pages
     shape = [num_kv_heads, pages, geom.page_size, head_dim]
     k_shape = shape[:2] + list(key_page_shape(
         geom.page_size, k_dim or head_dim, head_dim))
-    names = pool_names(num_layers, dtype == "int8", "w" if window else "")
+    names = pool_names(num_layers, dtype == "int8", "w" if window else "",
+                       values=not latent)
     pools = [[block.create_var(name=n, shape=shp, dtype=dt, persistable=True,
                                stop_gradient=True) for n in kind]
              for kind, shp, dt in zip(
@@ -391,6 +393,11 @@ def build_mimo_step_program(cfg: MiMoConfig, geom: CacheGeometry,
     ``build_hybrid_step_program`` (``gen_pos_ids`` feeds the rotary
     embedding), with page pools by KIND of attention layer:
 
+    * ``gen_k_pages_{j}`` alone for the j-th LATENT layer, one entry a
+      token (kernels/latent_attention.py: ``[1, pages, page_size +
+      rotary_dim, kv_rank]``, page_size == kv_rank), read through
+      ``gen_block_tables`` by the absorbed kernel
+      (``latent_paged_attention``), every head against the latents;
     * ``gen_k_pages_{j}`` / ``gen_v_pages_{j}`` for the j-th FULL layer,
       ``cfg.num_kv_heads`` heads, read through ``gen_block_tables``;
     * ``gen_wk_pages_{j}`` / ``gen_wv_pages_{j}`` for the j-th WINDOW
@@ -419,25 +426,38 @@ def build_mimo_step_program(cfg: MiMoConfig, geom: CacheGeometry,
         num_valid = layers.data("gen_num_valid", [], dtype="int32")
         tables = {"full": layers.data(
             "gen_block_tables", [geom.max_pages_per_seq], dtype="int32")}
+        tables["latent"] = tables["full"]
         pools = {}
-        for kind in ("full", "window"):
+        for kind in ("full", "window", "latent"):
             held = cfg.layers_of(kind)
-            if kind == "window" and held:
+            if not held:
+                continue
+            if kind == "window":
                 tables[kind] = layers.data(
                     "gen_block_tables_window", [geom.window_pages_per_seq],
                     dtype="int32")
+            kvh, v_dim, k_dim = cfg.pool_geometry(kind)
             kps, vps, _, _ = _page_pools(
-                main, len(held), cfg.kv_heads_of(kind), cfg.v_dim, geom,
-                kv_dtype, k_dim=cfg.k_dim, window=kind == "window")
+                main, len(held), kvh, v_dim, geom, kv_dtype, k_dim=k_dim,
+                window=kind == "window", latent=kind == "latent")
             pools.update(zip(held, zip(kps, vps)))
         state = {name: layers.data(name, list(shp), dtype=dt,
                                    append_batch_size=False)
                  for name, (shp, dt) in cfg.state_shapes(-1).items()}
-        from ..kernels import (ragged_paged_attention_layer,
+        from ..kernels import (latent_cache_write_layer,
+                               latent_paged_attention_layer,
+                               ragged_paged_attention_layer,
                                split_kv_cache_write_layer)
 
         def attention(i, kind, q, k, v, sink):
             kp, vp = pools[i]
+            if kind == "latent":
+                # k: the cache entry, v: the up-projection W_kv_b
+                latent_cache_write_layer(kp, k, tables[kind], positions,
+                                         num_valid)
+                return latent_paged_attention_layer(
+                    q, kp, v, tables[kind], positions, num_valid,
+                    cfg.num_heads, cfg.v_dim)
             windowed = kind == "window"
             split_kv_cache_write_layer(
                 kp, vp, k, v, tables[kind], positions, num_valid,

@@ -30,6 +30,12 @@ attention/ffn/layer_norm/adam/softmax-ce):
     small-rank matmuls accumulated in VMEM, composing with the
     dense OR quantized base — the kernel layer under
     paddle_tpu.adapters' multi-adapter serving
+  * latent paged attention — the absorbed form of latent attention
+    (MLA): every query head of a token against one compressed entry a
+    token (the normed latent and a rope key) in a paged pool with no
+    values, each page read once a lane for all of its tokens
+    (kernels/latent_attention.py): the kernel under the MiMo step's
+    ``latent`` layers
   * Mamba-2 state step — read a chunk out of every lane's carried
     state [H, P, N] and advance it, one pass over the state
     (kernels/mamba2_state.py): the kernel under ops/ssm.py's
@@ -55,6 +61,10 @@ back to the pure-XLA implementation with identical numerics
 from .flash_attention import flash_attention, flash_attention_layer
 from .fused_optim import (fused_adam_update, fused_momentum_update,
                           optimizer_fuse_enabled)
+from .latent_attention import (latent_attention_layer,
+                               latent_cache_write_layer,
+                               latent_paged_attention,
+                               latent_paged_attention_layer)
 from .layer_norm import fused_layer_norm, layer_norm_pallas
 from .mamba2_state import state_step
 from .lora import (batched_lora_delta, batched_lora_matmul,

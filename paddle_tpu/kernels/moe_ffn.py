@@ -52,8 +52,9 @@ PACK = 16             # rows of a packed bfloat16 tile: x is widened by them
 # a fifth slower); two arrays x two buffers
 _TILE_BYTES = 4 * 1024 * 1024
 # what the call may hold in VMEM beside its weight tiles (x as it arrives
-# and in float32, the float32 output, a visit's rows): a v5e has 128 MiB
-_RESIDENT_BYTES = 40 * 1024 * 1024
+# and in float32, the float32 output, a visit's rows): a v5e has 128 MiB;
+# 512 rows at d = 7680 hold 47.5 MiB (and the call ~80 MiB with its tiles)
+_RESIDENT_BYTES = 56 * 1024 * 1024
 
 
 def _tile_rows(rows: int, row_bytes: int):

@@ -91,20 +91,25 @@ def gated_ffn(input, size, param_attr=None, dtype=None, name=None,
     return out
 
 
-def rotary_embedding(input, positions, num_heads, rotary_dim, base=10000.0):
-    """Rotary position embedding over the first ``rotary_dim`` values of
-    each of ``num_heads`` heads of ``input`` [rows, chunk, heads * D], at
-    ``positions`` [rows, chunk]; the rest of a head passes. float32."""
+def rotary_embedding(input, positions, num_heads, rotary_dim, base=10000.0,
+                     offset=0):
+    """Rotary position embedding over ``rotary_dim`` values of each of
+    ``num_heads`` heads of ``input`` [rows, chunk, heads * D], from the
+    head's ``offset``-th value on (a decoupled rope part at the end of a
+    head), at ``positions`` [rows, chunk]; the rest of a head passes.
+    float32."""
     helper = LayerHelper("rotary_embedding")
-    if rotary_dim % 2 or rotary_dim > int(input.shape[-1]) // num_heads:
-        raise ValueError(f"rotary_dim {rotary_dim}: even, at most the head")
+    if rotary_dim % 2 or offset + rotary_dim > int(input.shape[-1]) // num_heads:
+        raise ValueError(f"rotary_dim {rotary_dim}: even, within the head")
     out = _out(helper, input, dtype="float32")
+    attrs = {"num_heads": int(num_heads), "rotary_dim": int(rotary_dim),
+             "base": float(base)}
+    if offset:
+        attrs["offset"] = int(offset)
     helper.append_op(
         type="rotary_embedding",
         inputs={"X": [input], "Positions": [positions]},
-        outputs={"Out": [out]},
-        attrs={"num_heads": int(num_heads), "rotary_dim": int(rotary_dim),
-               "base": float(base)})
+        outputs={"Out": [out]}, attrs=attrs)
     return out
 
 
@@ -134,7 +139,7 @@ def causal_attention(q, k, v, num_heads, num_kv_heads, window=None,
 def topk_moe(input, num_experts, top_k, expert_size, held_experts=None,
              first_expert=0, num_valid=None, loads=None,
              param_attr=None, dtype=None, name=None, score_func="softmax",
-             select_bias=False):
+             select_bias=False, routed_scale=1.0):
     """Dropless top-k mixture of gated-SiLU experts over ``[rows, chunk,
     d]`` (ops/moe.py ``topk_moe``): the router is ``num_experts`` wide,
     this program holds experts ``first_expert .. first_expert +
@@ -144,7 +149,8 @@ def topk_moe(input, num_experts, top_k, expert_size, held_experts=None,
     "softmax" (gates: the softmax over the chosen logits) or "sigmoid"
     (gates: the chosen experts' sigmoid scores over their sum);
     ``select_bias``: a per-expert bias ``<name>router.bias`` that ranks
-    the experts and weighs nothing. Returns (out, loads_out). Parameters
+    the experts and weighs nothing; ``routed_scale`` multiplies every
+    gate (``routed_scaling_factor``). Returns (out, loads_out). Parameters
     ``<name>router.w``, ``<name>experts_in.w`` [held, d, 2 expert_size],
     ``<name>experts_out.w``."""
     helper = LayerHelper("topk_moe", param_attr=param_attr, name=name)
@@ -176,6 +182,8 @@ def topk_moe(input, num_experts, top_k, expert_size, held_experts=None,
             default_initializer=ConstantInitializer(0.0))]
     if score_func != "softmax":
         attrs["score_func"] = str(score_func)
+    if routed_scale != 1.0:
+        attrs["routed_scale"] = float(routed_scale)
     helper.append_op(
         type="topk_moe", inputs=inputs,
         outputs={"Out": [out], "LoadsOut": [loads_out]}, attrs=attrs)

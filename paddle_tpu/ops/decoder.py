@@ -80,18 +80,20 @@ def gated_silu_ffn(x, w_in, w_out, gate_multiplier=None,
     return out.reshape(rows, chunk, -1)
 
 
-def rotary_embedding(x, positions, rotary_dim: int, base: float):
-    """x [..., T, H, D] float32, positions [..., T]: rotate the first
-    ``rotary_dim`` values of every head at its token's position (pairs
-    (i, i + rotary_dim / 2), frequency base^(-2i / rotary_dim)); the
-    rest pass."""
+def rotary_embedding(x, positions, rotary_dim: int, base: float,
+                     offset: int = 0):
+    """x [..., T, H, D] float32, positions [..., T]: rotate the
+    ``rotary_dim`` values of every head from ``offset`` on at its
+    token's position (pairs (i, i + rotary_dim / 2), frequency
+    base^(-2i / rotary_dim)); the rest pass."""
     half = rotary_dim // 2
     inv = base ** (-jnp.arange(half, dtype=jnp.float32) / half)
     ang = positions.astype(jnp.float32)[..., None, None] * inv
     cos, sin = jnp.cos(ang), jnp.sin(ang)
-    x1, x2 = x[..., :half], x[..., half:rotary_dim]
-    return jnp.concatenate(
-        [x1 * cos - x2 * sin, x2 * cos + x1 * sin, x[..., rotary_dim:]], -1)
+    end = offset + rotary_dim
+    x1, x2 = x[..., offset:offset + half], x[..., offset + half:end]
+    parts = [x1 * cos - x2 * sin, x2 * cos + x1 * sin, x[..., end:]]
+    return jnp.concatenate([x[..., :offset]] * bool(offset) + parts, -1)
 
 
 def causal_attention(q, k, v, window=None, sink=None):
@@ -155,7 +157,7 @@ def _rotary_embedding_op(ctx, op, ins):
     out = rotary_embedding(
         x.astype(jnp.float32).reshape(x.shape[:-1] + (h, -1)),
         ins["Positions"][0], int(op.attrs["rotary_dim"]),
-        float(op.attrs["base"]))
+        float(op.attrs["base"]), int(op.attrs.get("offset", 0)))
     return {"Out": [out.reshape(x.shape)]}
 
 

@@ -230,7 +230,8 @@ _HI = jax.lax.Precision.HIGHEST
 
 def topk_moe(x, valid, router_w, w_in, w_out, loads=None, *, top_k: int,
              num_experts: int, first_expert: int, block_rows: int = 128,
-             score_func: str = "softmax", select_bias=None):
+             score_func: str = "softmax", select_bias=None,
+             routed_scale: float = 1.0):
     """x [T, d]; valid [T] bool (padding rows of a ragged window take no
     expert); router_w [d, num_experts]; w_in [held, d, 2f] and w_out
     [held, f, d]: experts first_expert .. first_expert + held, each a
@@ -243,7 +244,8 @@ def topk_moe(x, valid, router_w, w_in, w_out, loads=None, *, top_k: int,
     gates are the softmax over its top_k logits, or with ``score_func``
     "sigmoid" the chosen experts' sigmoid scores over their sum.
     ``select_bias`` [num_experts] is added to the scores for the ranking
-    alone: it chooses and does not weigh. The (token, expert)
+    alone: it chooses and does not weigh. ``routed_scale`` multiplies
+    every gate (a published ``routed_scaling_factor``). The (token, expert)
     pairs that landed on held experts are sorted by expert and go
     through the grouped gated feed-forward: ``kernels/moe_ffn.py`` where
     it runs (``moe_ffn.fits``: a TPU or the interpreter, shapes its tiles
@@ -257,7 +259,7 @@ def topk_moe(x, valid, router_w, w_in, w_out, loads=None, *, top_k: int,
         x, valid, router_w, w_in, w_out, loads, select_bias, top_k=top_k,
         num_experts=num_experts, first_expert=first_expert,
         block_rows=block_rows, score_func=score_func,
-        kernel=moe_ffn.fits(x.shape[0], x.shape[1], w_out.shape[1],
+        routed_scale=float(routed_scale), kernel=moe_ffn.fits(x.shape[0], x.shape[1], w_out.shape[1],
                             w_in.dtype))
 
 
@@ -266,9 +268,10 @@ def topk_moe(x, valid, router_w, w_in, w_out, loads=None, *, top_k: int,
 # the shapes) is part of the key
 @functools.partial(jax.jit, static_argnames=(
     "top_k", "num_experts", "first_expert", "block_rows", "score_func",
-    "kernel"))
+    "routed_scale", "kernel"))
 def _topk_moe(x, valid, router_w, w_in, w_out, loads, select_bias, *, top_k,
-              num_experts, first_expert, block_rows, score_func, kernel):
+              num_experts, first_expert, block_rows, score_func,
+              routed_scale, kernel):
     T, d = x.shape
     held = w_in.shape[0]
     if router_w.shape[1] != num_experts or first_expert + held > num_experts:
@@ -288,6 +291,8 @@ def _topk_moe(x, valid, router_w, w_in, w_out, loads, select_bias, *, top_k,
         vals = jnp.take_along_axis(scores, idx, axis=-1)
     gates = (vals / jnp.sum(vals, axis=-1, keepdims=True)
              if score_func == "sigmoid" else jax.nn.softmax(vals, axis=-1))
+    if routed_scale != 1.0:
+        gates = gates * routed_scale
     local = idx.astype(jnp.int32) - first_expert
     mine = valid[:, None] & (local >= 0) & (local < held)
     key = jnp.where(mine, local, held).reshape(-1)          # dead pairs last
@@ -357,5 +362,6 @@ def _topk_moe_op(ctx, op, ins):
         num_experts=int(op.attrs["num_experts"]),
         first_expert=int(op.attrs["first_expert"]),
         score_func=str(op.attrs.get("score_func", "softmax")),
-        select_bias=bias[0] if bias else None)
+        select_bias=bias[0] if bias else None,
+        routed_scale=float(op.attrs.get("routed_scale", 1.0)))
     return {"Out": [out.reshape(R, C, d)], "LoadsOut": [loads]}

@@ -21,10 +21,10 @@ def _mod(name):
     return importlib.import_module("paddle_tpu.kernels." + name)
 
 
-(fa, fused_optim, layer_norm, lora, mamba2_state, moe_ffn, quant_matmul, rpa,
- softmax_xent) = map(
-    _mod, ("flash_attention", "fused_optim", "layer_norm", "lora",
-           "mamba2_state", "moe_ffn", "quant_matmul",
+(fa, fused_optim, latent, layer_norm, lora, mamba2_state, moe_ffn,
+ quant_matmul, rpa, softmax_xent) = map(
+    _mod, ("flash_attention", "fused_optim", "latent_attention",
+           "layer_norm", "lora", "mamba2_state", "moe_ffn", "quant_matmul",
            "ragged_paged_attention", "softmax_xent"))
 
 KERNELS = os.path.dirname(os.path.abspath(fa.__file__))
@@ -97,6 +97,13 @@ def _moe_ffn():
         _z(16), jnp.ones(2, i32), True)
 
 
+def _latent():
+    i32 = jnp.int32
+    return latent._latent_pallas(
+        _z(2, 4, 8, 128), _z(2, 4, 8, 64), _z(1, 4, 192, 128),
+        _z(2, dtype=i32), jnp.ones(2, i32), _z(2, 2, dtype=i32), 0.1, True)
+
+
 def _adam():
     p = _z(8, 128)
     return fused_optim.fused_adam_update(p, p, p, p, 1e-3, 0.9, 0.999)
@@ -149,6 +156,7 @@ SITES = [
     ("lora.py", _lora, ["lora_delta"]),
     ("mamba2_state.py", _state_step, ["mamba2_state_step"]),
     ("moe_ffn.py", _moe_ffn, ["moe_grouped_ffn"]),
+    ("latent_attention.py", _latent, ["latent_paged_attention"]),
 ]
 
 

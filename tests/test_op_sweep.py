@@ -548,6 +548,12 @@ COVERED_ELSEWHERE = {
     # into the split key layout and the ring tables through the kernel
     # test, the step program == one full forward and the engine)
     'rotary_embedding', 'causal_attention', 'kv_cache_write_split',
+    # PR-43 latent attention ops (tests/test_latent_attention.py: the
+    # kernel interpreted and its jnp path against the definition over a
+    # pool the cache write filled, absorbed == expanded;
+    # tests/test_openpangu.py: the export program (expanded) and the step
+    # program (absorbed, over latent pages) == the reference's logits)
+    'latent_attention', 'latent_cache_write', 'latent_paged_attention',
     # PR-6 generation ops (tests/test_generation.py: paged_attention
     # vs dense-softmax oracle incl. length masking + len-0 rows;
     # kv_cache_write scatter vs oracle + junk-page isolation; both

@@ -582,6 +582,77 @@ def _child():
 
     record("mimo_step_program_mimo_v2_5_7layer", mimo_step_program,
            group="mimo")
+
+    # -- the openPangu serving cell (PT_AOT_ONLY=openpangu): its step as
+    # benchmark/ runs it (5 latent layers, 16 of 256 experts, bfloat16
+    # weights and latent pages, 32 lanes x 16 tokens) and the absorbed
+    # latent kernel alone. The step must write its pools in place: no
+    # copy of a pool's shape in the optimized HLO.
+    def openpangu_step_program():
+        import ml_dtypes
+
+        import paddle_tpu as fluid
+        from paddle_tpu.generation.kvcache import key_page_shape
+        from paddle_tpu.generation.model import (CacheGeometry,
+                                                 build_mimo_step_program)
+
+        bench = os.path.join(HERE, "benchmark")
+        with open(os.path.join(bench, "configs",
+                               "openpangu_ultra_moe_718b_serve.json")) as f:
+            cfg = json.load(f)
+        ref = _load(os.path.join(bench, "models", "openpangu_reference.py"))
+        mcfg = _load(os.path.join(
+            bench, "models", "openpangu_program.py")).openpangu_config(cfg)
+        eng = cfg["engine"]
+        lanes, chunk, ps = eng["lanes"], eng["chunk_tokens"], eng["page_size"]
+        maxp = -(-eng["max_position"] // ps)
+        geom = CacheGeometry(num_pages=eng["num_pages"], page_size=ps,
+                             max_pages_per_seq=maxp)
+        prog, fetches = build_mimo_step_program(mcfg, geom, chunk,
+                                                eng["kv_dtype"])
+        feed = {"gen_tokens": np.zeros((lanes, chunk), np.int64),
+                "gen_pos_ids": np.zeros((lanes, chunk), np.int64),
+                "gen_positions": np.zeros(lanes, np.int64),
+                "gen_num_valid": np.zeros(lanes, np.int32),
+                "gen_block_tables": np.zeros((lanes, maxp), np.int32)}
+        for name, (shape, dt) in mcfg.state_shapes(lanes).items():
+            feed[name] = np.zeros(shape, dt)
+        scope = fluid.Scope()
+        _kvh, v_dim, k_dim = mcfg.pool_geometry("latent")
+        pool = (1, geom.num_pages) + key_page_shape(ps, k_dim, v_dim)
+        for j in range(mcfg.num_layers):
+            scope.set_var(f"gen_k_pages_{j}",
+                          np.zeros(pool, ml_dtypes.bfloat16))
+        for name, shape, _init in ref.spec(cfg):
+            scope.set_var(name, np.zeros(shape, ml_dtypes.bfloat16))
+        exe = fluid.Executor(fluid.TPUPlace())
+        compiled = exe.aot_compile(prog, feed, fetches, scope=scope,
+                                   devices=[dev])
+        shape = "bf16[" + ",".join(map(str, pool)) + "]"
+        copies = [ln for ln in compiled.as_text().splitlines()
+                  if f" = {shape}" in ln and " copy(" in ln]
+        assert not copies, f"a pool is copied: {copies[0][:200]}"
+        return compiled
+
+    record("openpangu_step_program_5layer", openpangu_step_program,
+           group="openpangu")
+
+    def latent_kernel_row():
+        from paddle_tpu.kernels.latent_attention import _latent_pallas
+
+        B, C, H, L, rope, pages, maxp = 32, 16, 128, 512, 64, 901, 36
+        args = (jax.ShapeDtypeStruct((B, C, H, L), bf),
+                jax.ShapeDtypeStruct((B, C, H, rope), bf),
+                jax.ShapeDtypeStruct((1, pages, L + rope, L), bf),
+                jax.ShapeDtypeStruct((B,), jnp.int32),
+                jax.ShapeDtypeStruct((B,), jnp.int32),
+                jax.ShapeDtypeStruct((B, maxp), jnp.int32))
+        return jax.jit(lambda *a: _latent_pallas(
+            *a, sm_scale=1 / 192 ** 0.5, interpret=False),
+            in_shardings=(R,) * 6).lower(*args).compile()
+
+    record("latent_paged_attention_openpangu_32x16x128x512", latent_kernel_row,
+           group="openpangu")
     from paddle_tpu.kernels.mamba2_state import state_step
 
     f32 = jnp.float32
@@ -646,6 +717,8 @@ def _child():
     moe_ffn_row("moe_ffn_hybrid_36x4096x768_top10", "hybrid", 36, 4096, 768,
                 10)
     moe_ffn_row("moe_ffn_mimo_16x4096x2048_top8", "mimo", 16, 4096, 2048, 8)
+    moe_ffn_row("moe_ffn_openpangu_16x7680x2048_top8", "openpangu", 16, 7680,
+                2048, 8)
 
     # -- the headline: the train cell's own step at its REAL shapes ----
     # params + adam state from the startup program, full fwd+bwd+
